@@ -22,6 +22,10 @@ def main() -> None:
                          "(perf trajectory for future PRs); '' disables")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+
     want = lambda name: args.only is None or any(
         name.startswith(o) for o in args.only
     )

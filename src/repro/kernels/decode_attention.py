@@ -8,10 +8,14 @@ Layout: q [B, H, hd] (one query token per slot), k/v [B, S_max, kvH, hd]
 (the KV cache in its native engine layout — no transpose copy on the hot
 path), lengths [B] int32 (valid KV entries per slot; 0 marks an empty slot).
 
-Grid: (B, kvH, num_kv_blocks).  Each program owns one slot's GQA group
-(``H // kvH`` query heads) and accumulates the online softmax over KV tiles
-in VMEM scratch, exactly like ``flash_attention.py``.  Two length-awareness
-levers make the kernel ragged-batch fast:
+Grid: (B, num_kv_blocks).  Each program owns one slot.  A KV tile is
+``[block_k, kvH, hd]`` — all kv heads in one contiguous DMA; the TPU
+lowering requires a block's two trailing dims to be (8, 128)-aligned or the
+array's own, and a one-head ``(block_k, 1, hd)`` slice is neither.  The body
+loops over the kv heads (static sublane slices of the tile); each head's
+GQA group (``H // kvH`` query heads) accumulates the online softmax in its
+own VMEM scratch row block, exactly like ``flash_attention.py``.  Two
+length-awareness levers make the kernel ragged-batch fast:
 
   * ``lengths`` rides in as a scalar-prefetch operand
     (``PrefetchScalarGridSpec``), so the KV BlockSpec index_map can clamp the
@@ -20,7 +24,13 @@ levers make the kernel ragged-batch fast:
   * the kernel body early-exits (``pl.when(k_start < length)``) for tiles
     past the length, so their FLOPs are skipped too.
 
-``interpret=True`` runs the same kernel body on CPU for CI.
+The per-tile helpers below (``init_scratch`` / ``tile_update`` /
+``finalize`` / ``scratch_shapes``) are shared by every attention kernel of
+this package; each kernel only supplies its visibility mask.
+
+``interpret=True`` runs the body in the Pallas interpreter off the TPU, for
+tests only: the interpreter does not apply the TPU lowering rules, which
+``tests/test_tpu_compile.py`` checks by compiling for a described v5e.
 """
 from __future__ import annotations
 
@@ -31,61 +41,94 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 NEG_INF = -1e30
+
+
+def scratch_shapes(kvh: int, rows: int, hd: int) -> list:
+    """fp32 VMEM scratch for ``kvh`` heads of ``rows`` query rows each: the
+    online-softmax accumulator, running max and running denominator."""
+    return [
+        pltpu.VMEM((kvh, rows, hd), jnp.float32),
+        pltpu.VMEM((kvh, rows, 1), jnp.float32),
+        pltpu.VMEM((kvh, rows, 1), jnp.float32),
+    ]
+
+
+def init_scratch(acc_ref, m_ref, l_ref) -> None:
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+    m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+    l_ref[...] = jnp.zeros_like(l_ref)
+
+
+def tile_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, mask, sm_scale):
+    """Fold one KV tile into the online softmax of every kv head.
+
+    q_ref [1, kvH, R, hd] (R query rows per head); k_ref / v_ref
+    [1, bk, kvH, hd]; mask [R, bk] bool — which (row, key) pairs are
+    visible, the same for every head.  Masked pairs contribute exactly 0, so
+    a row whose window is empty keeps ``l == 0`` and finalizes to zeros
+    (without the guard ``exp(s - m_new)`` would be 1 for a fully-masked row
+    and the output an unweighted mean of V)."""
+    for h in range(k_ref.shape[2]):
+        q = q_ref[0, h].astype(jnp.float32)  # [R, hd]
+        k = k_ref[0, :, h].astype(jnp.float32)  # [bk, hd]
+        s = jax.lax.dot_general(
+            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        ) * sm_scale  # [R, bk]
+        s = jnp.where(mask, s, NEG_INF)
+        m_prev = m_ref[h]
+        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+        corr = jnp.exp(m_prev - m_new)
+        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+        v = v_ref[0, :, h].astype(jnp.float32)
+        pv = jax.lax.dot_general(
+            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        )
+        acc_ref[h] = acc_ref[h] * corr + pv
+        m_ref[h] = m_new
+
+
+def finalize(o_ref, acc_ref, l_ref) -> None:
+    # rows that never accumulated (empty slots, empty windows): l stays 0,
+    # clamped -> output 0
+    l = jnp.maximum(l_ref[...], 1e-30)
+    o_ref[0] = (acc_ref[...] / l).astype(o_ref.dtype)
 
 
 def _decode_kernel(
     lengths_ref,  # scalar prefetch: [B] int32
-    q_ref,  # [1, 1, gp, hd]
-    k_ref, v_ref,  # [1, bk, 1, hd]
-    o_ref,  # [1, 1, gp, hd]
-    acc_ref, m_ref, l_ref,  # VMEM scratch: [gp, hd], [gp, 1], [gp, 1] (fp32)
+    q_ref,  # [1, kvH, gp, hd]
+    k_ref, v_ref,  # [1, bk, kvH, hd]
+    o_ref,  # [1, kvH, gp, hd]
+    acc_ref, m_ref, l_ref,  # VMEM scratch (scratch_shapes)
     *,
     block_k: int,
     sm_scale: float,
 ):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     k_start = ki * block_k
 
     @pl.when(k_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [gp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [bk, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [gp, bk]
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        s = jnp.where(kpos < length, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.exp(s - m_new)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        shape = (q_ref.shape[2], block_k)
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        tile_update(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, kpos < length,
+            sm_scale,
         )
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        # length == 0 slots never accumulate: l stays 0, clamped -> output 0.
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -116,29 +159,25 @@ def decode_attention(
         qr = jnp.pad(qr, ((0, 0), (0, 0), (0, gp - group), (0, 0)))
     lengths = jnp.minimum(lengths.astype(jnp.int32), s)
 
-    def q_map(bi, hi, ki, lens):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens):
+    def kv_map(bi, ki, lens):
         # Clamp past-length tiles onto the slot's last useful block: the
         # pipeline sees a repeated index and skips the DMA (ragged early-exit).
         last = jnp.maximum(pl.cdiv(lens[bi], block_k) - 1, 0)
-        return (bi, jnp.minimum(ki, last), hi, 0)
+        return (bi, jnp.minimum(ki, last), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, gp, hd), q_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, gp, hd), q_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, gp, hd),
     )
     kernel = functools.partial(
         _decode_kernel, block_k=block_k, sm_scale=hd**-0.5
@@ -147,8 +186,8 @@ def decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, qr, k, v)

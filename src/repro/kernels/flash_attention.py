@@ -8,6 +8,11 @@ output block on the final kv step — the canonical TPU flash schedule.
 Block shapes default to (128, head_dim) q-tiles and (512, head_dim) kv-tiles:
 q/k/v tiles plus fp32 accumulators stay well under ~2 MiB VMEM per core while
 keeping the MXU matmul dims at multiples of 128 (hardware-aligned).
+
+Forward only: the kernel has no custom VJP and ``jax.grad`` through a
+``pallas_call`` fails.  ``ops.attention(impl="auto")`` selects it on TPU for
+forward-only callers (monolithic prefill); ``make_train_step`` maps "auto"
+to the XLA family and refuses ``impl="pallas"``.
 """
 from __future__ import annotations
 
@@ -17,8 +22,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.kernels.compat import CompilerParams
 
 NEG_INF = -1e30
 
@@ -140,7 +143,7 @@ def flash_attention(
             pltpu.VMEM((block_q, 1), jnp.float32),
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,

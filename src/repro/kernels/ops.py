@@ -1,12 +1,17 @@
 """Jit'd dispatch wrappers over the Pallas kernels with XLA fallbacks.
 
-``impl`` semantics:
-  * "auto"      -- pallas on TPU; on CPU/GPU pick xla (short seq) or
-                   xla_flash (long seq, no S^2 buffer)
-  * "xla"       -- plain einsum attention
-  * "xla_flash" -- lax.scan blocked online softmax
-  * "pallas"    -- Pallas kernel (interpret=True automatically off-TPU,
-                   so tests validate the real kernel body on CPU)
+``impl`` semantics of the serving kernels (decode / verify / tree verify /
+chunked prefill, dense and paged):
+  * "auto"   -- the Pallas kernel on TPU, the XLA fallback elsewhere
+  * "xla"    -- the masked dense XLA fallback (the kernel's oracle)
+  * "pallas" -- the Pallas kernel; compiled for the chip on TPU, run in
+                the Pallas interpreter elsewhere (tests only: the
+                interpreter does not apply the TPU lowering rules, which
+                tests/test_tpu_compile.py checks against a described v5e)
+
+Full-sequence ``attention`` has the same "auto", but its Pallas flash
+kernel is forward only (no VJP): the train step asks for "xla_auto", the
+XLA family on every backend.
 """
 from __future__ import annotations
 
@@ -28,14 +33,23 @@ def attention(
     causal: bool = True,
     impl: str = "auto",
 ) -> jax.Array:
-    """q: [B, Sq, H, hd]; k/v: [B, Sk, kvH, hd].  Returns [B, Sq, H, hd]."""
+    """q: [B, Sq, H, hd]; k/v: [B, Sk, kvH, hd].  Returns [B, Sq, H, hd].
+
+    ``impl``:
+      * "auto"      -- "pallas" on TPU, "xla_auto" elsewhere
+      * "xla_auto"  -- "xla", or "xla_flash" from ``_FLASH_SEQ_THRESHOLD``
+                       query rows, on every backend (differentiable)
+      * "xla"       -- plain einsum attention
+      * "xla_flash" -- lax.scan blocked online softmax (no S^2 buffer)
+      * "pallas"    -- the Pallas flash kernel, forward only: it has no
+                       VJP, so a training step must not select it
+    """
     from repro.models import layers as L
 
     if impl == "auto":
-        if _on_tpu():
-            impl = "pallas"
-        else:
-            impl = "xla_flash" if q.shape[1] >= _FLASH_SEQ_THRESHOLD else "xla"
+        impl = "pallas" if _on_tpu() else "xla_auto"
+    if impl == "xla_auto":
+        impl = "xla_flash" if q.shape[1] >= _FLASH_SEQ_THRESHOLD else "xla"
 
     if impl == "xla":
         return L.attention_xla(q, k, v, causal=causal)
@@ -71,11 +85,8 @@ def decode_attention(
     q: [B, H, hd]; k/v_cache: [B, S_max, kvH, hd]; lengths: [B] int32 valid-KV
     counts (0 == empty slot -> zero output).  Returns [B, H, hd].
 
-    ``impl``:
-      * "auto"   -- pallas on TPU, xla elsewhere (interpret-mode pallas is
-                    correct but slow; CI forces it explicitly)
-      * "xla"    -- length-masked dense attention over S_max
-      * "pallas" -- flash-decode kernel (interpret=True automatically off-TPU)
+    ``impl`` as in the module docstring; "xla" is length-masked dense
+    attention over S_max.
     """
     from repro.models import layers as L
 
@@ -124,10 +135,8 @@ def verify_attention(
     attends to ``kpos <= lengths - T + t`` — the prefix plus the chunk's own
     causal triangle.  Returns [B, T, H, hd].
 
-    ``impl``:
-      * "auto"   -- pallas on TPU, xla elsewhere
-      * "xla"    -- chunk-causal length-masked dense attention over S_max
-      * "pallas" -- chunk-verify kernel (interpret=True automatically off-TPU)
+    ``impl`` as in the module docstring; "xla" is chunk-causal
+    length-masked dense attention over S_max.
     """
     from repro.models import layers as L
 
@@ -184,10 +193,8 @@ def tree_verify_attention(
     positions its bitmask admits.  A linear-chain anc reproduces
     ``verify_attention`` exactly.  Returns [B, N, H, hd].
 
-    ``impl``:
-      * "auto"   -- pallas on TPU, xla elsewhere
-      * "xla"    -- ancestor-masked dense attention over S_max
-      * "pallas" -- tree-verify kernel (interpret=True automatically off-TPU)
+    ``impl`` as in the module docstring; "xla" is ancestor-masked dense
+    attention over S_max.
     """
     from repro.models import layers as L
 
@@ -251,11 +258,8 @@ def prefill_chunk_attention(
     plus the chunk's own causal triangle.  Returns [B, C, H, hd]; rows
     ``t >= chunk_lens`` return zeros.
 
-    ``impl``:
-      * "auto"   -- pallas on TPU, xla elsewhere
-      * "xla"    -- chunk-causal masked dense attention over S_max
-      * "pallas" -- ragged prefill kernel (interpret=True automatically
-                    off-TPU)
+    ``impl`` as in the module docstring; "xla" is chunk-causal masked
+    dense attention over S_max.
     """
     from repro.models import layers as L
 
@@ -325,10 +329,8 @@ def paged_decode_attention(
     (unused entries hold the sentinel page 0); lengths: [B] int32 valid-KV
     counts (0 == empty slot -> zero output).  Returns [B, H, hd].
 
-    ``impl``:
-      * "auto"   -- pallas on TPU, xla elsewhere
-      * "xla"    -- gather pages dense, then length-masked attention
-      * "pallas" -- block-table flash-decode kernel (interpret off-TPU)
+    ``impl`` as in the module docstring; "xla" gathers the pages dense,
+    then runs length-masked attention.
     """
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
@@ -373,7 +375,7 @@ def paged_verify_attention(
     block_tables: [B, W] int32; lengths: [B] int32 valid-KV counts
     *including* the chunk.  Returns [B, T, H, hd].
 
-    ``impl``: same semantics as ``paged_decode_attention``.
+    ``impl``: as in ``paged_decode_attention``.
     """
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
@@ -420,7 +422,7 @@ def paged_tree_verify_attention(
     [B, W] int32; lengths: [B] int32 *including* the N tree positions;
     anc: [B, N] int32 ancestor bitmasks.  Returns [B, N, H, hd].
 
-    ``impl``: same semantics as ``paged_decode_attention``.
+    ``impl``: as in ``paged_decode_attention``.
     """
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
@@ -468,7 +470,7 @@ def paged_prefill_chunk_attention(
     block_tables: [B, W] int32; starts / chunk_lens: [B] int32 as in
     ``prefill_chunk_attention``.  Returns [B, C, H, hd].
 
-    ``impl``: same semantics as ``paged_decode_attention``.
+    ``impl``: as in ``paged_decode_attention``.
     """
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "xla"
@@ -499,7 +501,7 @@ def paged_prefill_chunk_attention(
 
 
 def ssm_scan_chunk(xi, dt, B_, C_, A, h0):
-    """Pallas selective-scan chunk (interpret mode off-TPU)."""
+    """Pallas selective-scan chunk (the interpreter off the TPU)."""
     from repro.kernels.ssm_scan import ssm_scan_chunk as _kernel
 
     y, h = _kernel(

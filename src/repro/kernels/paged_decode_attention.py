@@ -14,8 +14,10 @@ pages appear in several block tables), block_tables [B, W] int32 (logical
 page ``j`` of slot ``b`` lives at physical page ``block_tables[b, j]``;
 unused entries hold the sentinel page 0), lengths [B] int32 valid-KV counts.
 
-Grid: (B, kvH, num_logical_pages).  Both ragged-batch levers of the dense
-kernel survive the indirection:
+Grid: (B, num_logical_pages).  One grid step DMAs one whole page
+``[page, kvH, hd]`` — contiguous in the pool — and the dense kernel's body
+loops over its kv heads.  Both ragged-batch levers of the dense kernel
+survive the indirection:
 
   * ``lengths`` and ``block_tables`` ride in as scalar-prefetch operands, so
     the KV index_map clamps the logical page index at the slot's last useful
@@ -25,7 +27,7 @@ kernel survive the indirection:
     past the length, skipping their FLOPs.
 
 ``lengths == 0`` marks an empty slot (output zeros).  ``interpret=True``
-runs the same kernel body on CPU for CI.
+is for tests off the TPU only (see ``decode_attention``).
 """
 from __future__ import annotations
 
@@ -36,10 +38,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-from repro.kernels.decode_attention import _decode_kernel
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import _decode_kernel, scratch_shapes
 
 
 def _paged_decode_kernel(lengths_ref, tables_ref, *refs, **kw):
@@ -80,30 +79,26 @@ def paged_decode_attention(
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def q_map(bi, hi, ki, lens, tables):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens, tables):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens, tables):
+    def kv_map(bi, ki, lens, tables):
         # Clamp the *logical* page index at the slot's last useful page, then
         # dereference the block table: past-length tiles re-address the same
         # physical page and the pipeline skips their DMA (ragged early-exit).
         last = jnp.maximum(pl.cdiv(lens[bi], page) - 1, 0)
-        return (tables[bi, jnp.minimum(ki, last)], 0, hi, 0)
+        return (tables[bi, jnp.minimum(ki, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, gp, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, gp, hd), q_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((gp, hd), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-            pltpu.VMEM((gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, gp, hd),
     )
     kernel = functools.partial(
         _paged_decode_kernel, block_k=page, sm_scale=hd**-0.5
@@ -112,8 +107,8 @@ def paged_decode_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, block_tables, qr, k_pool, v_pool)

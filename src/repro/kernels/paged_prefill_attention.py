@@ -12,14 +12,15 @@ been scattered into the slot's pages at positions
 Layout: q [B, C, H, hd]; k/v pools [P, page, kvH, hd]; block_tables [B, W]
 int32; starts / chunk_lens [B] int32 as in the dense kernel.
 
-Grid: (B, kvH, num_q_blocks, num_logical_pages); query rows fold to
-``block_q * gp`` sublanes exactly as in ``prefill_attention``.  The
+Grid: (B, num_q_blocks, num_logical_pages); each step DMAs one whole page
+``[page, kvH, hd]`` and query rows fold to ``block_q * gp`` sublanes per kv
+head exactly as in ``prefill_attention``.  The
 scalar-prefetched block table is dereferenced in the KV index_map after
 clamping the logical page at the q block's causal bound
 ``starts + min((qi + 1) * block_q, chunk_lens)`` — the DMA-skip lever now
 scales with prefill *progress*: early chunks of a long prompt sweep only
-the few pages written so far.  ``interpret=True`` runs the same body on
-CPU for CI.
+the few pages written so far.  ``interpret=True`` is for tests off the TPU
+only (see ``decode_attention``).
 """
 from __future__ import annotations
 
@@ -30,7 +31,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.decode_attention import scratch_shapes
 from repro.kernels.prefill_attention import (
     _fold_queries,
     _prefill_kernel,
@@ -78,28 +79,24 @@ def paged_prefill_attention(
     chunk_lens = jnp.minimum(chunk_lens.astype(jnp.int32), c)
     block_tables = block_tables.astype(jnp.int32)
 
-    def q_map(bi, hi, qi, ki, starts, lens, tables):
-        return (bi, hi, qi, 0)
+    def q_map(bi, qi, ki, starts, lens, tables):
+        return (bi, 0, qi, 0)
 
-    def kv_map(bi, hi, qi, ki, starts, lens, tables):
+    def kv_map(bi, qi, ki, starts, lens, tables):
         limit = starts[bi] + jnp.minimum((qi + 1) * block_q, lens[bi])
         last = jnp.maximum(pl.cdiv(limit, page) - 1, 0)
-        return (tables[bi, jnp.minimum(ki, last)], 0, hi, 0)
+        return (tables[bi, jnp.minimum(ki, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, nq, nk),
+        grid=(b, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q * gp, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, block_q * gp, hd), q_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * gp, hd), jnp.float32),
-            pltpu.VMEM((block_q * gp, 1), jnp.float32),
-            pltpu.VMEM((block_q * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, block_q * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, block_q * gp, hd),
     )
     kernel = functools.partial(
         _paged_prefill_kernel, block_q=block_q, block_k=page, gp=gp,
@@ -109,9 +106,8 @@ def paged_prefill_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, cp * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(starts, chunk_lens, block_tables, qr, k_pool, v_pool)

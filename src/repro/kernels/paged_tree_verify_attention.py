@@ -13,8 +13,9 @@ int32 (last column = overflow sentinel, so the grid iterates W-1 logical
 pages); lengths [B] int32 INCLUDING the N tree positions; anc [B, N] int32
 ancestor bitmasks riding as a THIRD scalar-prefetch operand after lengths
 and the block table.  The body IS ``_tree_verify_kernel`` — the table only
-steers the KV index_map, exactly as in ``paged_verify_attention``.
-``interpret=True`` runs the same body on CPU for CI.
+steers the KV index_map, exactly as in ``paged_verify_attention``: grid
+(B, num_logical_pages), one whole ``[page, kvH, hd]`` page per step.
+``interpret=True`` is for tests off the TPU only (see ``decode_attention``).
 """
 from __future__ import annotations
 
@@ -25,13 +26,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.decode_attention import scratch_shapes
 from repro.kernels.tree_verify_attention import (
     MAX_TREE_NODES,
     _tree_verify_kernel,
 )
-
-NEG_INF = -1e30
 
 
 def _paged_tree_verify_kernel(lengths_ref, tables_ref, anc_ref, *refs, **kw):
@@ -74,27 +73,23 @@ def paged_tree_verify_attention(
     block_tables = block_tables.astype(jnp.int32)
     anc = anc.astype(jnp.int32)
 
-    def q_map(bi, hi, ki, lens, tables, ancs):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens, tables, ancs):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens, tables, ancs):
+    def kv_map(bi, ki, lens, tables, ancs):
         last = jnp.maximum(pl.cdiv(lens[bi], page) - 1, 0)
-        return (tables[bi, jnp.minimum(ki, last)], 0, hi, 0)
+        return (tables[bi, jnp.minimum(ki, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, t * gp, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t * gp, hd), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, t * gp, hd),
     )
     kernel = functools.partial(
         _paged_tree_verify_kernel, block_k=page, chunk=t, gp=gp,
@@ -104,8 +99,8 @@ def paged_tree_verify_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, block_tables, anc, qr, k_pool, v_pool)

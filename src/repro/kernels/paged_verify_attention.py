@@ -12,11 +12,13 @@ int32; lengths [B] int32 valid-KV counts INCLUDING the chunk.  Chunk query t
 sits at sequence position ``lengths - T + t`` and attends to
 ``kpos <= lengths - T + t`` — prefix plus the chunk's own causal triangle.
 
-Grid: (B, kvH, num_logical_pages); query rows fold to a single ``T * gp``
-sublane axis exactly as in ``verify_attention``.  The scalar-prefetched
+Grid: (B, num_logical_pages); each step DMAs one whole page
+``[page, kvH, hd]`` and query rows fold to a single ``T * gp`` sublane axis
+per kv head exactly as in ``verify_attention``.  The scalar-prefetched
 block table is dereferenced in the KV index_map after clamping the logical
 page index at the slot's last useful page, preserving the DMA-skip behavior
-for ragged batches.  ``interpret=True`` runs the same body on CPU for CI.
+for ragged batches.  ``interpret=True`` is for tests off the TPU only (see
+``decode_attention``).
 """
 from __future__ import annotations
 
@@ -27,10 +29,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
+from repro.kernels.decode_attention import scratch_shapes
 from repro.kernels.verify_attention import _verify_kernel
-
-NEG_INF = -1e30
 
 
 def _paged_verify_kernel(lengths_ref, tables_ref, *refs, **kw):
@@ -77,27 +77,23 @@ def paged_verify_attention(
     lengths = lengths.astype(jnp.int32)
     block_tables = block_tables.astype(jnp.int32)
 
-    def q_map(bi, hi, ki, lens, tables):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens, tables):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens, tables):
+    def kv_map(bi, ki, lens, tables):
         last = jnp.maximum(pl.cdiv(lens[bi], page) - 1, 0)
-        return (tables[bi, jnp.minimum(ki, last)], 0, hi, 0)
+        return (tables[bi, jnp.minimum(ki, last)], 0, 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, t * gp, hd), q_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
-            pl.BlockSpec((1, page, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
+            pl.BlockSpec((1, page, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t * gp, hd), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, t * gp, hd),
     )
     kernel = functools.partial(
         _paged_verify_kernel, block_k=page, chunk=t, gp=gp,
@@ -107,8 +103,8 @@ def paged_verify_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, block_tables, qr, k_pool, v_pool)

@@ -18,10 +18,12 @@ slot's chunk at its own length, 0 = slot not prefilling).  Chunk query t
 sits at sequence position ``starts + t`` and attends ``kpos <= starts + t``;
 rows ``t >= chunk_lens`` return zeros.
 
-Grid: (B, kvH, num_q_blocks, num_kv_blocks).  Each program owns one
-``block_q``-row slice of one slot's GQA group, folded to a single
-``block_q * gp`` sublane axis exactly as in the verify kernel.  Both
-ragged-batch levers generalize:
+Grid: (B, num_q_blocks, num_kv_blocks).  Each program owns one
+``block_q``-row slice of one slot's chunk, folded per kv head to a single
+``block_q * gp`` sublane axis exactly as in the verify kernel; the body
+loops over the kv heads of each ``[block_k, kvH, hd]`` tile through the
+decode kernel's shared ``tile_update``.  Both ragged-batch levers
+generalize:
 
   * ``starts`` and ``chunk_lens`` ride in as scalar-prefetch operands; the
     KV BlockSpec index_map clamps the tile index at the q block's *causal*
@@ -34,7 +36,8 @@ ragged-batch levers generalize:
     bound ``kpos <= starts + t`` on top of the row-validity mask.
 
 ``chunk_lens == 0`` marks a frozen slot: every tile is skipped and the
-output is zeros.  ``interpret=True`` runs the same body on CPU for CI.
+output is zeros.  ``interpret=True`` is for tests off the TPU only (see
+``decode_attention``).
 """
 from __future__ import annotations
 
@@ -45,18 +48,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (
+    finalize,
+    init_scratch,
+    scratch_shapes,
+    tile_update,
+)
 
 
 def _prefill_kernel(
     starts_ref,  # scalar prefetch: [B] int32
     lens_ref,  # scalar prefetch: [B] int32
-    q_ref,  # [1, 1, block_q * gp, hd]
-    k_ref, v_ref,  # [1, block_k, 1, hd]
-    o_ref,  # [1, 1, block_q * gp, hd]
-    acc_ref, m_ref, l_ref,  # VMEM scratch
+    q_ref,  # [1, kvH, block_q * gp, hd]
+    k_ref, v_ref,  # [1, block_k, kvH, hd]
+    o_ref,  # [1, kvH, block_q * gp, hd]
+    acc_ref, m_ref, l_ref,  # VMEM scratch (scratch_shapes)
     *,
     block_q: int,
     block_k: int,
@@ -64,18 +70,16 @@ def _prefill_kernel(
     sm_scale: float,
 ):
     b = pl.program_id(0)
-    qi = pl.program_id(2)
-    ki = pl.program_id(3)
-    nk = pl.num_programs(3)
+    qi = pl.program_id(1)
+    ki = pl.program_id(2)
+    nk = pl.num_programs(2)
     start = starts_ref[b]
     clen = lens_ref[b]
     q0 = qi * block_q  # first chunk row owned by this program
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     k_start = ki * block_k
     # Exclusive KV bound of this q block: its last real row q0 + block_q - 1
@@ -84,39 +88,21 @@ def _prefill_kernel(
 
     @pl.when((q0 < clen) & (k_start < limit))
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [block_q * gp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [block_k, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [block_q * gp, block_k]
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        shape = (q_ref.shape[2], block_k)  # [block_q * gp, block_k]
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         # Row r holds chunk query t = q0 + r // gp at sequence position
         # start + t: causal bound over prefix + intra-chunk triangle, and
-        # rows past the slot's real chunk length are masked out entirely.
-        t_row = q0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
-        s = jnp.where((kpos <= start + t_row) & (t_row < clen), s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # Fully-masked rows (t >= chunk_lens) leave m_new == NEG_INF;
-        # exp(s - m_new) would then be 1, turning the output into an
-        # unweighted mean of V.  Mask so l stays 0 and they finalize to 0.
-        p = jnp.where(s > NEG_INF, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        # rows past the slot's real chunk length are masked out entirely
+        # (they finalize to zeros).
+        t_row = q0 + jax.lax.broadcasted_iota(jnp.int32, shape, 0) // gp
+        tile_update(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            (kpos <= start + t_row) & (t_row < clen), sm_scale,
         )
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        # chunk_lens == 0 slots and pad rows never accumulate: l stays 0,
-        # clamped -> output 0.
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        finalize(o_ref, acc_ref, l_ref)
 
 
 def _fold_queries(q: jax.Array, kvh: int, group: int, gp: int, block_q: int):
@@ -176,31 +162,27 @@ def prefill_attention(
     # are pad by contract (the engine sizes chunks to fit)
     chunk_lens = jnp.minimum(chunk_lens.astype(jnp.int32), c)
 
-    def q_map(bi, hi, qi, ki, starts, lens):
-        return (bi, hi, qi, 0)
+    def q_map(bi, qi, ki, starts, lens):
+        return (bi, 0, qi, 0)
 
-    def kv_map(bi, hi, qi, ki, starts, lens):
+    def kv_map(bi, qi, ki, starts, lens):
         # Clamp past-bound tiles onto the q block's last useful KV block:
         # the pipeline sees a repeated index and skips the DMA, so short
         # chunks skip the KV tiles their missing rows would have swept.
         limit = starts[bi] + jnp.minimum((qi + 1) * block_q, lens[bi])
         last = jnp.maximum(pl.cdiv(limit, block_k) - 1, 0)
-        return (bi, jnp.minimum(ki, last), hi, 0)
+        return (bi, jnp.minimum(ki, last), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nq, nk),
+        grid=(b, nq, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q * gp, hd), q_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, block_q * gp, hd), q_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, block_q * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * gp, hd), jnp.float32),
-            pltpu.VMEM((block_q * gp, 1), jnp.float32),
-            pltpu.VMEM((block_q * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, block_q * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, block_q * gp, hd),
     )
     kernel = functools.partial(
         _prefill_kernel, block_q=block_q, block_k=block_k, gp=gp,
@@ -210,9 +192,8 @@ def prefill_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, cp * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary",
-                                 "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary", "arbitrary")
         ),
         interpret=interpret,
     )(starts, chunk_lens, qr, k, v)

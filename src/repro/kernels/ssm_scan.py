@@ -20,8 +20,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
 
 def _ssm_kernel(
     xi_ref, dt_ref,  # [1, Q, bd]
@@ -96,7 +94,7 @@ def ssm_scan_chunk(
             jax.ShapeDtypeStruct((b, di, ds), jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((block_d, ds), jnp.float32)],
-        compiler_params=CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel")
         ),
         interpret=interpret,

@@ -21,11 +21,13 @@ pins down).
 Layout mirrors ``verify_attention`` exactly: q [B, N, H, hd] (one query per
 tree node), k/v [B, S_max, kvH, hd], lengths [B] int32 INCLUDING the N tree
 positions, anc [B, N] int32 riding in as a second scalar-prefetch operand
-next to lengths.  Grid (B, kvH, num_kv_blocks); query rows fold to a
-``N * gp`` sublane axis; the DMA-clamp index_map and the fully-masked-row
-guard are reused verbatim.  The per-row bitmask test is an unrolled Python
+next to lengths.  Grid (B, num_kv_blocks), one ``[block_k, kvH, hd]`` tile
+per step; query rows fold to a ``N * gp`` sublane axis per kv head; the
+DMA-clamp index_map and the shared ``tile_update`` (with its fully-masked-row
+guard) are reused verbatim.  The per-row bitmask test is an unrolled Python
 loop over the N chunk rows reading one SMEM scalar each — no gathers inside
-the kernel body.  ``interpret=True`` runs the same body on CPU for CI.
+the kernel body — and is built once per tile for all heads.
+``interpret=True`` is for tests off the TPU only (see ``decode_attention``).
 """
 from __future__ import annotations
 
@@ -36,9 +38,12 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (
+    finalize,
+    init_scratch,
+    scratch_shapes,
+    tile_update,
+)
 
 #: Hard cap on packed-tree size: ancestor sets are int32 bitmasks.
 MAX_TREE_NODES = 31
@@ -47,10 +52,10 @@ MAX_TREE_NODES = 31
 def _tree_verify_kernel(
     lengths_ref,  # scalar prefetch: [B] int32
     anc_ref,  # scalar prefetch: [B, N] int32 ancestor bitmasks
-    q_ref,  # [1, 1, N * gp, hd]
-    k_ref, v_ref,  # [1, bk, 1, hd]
-    o_ref,  # [1, 1, N * gp, hd]
-    acc_ref, m_ref, l_ref,  # VMEM scratch: [N*gp, hd], [N*gp, 1], [N*gp, 1]
+    q_ref,  # [1, kvH, N * gp, hd]
+    k_ref, v_ref,  # [1, bk, kvH, hd]
+    o_ref,  # [1, kvH, N * gp, hd]
+    acc_ref, m_ref, l_ref,  # VMEM scratch (scratch_shapes)
     *,
     block_k: int,
     chunk: int,  # N = tree nodes
@@ -58,27 +63,21 @@ def _tree_verify_kernel(
     sm_scale: float,
 ):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     k_start = ki * block_k
 
     @pl.when(k_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [N*gp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [bk, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [N*gp, bk]
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        t_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
+        shape = (q_ref.shape[2], block_k)  # [N*gp, bk]
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
+        t_row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // gp
         # Intra-chunk node index of each key position (negative = prefix,
         # >= chunk = beyond the tree).  Shifts are clamped into [0, 31] so
         # out-of-range lanes stay defined; ``in_chunk`` gates them off.
@@ -87,31 +86,21 @@ def _tree_verify_kernel(
         in_chunk = (jpos >= 0) & (jpos < chunk)
         # Row r holds tree node t = r // gp.  Visibility of key node j from
         # query node t is bit j of anc[b, t]; each of the N rows reads its
-        # one SMEM scalar in an unrolled loop (no in-kernel gathers).
-        intra = jnp.zeros(s.shape, jnp.bool_)
+        # one SMEM scalar in an unrolled loop (no in-kernel gathers).  The
+        # bits stay int32 until the final compare: Mosaic cannot truncate a
+        # constant to a bool vector.
+        bits = jnp.zeros(shape, jnp.int32)
         for t in range(chunk):
-            bit = ((anc_ref[b, t] >> jc) & 1) == 1
-            intra = jnp.where(t_row == t, bit, intra)
-        s = jnp.where((kpos < length - chunk) | (in_chunk & intra), s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # Fully-masked rows (empty slots, lengths < N) must finalize to
-        # zeros: mask the exp so l stays 0 (same guard as verify_attention).
-        p = jnp.where(s > NEG_INF, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+            bits = jnp.where(t_row == t, (anc_ref[b, t] >> jc) & 1, bits)
+        # Fully-masked rows (empty slots, lengths < N) finalize to zeros.
+        tile_update(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            (kpos < length - chunk) | (in_chunk & (bits == 1)), sm_scale,
         )
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -151,27 +140,23 @@ def tree_verify_attention(
     lengths = jnp.minimum(lengths.astype(jnp.int32), s)
     anc = anc.astype(jnp.int32)
 
-    def q_map(bi, hi, ki, lens, ancs):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens, ancs):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens, ancs):
+    def kv_map(bi, ki, lens, ancs):
         last = jnp.maximum(pl.cdiv(lens[bi], block_k) - 1, 0)
-        return (bi, jnp.minimum(ki, last), hi, 0)
+        return (bi, jnp.minimum(ki, last), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, t * gp, hd), q_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t * gp, hd), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, t * gp, hd),
     )
     kernel = functools.partial(
         _tree_verify_kernel, block_k=block_k, chunk=t, gp=gp,
@@ -181,8 +166,8 @@ def tree_verify_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, anc, qr, k, v)

@@ -13,11 +13,11 @@ lengths [B] int32 = valid KV entries per slot INCLUDING the chunk.  Chunk
 query t sits at sequence position ``lengths - T + t`` and may attend to
 ``kpos <= lengths - T + t`` — prefix plus the chunk's own causal triangle.
 
-Grid: (B, kvH, num_kv_blocks).  Each program owns one slot's GQA group for
-ALL T chunk queries: the query rows fold to a single ``T * gp`` sublane axis
-(``gp`` = sublane-padded group size), so the online-softmax scratch and both
-MXU contractions keep the exact shape discipline of ``decode_attention``.
-The same two ragged-batch levers apply:
+Grid: (B, num_kv_blocks).  Each program owns one slot for ALL T chunk
+queries: per kv head, the query rows fold to a single ``T * gp`` sublane
+axis (``gp`` = sublane-padded group size), and the body loops over the kv
+heads of each ``[block_k, kvH, hd]`` tile through the decode kernel's shared
+``tile_update``.  The same two ragged-batch levers apply:
 
   * ``lengths`` rides in as a scalar-prefetch operand, so the KV BlockSpec
     index_map clamps the tile index at each slot's last useful block — tiles
@@ -28,7 +28,8 @@ The same two ragged-batch levers apply:
     per-row position bound on top of the shared length mask.
 
 ``lengths == 0`` marks an empty slot: every tile is skipped and the output
-is zeros.  ``interpret=True`` runs the same kernel body on CPU for CI.
+is zeros.  ``interpret=True`` is for tests off the TPU only (see
+``decode_attention``).
 """
 from __future__ import annotations
 
@@ -39,17 +40,20 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.compat import CompilerParams
-
-NEG_INF = -1e30
+from repro.kernels.decode_attention import (
+    finalize,
+    init_scratch,
+    scratch_shapes,
+    tile_update,
+)
 
 
 def _verify_kernel(
     lengths_ref,  # scalar prefetch: [B] int32
-    q_ref,  # [1, 1, T * gp, hd]
-    k_ref, v_ref,  # [1, bk, 1, hd]
-    o_ref,  # [1, 1, T * gp, hd]
-    acc_ref, m_ref, l_ref,  # VMEM scratch: [T*gp, hd], [T*gp, 1], [T*gp, 1]
+    q_ref,  # [1, kvH, T * gp, hd]
+    k_ref, v_ref,  # [1, bk, kvH, hd]
+    o_ref,  # [1, kvH, T * gp, hd]
+    acc_ref, m_ref, l_ref,  # VMEM scratch (scratch_shapes)
     *,
     block_k: int,
     chunk: int,  # T = gamma + 1
@@ -57,53 +61,32 @@ def _verify_kernel(
     sm_scale: float,
 ):
     b = pl.program_id(0)
-    ki = pl.program_id(2)
-    nk = pl.num_programs(2)
+    ki = pl.program_id(1)
+    nk = pl.num_programs(1)
     length = lengths_ref[b]
 
     @pl.when(ki == 0)
     def _init():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
-        l_ref[...] = jnp.zeros_like(l_ref)
+        init_scratch(acc_ref, m_ref, l_ref)
 
     k_start = ki * block_k
 
     @pl.when(k_start < length)
     def _compute():
-        q = q_ref[0, 0].astype(jnp.float32)  # [T*gp, hd]
-        k = k_ref[0, :, 0].astype(jnp.float32)  # [bk, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [T*gp, bk]
-        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        shape = (q_ref.shape[2], block_k)  # [T*gp, bk]
+        kpos = k_start + jax.lax.broadcasted_iota(jnp.int32, shape, 1)
         # Row r holds chunk query t = r // gp at sequence position
         # length - chunk + t: causal bound over prefix + intra-chunk triangle.
-        t_row = jax.lax.broadcasted_iota(jnp.int32, s.shape, 0) // gp
-        s = jnp.where(kpos <= length - chunk + t_row, s, NEG_INF)
-
-        m_prev = m_ref[...]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        # A fully-masked row (its causal window is empty, e.g. lengths < T)
-        # leaves m_new == NEG_INF; exp(s - m_new) would then be 1, turning
-        # the output into an unweighted mean of V.  Mask those entries so l
-        # stays 0 and the row finalizes to zeros.
-        p = jnp.where(s > NEG_INF, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[...] = l_ref[...] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, 0].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        # A row whose window is empty (lengths < T) finalizes to zeros.
+        t_row = jax.lax.broadcasted_iota(jnp.int32, shape, 0) // gp
+        tile_update(
+            q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref,
+            kpos <= length - chunk + t_row, sm_scale,
         )
-        acc_ref[...] = acc_ref[...] * corr + pv
-        m_ref[...] = m_new
 
     @pl.when(ki == nk - 1)
     def _finalize():
-        # length == 0 slots (and rows whose causal window is empty) never
-        # accumulate: l stays 0, clamped -> output 0.
-        l = jnp.maximum(l_ref[...], 1e-30)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(o_ref.dtype)
+        finalize(o_ref, acc_ref, l_ref)
 
 
 @functools.partial(jax.jit, static_argnames=("block_k", "interpret"))
@@ -139,29 +122,25 @@ def verify_attention(
     qr = qr.transpose(0, 2, 1, 3, 4).reshape(b, kvh, t * gp, hd)
     lengths = jnp.minimum(lengths.astype(jnp.int32), s)
 
-    def q_map(bi, hi, ki, lens):
-        return (bi, hi, 0, 0)
+    def q_map(bi, ki, lens):
+        return (bi, 0, 0, 0)
 
-    def kv_map(bi, hi, ki, lens):
+    def kv_map(bi, ki, lens):
         # Clamp past-length tiles onto the slot's last useful block: the
         # pipeline sees a repeated index and skips the DMA (ragged early-exit).
         last = jnp.maximum(pl.cdiv(lens[bi], block_k) - 1, 0)
-        return (bi, jnp.minimum(ki, last), hi, 0)
+        return (bi, jnp.minimum(ki, last), 0, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(b, kvh, nk),
+        grid=(b, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, t * gp, hd), q_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
-            pl.BlockSpec((1, block_k, 1, hd), kv_map),
+            pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
+            pl.BlockSpec((1, block_k, kvh, hd), kv_map),
         ],
-        out_specs=pl.BlockSpec((1, 1, t * gp, hd), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((t * gp, hd), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-            pltpu.VMEM((t * gp, 1), jnp.float32),
-        ],
+        out_specs=pl.BlockSpec((1, kvh, t * gp, hd), q_map),
+        scratch_shapes=scratch_shapes(kvh, t * gp, hd),
     )
     kernel = functools.partial(
         _verify_kernel, block_k=block_k, chunk=t, gp=gp, sm_scale=hd**-0.5
@@ -170,8 +149,8 @@ def verify_attention(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
-        compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary")
         ),
         interpret=interpret,
     )(lengths, qr, k, v)

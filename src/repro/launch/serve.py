@@ -36,6 +36,7 @@ import jax
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serving.core import Priority, SamplingParams
 from repro.serving.engine import InferenceEngine
@@ -164,6 +165,7 @@ def main() -> None:
         "between them per quantum (DESIGN.md §10)",
     )
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     params = T.init_params(cfg, jax.random.PRNGKey(args.seed))

@@ -17,6 +17,7 @@ import time
 import numpy as np
 
 from repro import configs
+from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import SpecInFConfig, TrainConfig
 from repro.launch.mesh import make_dev_mesh, make_production_mesh
 from repro.runtime.trainer import Trainer
@@ -40,6 +41,7 @@ def main() -> None:
                     help="fill training bubbles with a collocated inference "
                          "engine (SpecInF)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = configs.smoke_config(args.arch) if args.smoke else configs.get_config(args.arch)
     tcfg = TrainConfig(
@@ -74,33 +76,41 @@ def main() -> None:
     )
 
 
-def _train_collocated(args, cfg, trainer) -> None:
-    """SpecInF end-to-end: the trainer's real step runs under the
-    speculative-filling runtime with a real inference engine."""
+def collocated_runtime(cfg, trainer, *, max_seq: int, train_step=None,
+                       batch_iter=None):
+    """SpecInF end-to-end: the trainer's step runs under the
+    speculative-filling runtime, and an inference engine serving the
+    trainer's own params (on one device, see ``InferenceEngine``) decodes
+    four offline requests in the bubbles of a ``dp_profile``.
+
+    ``train_step`` and ``batch_iter`` default to the trainer's jitted step
+    and data stream; the runtime starts from ``trainer.state``."""
     from repro.core import SpecInFRuntime
     from repro.core.profiles import dp_profile
-    from repro.serving.engine import InferenceEngine, Request
+    from repro.serving.core import Priority, SamplingParams
+    from repro.serving.engine import InferenceEngine
 
-    params = trainer.state["params"]
-    engine = InferenceEngine(cfg, params, max_slots=4, max_seq=args.seq_len)
+    engine = InferenceEngine(cfg, trainer.state["params"], max_slots=4,
+                             max_seq=max_seq)
     for i in range(4):
-        engine.add_request(
-            Request(prompt=np.arange(8) % cfg.vocab_size, max_new_tokens=10**9)
-        )
-
-    def step(state, batch):
-        return trainer.step_fn(state, batch)
+        engine.core.submit(np.arange(8 + i) % cfg.vocab_size,
+                           SamplingParams(max_new_tokens=10**9),
+                           priority=Priority.OFFLINE)
 
     def batches():
         while True:
             yield trainer._batch()
 
-    profile = dp_profile(cfg.name, compute_s=0.05, comm_s=0.025)
-    rt = SpecInFRuntime(
-        train_step=step, train_state=trainer.state, batch_iter=batches(),
-        profile=profile, engine=engine, cfg=SpecInFConfig(),
-        decode_microstep_s=0.004,
+    return SpecInFRuntime(
+        train_step=train_step or trainer.step_fn,
+        train_state=trainer.state, batch_iter=batch_iter or batches(),
+        profile=dp_profile(cfg.name, compute_s=0.05, comm_s=0.025),
+        engine=engine, cfg=SpecInFConfig(), decode_microstep_s=0.004,
     )
+
+
+def _train_collocated(args, cfg, trainer) -> None:
+    rt = collocated_runtime(cfg, trainer, max_seq=args.seq_len)
     t0 = time.time()
     metrics = rt.run(args.steps)
     dt = time.time() - t0

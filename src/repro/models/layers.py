@@ -4,8 +4,11 @@ SwiGLU MLP.  Pure functions over explicit parameter pytrees.
 Attention exposes three execution paths:
   * ``xla``       -- plain einsum softmax (small sequences)
   * ``xla_flash`` -- lax.scan blocked online-softmax (long prefill; no S^2 buffer)
-  * ``pallas``    -- Pallas TPU flash kernel (kernels/flash_attention.py)
-The path is chosen by ``repro.kernels.ops.attention`` unless forced.
+  * ``pallas``    -- Pallas TPU flash kernel (kernels/flash_attention.py),
+                     forward only
+The path is chosen by ``repro.kernels.ops.attention`` unless forced; the
+train step forces the XLA family ("xla_auto") because the kernel has no
+backward pass.
 """
 from __future__ import annotations
 

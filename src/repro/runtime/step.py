@@ -92,6 +92,16 @@ def make_train_step(
     *,
     impl: str = "auto",
 ) -> TrainStepArtifacts:
+    """``impl`` is the attention path of the loss.  The Pallas flash kernel
+    has no VJP, so "auto" means "xla_auto" here (the XLA family on every
+    backend) and "pallas" is refused."""
+    if impl == "pallas":
+        raise ValueError(
+            "the Pallas flash attention kernel is forward only (no VJP); "
+            "train with impl='auto', 'xla_auto', 'xla' or 'xla_flash'"
+        )
+    if impl == "auto":
+        impl = "xla_auto"
     schedule = make_schedule(tcfg)
     compute_dtype = jnp.dtype(tcfg.compute_dtype)
     n_micro = max(1, tcfg.microbatches)

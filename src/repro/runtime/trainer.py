@@ -3,7 +3,10 @@
 Production-loop features (DESIGN.md §3 runtime):
   * checkpoint/restart  -- atomic async checkpoints every N steps; on step
     failure the loop restores the latest complete checkpoint (params, opt
-    state, data-stream position) and continues
+    state, data-stream position) and continues.  A step that keeps failing
+    (a compile error, a device out-of-memory) re-raises after
+    ``MAX_FAILURES_WITHOUT_PROGRESS`` failures in which no step got past the
+    furthest failed one, instead of restoring forever
   * straggler mitigation -- per-step EMA timing; a straggling step (or an
     external straggler signal) triggers SpecInF *filling backoff*: the
     collocated-inference token ceiling is scaled down so recovery compute
@@ -38,6 +41,10 @@ class TrainerReport:
 
 
 class Trainer:
+    #: failed steps, with no step succeeding past the furthest of them, after
+    #: which ``train`` re-raises the step's exception
+    MAX_FAILURES_WITHOUT_PROGRESS = 3
+
     def __init__(
         self,
         cfg: ModelConfig,
@@ -102,6 +109,10 @@ class Trainer:
 
     def train(self, num_steps: int) -> TrainerReport:
         target = self.step_no + num_steps
+        # failed steps since the run last got past the furthest step that
+        # failed: re-running the steps between a checkpoint and a step that
+        # always fails is no progress
+        failures, failed_at = 0, -1
         while self.step_no < target:
             batch = self._batch()
             t0 = time.monotonic()
@@ -111,6 +122,10 @@ class Trainer:
                 self.state, metrics = self.step_fn(self.state, batch)
                 loss = float(metrics["loss"])
             except Exception:
+                failures += 1
+                failed_at = max(failed_at, self.step_no)
+                if failures >= self.MAX_FAILURES_WITHOUT_PROGRESS:
+                    raise
                 if not self.restore_latest():
                     # no checkpoint yet: restart from scratch, same seed
                     self.state = self.artifacts.init_state(
@@ -120,6 +135,8 @@ class Trainer:
                     self.step_no = 0
                     self.report.restores += 1
                 continue
+            if self.step_no >= failed_at:
+                failures = 0
             dt = time.monotonic() - t0
             self.step_no += 1
             self.report.steps += 1

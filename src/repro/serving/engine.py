@@ -113,6 +113,19 @@ DEFAULT_PREFILL_CHUNK = 32
 _ATTENTION_FAMILIES = ("dense", "moe", "audio", "vlm")
 
 
+def _on_one_device(tree: Any) -> Any:
+    """The engine runs on one device: its cache and token arrays are made on
+    the default device, and the TPU lowering cannot partition its Pallas
+    kernels over a mesh.  Params sharded over several devices (a collocated
+    trainer's) are copied onto the default device; params already on one
+    device are served as they are, without a copy."""
+    sharded = any(
+        isinstance(x, jax.Array) and len(x.sharding.device_set) > 1
+        for x in jax.tree.leaves(tree)
+    )
+    return jax.device_put(tree, jax.devices()[0]) if sharded else tree
+
+
 class RegistryCounterView:
     """Thin view (DESIGN.md §8): a historical ``InferenceEngine`` counter
     attribute backed by a ``repro.obs`` registry counter under a stable
@@ -212,7 +225,7 @@ class InferenceEngine:
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.compute_dtype = compute_dtype
-        self.params = params
+        self.params = _on_one_device(params)
         self.clock: Callable[[], float] = clock or time.monotonic
         self.min_prefill_bucket = min_prefill_bucket
         #: decode-path attention impl, kept for programs built after
@@ -378,7 +391,7 @@ class InferenceEngine:
 
         # --- speculative decoding (draft/target pairing) ---------------
         self.draft_cfg = draft_cfg
-        self.draft_params = draft_params
+        self.draft_params = _on_one_device(draft_params)
         self.draft_cache = None
         self.spec_cfg = spec or SpecDecodeConfig()
         #: PRNG stream for simulated-acceptance modes (spec loop AND the

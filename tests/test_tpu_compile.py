@@ -1,0 +1,175 @@
+"""Ahead-of-time compiles for a described TPU v5e, at real widths.
+
+The Pallas interpreter that the other kernel tests use applies none of the
+TPU lowering rules (block-shape tiling, Mosaic's supported ops, VMEM
+limits).  These tests hand the kernels and one training step to the TPU
+compiler for a ``v5e:2x2`` topology that is described, not attached: what
+the compiler refuses here, the chip would refuse too.  Nothing runs, so
+they say nothing about results or speed.
+
+Widths are the serving path's own: qwen3-1.7b attention (16 query heads, 8
+kv heads, head_dim 128) in bf16, the engine's page size and prefill chunk,
+the dense kernels' default ``block_k``; falcon-mamba-7b's selective scan.
+
+The topology is described inside a module fixture, never at import: only
+one process at a time may load the TPU library, and every test worker
+imports this file.
+"""
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import AxisType, Mesh, SingleDeviceSharding
+
+from repro import configs
+from repro.configs.base import ShapeConfig, TrainConfig
+from repro.kernels.decode_attention import decode_attention
+from repro.kernels.flash_attention import flash_attention
+from repro.kernels.paged_decode_attention import paged_decode_attention
+from repro.kernels.paged_prefill_attention import paged_prefill_attention
+from repro.kernels.paged_tree_verify_attention import (
+    paged_tree_verify_attention,
+)
+from repro.kernels.paged_verify_attention import paged_verify_attention
+from repro.kernels.prefill_attention import prefill_attention
+from repro.kernels.ssm_scan import ssm_scan_chunk
+from repro.kernels.tree_verify_attention import tree_verify_attention
+from repro.kernels.verify_attention import verify_attention
+from repro.models.ssm import DEFAULT_CHUNK as SSM_CHUNK
+from repro.runtime.step import make_train_step
+from repro.serving.engine import DEFAULT_KV_PAGE_SIZE, DEFAULT_PREFILL_CHUNK
+
+QWEN3 = configs.get_config("qwen3-1.7b")
+H, KVH, HD = QWEN3.num_heads, QWEN3.num_kv_heads, QWEN3.resolved_head_dim
+B = 8  # serving slots
+S = 512  # dense cache length (two 256-token KV tiles)
+PAGE = DEFAULT_KV_PAGE_SIZE
+W = S // PAGE + 1  # block-table columns, the last one the overflow sentinel
+POOL = B * (W - 1) + 1  # physical pages, page 0 the sentinel
+T = 5  # chunk-verify rows (gamma = 4) and packed-tree nodes
+C = DEFAULT_PREFILL_CHUNK
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2"
+        )
+    except Exception as e:  # no TPU compiler / library lock held elsewhere
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module", autouse=True)
+def no_persistent_cache():
+    """A compile for a described chip is written to the persistent cache but
+    cannot be read back without the chip; keep it out of the cache."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+
+    prev = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", prev)
+    cc.reset_cache()
+
+
+def _sds(sharding, shape, dtype=jnp.bfloat16):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _kernel_cases(dev):
+    bf = lambda *shape: _sds(dev, shape)
+    i32 = lambda *shape: _sds(dev, shape, jnp.int32)
+    dense_kv = (bf(B, S, KVH, HD), bf(B, S, KVH, HD))
+    paged_kv = (bf(POOL, PAGE, KVH, HD), bf(POOL, PAGE, KVH, HD))
+    return {
+        "decode": (decode_attention, (bf(B, H, HD), *dense_kv, i32(B))),
+        "paged_decode": (
+            paged_decode_attention,
+            (bf(B, H, HD), *paged_kv, i32(B, W), i32(B)),
+        ),
+        "verify": (verify_attention, (bf(B, T, H, HD), *dense_kv, i32(B))),
+        "paged_verify": (
+            paged_verify_attention,
+            (bf(B, T, H, HD), *paged_kv, i32(B, W), i32(B)),
+        ),
+        "tree_verify": (
+            tree_verify_attention,
+            (bf(B, T, H, HD), *dense_kv, i32(B), i32(B, T)),
+        ),
+        "paged_tree_verify": (
+            paged_tree_verify_attention,
+            (bf(B, T, H, HD), *paged_kv, i32(B, W), i32(B), i32(B, T)),
+        ),
+        "prefill": (
+            prefill_attention,
+            (bf(B, C, H, HD), *dense_kv, i32(B), i32(B)),
+        ),
+        "paged_prefill": (
+            paged_prefill_attention,
+            (bf(B, C, H, HD), *paged_kv, i32(B, W), i32(B), i32(B)),
+        ),
+    }
+
+
+KERNELS = (
+    "decode", "paged_decode", "verify", "paged_verify", "tree_verify",
+    "paged_tree_verify", "prefill", "paged_prefill",
+)
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_attention_kernel_compiles_for_v5e(name, one_chip):
+    fn, args = _kernel_cases(one_chip)[name]
+    compiled = jax.jit(fn).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flash_attention_compiles_for_v5e(one_chip):
+    """The forward-only kernel ``ops.attention(impl="auto")`` picks on TPU
+    (monolithic prefill): GQA heads pre-expanded, a 1024-token prompt."""
+    q = _sds(one_chip, (1, H, 1024, HD))
+    compiled = jax.jit(flash_attention).lower(q, q, q).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_ssm_scan_compiles_for_v5e(one_chip):
+    cfg = configs.get_config("falcon-mamba-7b")
+    f32 = lambda *shape: _sds(one_chip, shape, jnp.float32)
+    di, ds = cfg.d_inner, cfg.ssm_state
+    args = (
+        f32(1, SSM_CHUNK, di), f32(1, SSM_CHUNK, di), f32(1, SSM_CHUNK, ds),
+        f32(1, SSM_CHUNK, ds), f32(di, ds), f32(1, di, ds),
+    )
+    compiled = jax.jit(ssm_scan_chunk).lower(*args).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_qwen3_layer_train_step_compiles_for_v5e(topo):
+    """Forward and backward (plus clip and AdamW) of one qwen3-width layer
+    through the real train-step builder, on a one-chip mesh of the described
+    topology: the loss path must differentiate on the chip."""
+    cfg = dataclasses.replace(QWEN3, num_layers=1)
+    mesh = Mesh(
+        np.array(topo.devices[:1]).reshape(1, 1), ("data", "model"),
+        axis_types=(AxisType.Auto, AxisType.Auto),
+    )
+    tcfg = TrainConfig(fsdp=False, zero1=False, remat_policy="full")
+    art = make_train_step(cfg, tcfg, mesh)
+    shape = ShapeConfig("t", seq_len=256, global_batch=2, kind="train")
+    compiled = art.jitted(donate=True).lower(
+        art.abstract_state(), art.abstract_batch(shape)
+    ).compile()
+    # params + AdamW moments in f32 are the step's arguments
+    n = cfg.param_count()
+    assert compiled.memory_analysis().argument_size_in_bytes >= 12 * n

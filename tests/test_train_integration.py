@@ -1,5 +1,6 @@
 """Integration tests for the production train step builder on a 1x1 dev
-mesh: loss descent, microbatch equivalence, fault-tolerant resume."""
+mesh: loss descent, microbatch equivalence, fault-tolerant resume, and the
+Trainer's bounded retry of failing steps."""
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -10,7 +11,9 @@ from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs.base import TrainConfig
 from repro.data.pipeline import SyntheticDataset
 from repro.launch.mesh import make_dev_mesh
+from repro.models.act_sharding import activation_sharding, shard
 from repro.runtime.step import make_train_step
+from repro.runtime.trainer import Trainer
 
 
 @pytest.fixture(scope="module")
@@ -129,3 +132,124 @@ def test_grad_compression_state_threads_through(mesh):
     err_norm = sum(float(jnp.abs(e).sum()) for e in jax.tree.leaves(new_state["err"]))
     assert err_norm > 0  # quantization residual captured
     assert np.isfinite(float(m["loss"]))
+
+
+def test_activation_constraint_under_jit_on_dev_mesh(mesh):
+    """Regression: ``make_dev_mesh`` builds Auto axes, so an activation
+    constraint traced under jit resolves instead of raising "can only refer
+    to Auto axes" (the default axis type of ``jax.make_mesh`` is Explicit)."""
+    from jax.sharding import PartitionSpec as P
+
+    @jax.jit
+    def f(x):
+        with activation_sharding(mesh, {"btd": P("data", None, "model")}):
+            return shard(x, "btd") * 2.0
+
+    np.testing.assert_array_equal(np.asarray(f(jnp.ones((2, 4, 8)))), 2.0)
+
+
+def test_train_step_refuses_forward_only_flash_kernel(mesh):
+    cfg = configs.smoke_config("olmo-1b")
+    with pytest.raises(ValueError, match="forward only"):
+        make_train_step(cfg, TrainConfig(fsdp=False), mesh, impl="pallas")
+
+
+def test_train_step_auto_attention_is_xla_where_forward_auto_is_the_kernel(
+    mesh, monkeypatch
+):
+    """On a TPU ``ops.attention(impl="auto")`` picks the forward-only flash
+    kernel; the train step's "auto" must pick the XLA family instead."""
+    from repro.kernels import ops
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    q = jnp.zeros((1, 8, 2, 16))
+    fwd = jax.make_jaxpr(lambda q: ops.attention(q, q, q))(q)
+    assert "pallas_call" in str(fwd)
+
+    cfg = configs.smoke_config("olmo-1b")
+    art = make_train_step(cfg, TrainConfig(fsdp=False, zero1=False), mesh)
+    state = art.init_state(jax.random.PRNGKey(0))
+    batch = _batch(SyntheticDataset(cfg=cfg, seq_len=16, global_batch=4))
+    assert "pallas_call" not in str(jax.make_jaxpr(art.step)(state, batch))
+
+
+def test_collocated_runtime_serves_the_trainer_params(mesh):
+    """``launch/train.py --collocate``: on a one-device mesh the engine
+    serves the trainer's own param buffers, and filling leaves the
+    training trajectory finite."""
+    from repro.launch.train import collocated_runtime
+
+    cfg = configs.smoke_config("qwen3-1.7b")
+    tcfg = TrainConfig(fsdp=False, zero1=False)
+    trainer = Trainer(cfg, tcfg, mesh, seq_len=32, global_batch=4)
+    rt = collocated_runtime(cfg, trainer, max_seq=32)
+    assert rt.engine.params is trainer.state["params"]
+    metrics = rt.run(2)
+    assert metrics.train_iterations == 2
+    assert np.isfinite(metrics.train_losses).all()
+
+
+def _trainer(mesh, tmp_path):
+    cfg = configs.smoke_config("olmo-1b")
+    tcfg = TrainConfig(learning_rate=1e-3, microbatches=1, fsdp=False,
+                       zero1=False)
+    return Trainer(cfg, tcfg, mesh, seq_len=16, global_batch=4,
+                   checkpoint_dir=str(tmp_path), checkpoint_every=2)
+
+
+def test_trainer_resumes_after_one_failed_step(mesh, tmp_path):
+    trainer = _trainer(mesh, tmp_path)
+    failed = []
+
+    def fail_once_at_3(step_no):
+        if step_no == 3 and not failed:
+            failed.append(step_no)
+            return True
+        return False
+
+    trainer.fail_hook = fail_once_at_3
+    report = trainer.train(5)
+    assert failed == [3]
+    assert trainer.step_no == 5
+    assert report.restores == 1
+    assert np.isfinite(report.losses).all()
+
+
+def test_trainer_reraises_a_step_that_always_fails(mesh, tmp_path):
+    """A step that fails on every fresh or restored state (a compile error,
+    a device OOM) ends the run after a bounded number of attempts."""
+    trainer = _trainer(mesh, tmp_path)
+    attempts = []
+
+    def always(step_no):
+        attempts.append(step_no)
+        return True
+
+    trainer.fail_hook = always
+    with pytest.raises(RuntimeError, match="injected failure"):
+        trainer.train(3)
+    assert len(attempts) == Trainer.MAX_FAILURES_WITHOUT_PROGRESS
+    assert trainer.report.restores == Trainer.MAX_FAILURES_WITHOUT_PROGRESS - 1
+    assert trainer.report.steps == 0
+
+
+def test_trainer_reraises_a_step_that_always_fails_after_a_checkpoint(
+    mesh, tmp_path
+):
+    """Steps between the restored checkpoint and the failing step succeed on
+    every retry; that is no progress, so the run still ends."""
+    trainer = _trainer(mesh, tmp_path)  # checkpoints every 2 steps
+    attempts = []
+
+    def always_at_3(step_no):
+        attempts.append(step_no)
+        if len(attempts) > 30:  # BaseException: escapes the retry loop
+            pytest.fail("the trainer kept retrying the failing step")
+        return step_no == 3
+
+    trainer.fail_hook = always_at_3
+    with pytest.raises(RuntimeError, match="injected failure @ step 3"):
+        trainer.train(6)
+    assert attempts.count(3) == Trainer.MAX_FAILURES_WITHOUT_PROGRESS
+    assert trainer.report.restores == Trainer.MAX_FAILURES_WITHOUT_PROGRESS - 1
+    assert trainer.report.steps > 3  # steps before 3 were re-run
