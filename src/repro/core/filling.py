@@ -309,6 +309,9 @@ class SpecInFRuntime:
         self.metrics = FillingMetrics(
             obs=engine.obs if engine is not None else None
         )
+        #: host spans (``runtime.train_step`` / ``runtime.fill`` /
+        #: ``runtime.monitor``) land on the same bundle
+        self.obs = self.metrics.obs
         self.decode_microstep_s = decode_microstep_s
         # Speculative engines spend grants in verified tokens: the gamma
         # controller sizes each round from phase + observed acceptance,
@@ -385,13 +388,17 @@ class SpecInFRuntime:
     def _observe_windows(self, n: int, activity: int = 0):
         """Feed monitor + Algorithm 1 for ``n`` windows; returns the last
         decision.  One observe per window keeps accounting identical whether
-        microsteps run fused or one-by-one."""
+        microsteps run fused or one-by-one.  Runs in the ``runtime.monitor``
+        host span."""
         d = None
-        for _ in range(n):
-            zc = self.monitor.observe(activity)
-            d = self.scheduler.update(zc)
-            ph = d.phase.value
-            self.metrics.phase_counts[ph] = self.metrics.phase_counts.get(ph, 0) + 1
+        with self.obs.span("runtime.monitor"):
+            for _ in range(n):
+                zc = self.monitor.observe(activity)
+                d = self.scheduler.update(zc)
+                ph = d.phase.value
+                self.metrics.phase_counts[ph] = (
+                    self.metrics.phase_counts.get(ph, 0) + 1
+                )
         return d
 
     def _advance_windows(self, span_s: float, activity: int) -> None:
@@ -538,12 +545,18 @@ class SpecInFRuntime:
 
     # ------------------------------------------------------------------
     def run(self, num_iterations: int) -> FillingMetrics:
+        """Run ``num_iterations`` training iterations: the train step (the
+        ``runtime.train_step`` host span, its loss's fetch included), then
+        the profile's segments, each bubble filled in a ``runtime.fill``
+        span."""
+        span = self.obs.span
         for _ in range(num_iterations):
             batch = next(self.batch_iter)
-            self.state, step_metrics = self.train_step(self.state, batch)
-            loss = step_metrics.get("loss")
-            if loss is not None:
-                self.metrics.train_losses.append(float(loss))
+            with span("runtime.train_step"):
+                self.state, step_metrics = self.train_step(self.state, batch)
+                loss = step_metrics.get("loss")
+                if loss is not None:
+                    self.metrics.train_losses.append(float(loss))
             for kind, dur in self.profile.segments:
                 if kind == "compute":
                     t0 = self.metrics.virtual_time_s
@@ -554,7 +567,8 @@ class SpecInFRuntime:
                         )
                     self._advance_windows(dur, activity=1)
                 else:
-                    self._fill_bubble(dur)
+                    with span("runtime.fill"):
+                        self._fill_bubble(dur)
             self.metrics.train_iterations += 1
         return self.metrics
 

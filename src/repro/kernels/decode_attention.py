@@ -184,6 +184,7 @@ def decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -130,6 +130,7 @@ def flash_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(b * h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, block_q, hd), lambda bh, qi, ki: (bh, qi, 0)),

@@ -105,6 +105,7 @@ def paged_decode_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_decode_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -104,6 +104,7 @@ def paged_prefill_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, cp * gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -97,6 +97,7 @@ def paged_tree_verify_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="paged_tree_verify_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -190,6 +190,7 @@ def prefill_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="prefill_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, cp * gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

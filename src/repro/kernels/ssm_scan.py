@@ -76,6 +76,7 @@ def ssm_scan_chunk(
     kernel = functools.partial(_ssm_kernel, chunk=q)
     y, h_fin = pl.pallas_call(
         kernel,
+        name="ssm_scan_chunk",
         grid=(b, nd),
         in_specs=[
             pl.BlockSpec((1, q, block_d), lambda bi, d: (bi, 0, d)),

@@ -164,6 +164,7 @@ def tree_verify_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="tree_verify_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

@@ -147,6 +147,7 @@ def verify_attention(
     )
     out = pl.pallas_call(
         kernel,
+        name="verify_attention",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, kvh, t * gp, hd), q.dtype),
         compiler_params=pltpu.CompilerParams(

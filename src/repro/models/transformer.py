@@ -19,6 +19,7 @@ cycle.
 """
 from __future__ import annotations
 
+import functools
 from typing import Any, Optional
 
 import jax
@@ -141,14 +142,27 @@ def _maybe_remat(fn, policy: str):
 # ---------------------------------------------------------------------------
 
 
+def cast_params(tree: Params, dtype) -> Params:
+    """The float32 matrices of a params subtree in the compute ``dtype``
+    (vectors stay float32), under the ``cast_params`` name scope, so a
+    profile names the conversion wherever a program does it."""
+    with jax.named_scope("cast_params"):
+        return jax.tree.map(
+            lambda a: a.astype(dtype)
+            if a.dtype == jnp.float32 and a.ndim > 1 else a, tree)
+
+
 def embed_tokens(cfg: ModelConfig, params: Params, tokens: jax.Array, dtype):
-    return params["embed"].astype(dtype)[tokens]
+    with jax.named_scope("cast_params"):
+        table = params["embed"].astype(dtype)
+    return table[tokens]
 
 
 def unembed(cfg: ModelConfig, params: Params, x: jax.Array) -> jax.Array:
-    head = (
-        params["embed"].T if cfg.tie_embeddings else params["lm_head"]
-    ).astype(x.dtype)
+    with jax.named_scope("cast_params"):
+        head = (
+            params["embed"].T if cfg.tie_embeddings else params["lm_head"]
+        ).astype(x.dtype)
     return jnp.einsum("bsd,dv->bsv", x, head)
 
 
@@ -170,8 +184,7 @@ def forward(
         x = inputs.astype(compute_dtype)
     x = shard(x, "btd")
 
-    cast = lambda t: jax.tree.map(lambda a: a.astype(compute_dtype)
-                                  if a.dtype == jnp.float32 and a.ndim > 1 else a, t)
+    cast = functools.partial(cast_params, dtype=compute_dtype)
 
     if cfg.family == "hybrid":
         shared = cast(params["shared"])
@@ -333,10 +346,9 @@ def decode_step(
     ``cache["index"]`` may be scalar (uniform batch) or [B] per-slot
     positions (continuous batching).  ``attn_impl`` picks the decode
     attention core (see ``ops.decode_attention``)."""
-    x = params["embed"].astype(compute_dtype)[tokens][:, None, :]  # [B, 1, d]
+    x = embed_tokens(cfg, params, tokens, compute_dtype)[:, None, :]  # [B, 1, d]
     idx = cache["index"]
-    cast = lambda t: jax.tree.map(lambda a: a.astype(compute_dtype)
-                                  if a.dtype == jnp.float32 and a.ndim > 1 else a, t)
+    cast = functools.partial(cast_params, dtype=compute_dtype)
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
         bt = cache.get("block_tables")  # paged cache: [B, W] page map
@@ -494,12 +506,10 @@ def decode_chunk(
             f"tree verification needs an attention family, got {cfg.family!r}"
         )
     if cfg.family in ("dense", "moe", "audio", "vlm"):
-        x = params["embed"].astype(compute_dtype)[tokens]  # [B, T, d]
+        x = embed_tokens(cfg, params, tokens, compute_dtype)  # [B, T, d]
         idx = cache["index"]
         bt = cache.get("block_tables")  # paged cache: [B, W] page map
-        cast = lambda tr: jax.tree.map(
-            lambda a: a.astype(compute_dtype)
-            if a.dtype == jnp.float32 and a.ndim > 1 else a, tr)
+        cast = functools.partial(cast_params, dtype=compute_dtype)
 
         def body(xc, per_layer):
             lp, k_c, v_c = per_layer
@@ -662,8 +672,7 @@ def prefill(
         b, s, _ = inputs.shape
         x = inputs.astype(compute_dtype)
     positions = jnp.broadcast_to(jnp.arange(s), (b, s))
-    cast = lambda t: jax.tree.map(lambda a: a.astype(compute_dtype)
-                                  if a.dtype == jnp.float32 and a.ndim > 1 else a, t)
+    cast = functools.partial(cast_params, dtype=compute_dtype)
 
     def attn_prefill(lp, h):
         q, k, v = L._project_qkv(cfg, lp, h, positions)
@@ -944,9 +953,7 @@ def prefill_chunks_into_slots(
     idx = cache["index"]
     lens = jnp.asarray(chunk_lens, jnp.int32)
     bt = cache.get("block_tables")  # paged cache: [B, W] page map
-    cast = lambda tr: jax.tree.map(
-        lambda a: a.astype(compute_dtype)
-        if a.dtype == jnp.float32 and a.ndim > 1 else a, tr)
+    cast = functools.partial(cast_params, dtype=compute_dtype)
 
     def body(xc, per_layer):
         lp, k_c, v_c = per_layer

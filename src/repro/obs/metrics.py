@@ -85,6 +85,20 @@ STABLE_NAMES = {
     "core/generated_tokens/online": "counter",
     "core/generated_tokens/offline": "counter",
     "core/starved_quanta": "counter",
+    "core/quanta": "counter",
+    # host spans (repro.obs.trace): wall nanoseconds inside each span
+    "host_ns/runtime.train_step": "counter",
+    "host_ns/runtime.fill": "counter",
+    "host_ns/runtime.monitor": "counter",
+    "host_ns/core.step": "counter",
+    "host_ns/core.plan": "counter",
+    "host_ns/core.admit": "counter",
+    "host_ns/core.collect": "counter",
+    "host_ns/core.record": "counter",
+    "host_ns/engine.tables": "counter",
+    "host_ns/engine.prefill": "counter",
+    "host_ns/engine.decode": "counter",
+    "host_ns/engine.fetch": "counter",
     # failure containment + graceful degradation (DESIGN.md §9)
     "fault/injected": "counter",
     "fault/nan_quarantines": "counter",

@@ -11,8 +11,9 @@ or mistyped required fields are errors.
 """
 from __future__ import annotations
 
-__all__ = ["EVENT_SCHEMAS", "INSTANT_ARG_SCHEMAS", "SPAN_ARG_SCHEMAS",
-           "validate_event", "validate_events", "validate_jsonl"]
+__all__ = ["EVENT_SCHEMAS", "INSTANT_ARG_SCHEMAS", "QUANTUM_OPTIONAL_ARGS",
+           "SPAN_ARG_SCHEMAS", "validate_event", "validate_events",
+           "validate_jsonl"]
 
 
 def NULLABLE(t):
@@ -56,6 +57,13 @@ TRANSITION_STATES = {
 SPAN_ARG_SCHEMAS = {
     "recovery": {"requests": int, "tokens": int, "clock_shift": _NUM},
 }
+#: ``quantum`` args whose type is pinned WHERE PRESENT: the decoded slots
+#: (``{slot: request_id}``; JSON makes the slot keys strings) from which
+#: the Chrome export draws the per-slot decode spans, the engine-clock
+#: instant their decode began, and the step's wall-clock interval
+QUANTUM_OPTIONAL_ARGS = {
+    "decoded": dict, "decode_t0": _NUM, "wall_ns": list,
+}
 INSTANT_ARG_SCHEMAS = {
     "arrival_restamp": {"request_id": int, "old": _NUM, "new": _NUM},
 }
@@ -94,6 +102,17 @@ def validate_event(ev, where: str = "event") -> list:
             errors.append(f"{where}: unknown state {ev['to']!r}")
         if ev["frm"] is not None and ev["frm"] not in TRANSITION_STATES:
             errors.append(f"{where}: unknown state {ev['frm']!r}")
+    elif kind == "quantum":
+        args = ev["args"]
+        present = {k: v for k, v in QUANTUM_OPTIONAL_ARGS.items() if k in args}
+        _check_fields(args, present, f"{where}.args", errors)
+        wall = args.get("wall_ns")
+        if isinstance(wall, list) and not (
+            len(wall) == 2 and all(isinstance(x, int) for x in wall)
+            and wall[0] <= wall[1]
+        ):
+            errors.append(f"{where}.args: wall_ns must be [t0, t1] integers, "
+                          f"t0 <= t1 (got {wall!r})")
     elif kind == "span":
         args_schema = SPAN_ARG_SCHEMAS.get(ev["name"])
         if args_schema is not None:

@@ -1,40 +1,90 @@
-"""Structured step tracer (DESIGN.md §8): one event per scheduling quantum,
-plus request state transitions and per-slot spans, exported as JSONL and as
-a Chrome trace (open in ``chrome://tracing`` or https://ui.perfetto.dev).
+"""Structured step tracer and host spans (DESIGN.md §8).
 
-Every timestamp a tracer event carries comes from the ENGINE'S clock (the
-caller stamps; the tracer never reads a clock of its own), so a collocated
-virtual-clock run produces a trace entirely on the virtual timebase — the
-same single-clock rule the engine applies to request timestamps.  Event
-kinds (``repro.obs.schema`` is the authoritative field list):
+The step tracer records one event per scheduling quantum, plus request
+state transitions and prefill spans, exported as JSONL and as a Chrome
+trace (open in ``chrome://tracing`` or https://ui.perfetto.dev).
+
+Two clocks, each for its own reader:
+
+* The ENGINE'S clock stamps every tracer event (the caller stamps; the
+  tracer never reads a clock of its own).  A collocated runtime binds it to
+  its virtual clock, on which Algorithm 1's gates and the SLO attribution
+  run, so a collocated trace is entirely on the virtual timebase.
+* The WALL clock, ``wall_ns`` (``time.perf_counter_ns``), times the host
+  spans (``Observability.span``) and the requests' wall stamps
+  (``EngineRequest.arrival_wall_ns`` / ``admit_wall_ns`` /
+  ``first_token_wall_ns`` / ``finish_wall_ns``), and each quantum record
+  carries its ``wall_ns: [t0, t1]``.  It is for observability only: no
+  scheduling decision reads it.
+
+Event kinds (``repro.obs.schema`` is the authoritative field list):
 
 * ``quantum`` — one per ``EngineCore.step()``: the grant, the policy plan
   (k / gamma / admissions / preemptions / prefill budget), realized token
-  costs, the clock advance, and the bubble-monitor window state when a
-  SpecInF runtime drove the step.
+  costs, the clock advance, the bubble-monitor window state when a
+  SpecInF runtime drove the step, the slots the fused loop decoded
+  (``decoded: {slot: request_id}``, from ``decode_t0`` to ``t1``) and the
+  wall-clock interval of the step.
 * ``transition`` — one per request state change (WAITING at submission,
   admissions, preemptions, finishes), the raw material SLO attribution
   (``repro.obs.attribution``) decomposes into queueing / prefill / decode /
   preempted segments.
 * ``span`` — an interval on a named track: ``train`` carries training
-  compute and bubble spans; ``slot{i}`` carries that slot's prefill chunks,
-  decode runs, and spec rounds.  Intra-quantum sub-spans are positioned by
-  the plan's deterministic cost split (exact token counts ride in ``args``).
+  compute and bubble spans; ``slot{i}`` carries that slot's prefill
+  chunks.  Intra-quantum sub-spans are positioned by the plan's
+  deterministic cost split (exact token counts ride in ``args``).  The
+  per-slot decode / spec-round intervals are not recorded as spans: the
+  Chrome export draws them on the slot tracks from each quantum's
+  ``decoded`` map.
 * ``instant`` — point events (a request's first token).
 
 Memory is bounded: past ``max_events`` the tracer counts drops instead of
 growing (``dropped``); a disabled tracer records nothing and costs one
 attribute check per call site.
+
+Host spans: ``with obs.span("core.step"):`` enters a
+``jax.profiler.TraceAnnotation`` named ``specinf.core.step`` (so the span
+lands in any profiler trace on the device operations' clock) and adds its
+wall time to the registry counter ``host_ns/core.step``.  Spans of one
+name never nest (opening one inside itself raises).  The spans, outermost
+first:
+
+* ``runtime.train_step`` — the train-step call of ``SpecInFRuntime.run``
+  and the loss's fetch; ``runtime.fill`` — one bubble's fill;
+  ``runtime.monitor`` — the bubble monitor and Algorithm 1 over a run of
+  2-ms windows (in the fill, and over the compute segments).
+* ``core.step`` — one ``EngineCore.step`` (annotated with its quantum
+  record's ``seq``), holding ``core.plan`` (deadline sweep, overload
+  ladder, the policy's plan), ``core.admit`` (preemptions and
+  admissions), ``engine.prefill`` / ``engine.decode`` (the engine's host
+  work and dispatches around its fetches), ``core.collect`` (absorbing
+  outputs, finishes, per-request deltas, the journal append) and
+  ``core.record`` (gauges and the tracer).
+* ``engine.tables`` — block-table top-ups and uploads; ``engine.fetch`` —
+  every blocking device-to-host copy of the engine: the host waiting on
+  the device.
+
+``core/quanta`` counts ``EngineCore.step`` calls.
 """
 from __future__ import annotations
 
 import json
 import math
+import time
 from typing import Optional
 
-__all__ = ["StepTracer", "Observability", "chrome_trace", "TRACE_VERSION"]
+from jax.profiler import TraceAnnotation
+
+__all__ = ["StepTracer", "Observability", "chrome_trace", "wall_ns",
+           "TRACE_VERSION"]
 
 TRACE_VERSION = 1
+
+#: the wall clock of host spans and request wall stamps (nanoseconds)
+wall_ns = time.perf_counter_ns
+
+#: prefix of every host span's name in a profiler trace
+SPAN_PREFIX = "specinf."
 
 
 def _num(x):
@@ -63,18 +113,20 @@ class StepTracer:
         self.window_state: Optional[dict] = None
 
     # ------------------------------------------------------------------
-    def _emit(self, ev: dict) -> None:
+    def _emit(self, ev: dict) -> Optional[int]:
+        """Record ``ev``; returns its ``seq``, or None if it was not kept."""
         if not self.enabled:
-            return
+            return None
         if len(self.events) >= self.max_events:
             self.dropped += 1
-            return
-        ev["seq"] = self._seq
+            return None
+        ev["seq"] = seq = self._seq
         self._seq += 1
         self.events.append(ev)
+        return seq
 
-    def quantum(self, t0: float, t1: float, **args) -> None:
-        self._emit({
+    def quantum(self, t0: float, t1: float, **args) -> Optional[int]:
+        return self._emit({
             "type": "quantum", "t0": float(t0), "t1": float(t1),
             "args": args,
         })
@@ -152,7 +204,9 @@ def chrome_trace(events: list) -> dict:
     quanta become complete ('X') events, instants/transitions become
     instant ('i') events, and each track becomes a named thread so Perfetto
     shows training, bubbles, the control plane, and every slot as parallel
-    timelines.  Timestamps convert from engine-clock seconds to µs."""
+    timelines.  A quantum's ``decoded`` slots become ``decode`` (or
+    ``spec_round``) spans on their slot tracks, from its ``decode_t0`` to
+    its end.  Timestamps convert from engine-clock seconds to µs."""
     tids: dict = {}
 
     def tid(track: str) -> int:
@@ -173,6 +227,20 @@ def chrome_trace(events: list) -> dict:
                 "dur": max(ev["t1"] - ev["t0"], 0.0) * 1e6,
                 "pid": 0, "tid": tid("control"), "args": ev["args"],
             })
+            a = ev["args"]
+            decoded = a.get("decoded") or {}
+            t_mid = a.get("decode_t0", ev["t0"])
+            name = "spec_round" if a.get("gamma") is not None else "decode"
+            for slot, rid in decoded.items():
+                out.append({
+                    "ph": "X", "name": name, "cat": "span",
+                    "ts": t_mid * 1e6,
+                    "dur": max(ev["t1"] - t_mid, 0.0) * 1e6,
+                    "pid": 0, "tid": tid(f"slot{slot}"),
+                    "args": {"k": a.get("k"), "gamma": a.get("gamma"),
+                             "proposer": a.get("proposer"),
+                             "request_id": rid},
+                })
         elif kind == "span":
             out.append({
                 "ph": "X", "name": ev["name"], "cat": "span",
@@ -223,3 +291,42 @@ class Observability:
 
         self.metrics = MetricsRegistry()
         self.tracer = StepTracer(enabled=tracing, max_events=max_events)
+        #: span name -> its reusable ``_HostSpan`` (spans of one name never
+        #: nest, so one object per name serves every use)
+        self._spans: dict = {}
+
+    def span(self, name: str):
+        """Host span ``name`` (module docstring): a profiler annotation
+        ``specinf.<name>`` plus its wall time on ``host_ns/<name>``.  The
+        context's value has ``set_metadata(**kw)``, which annotates the
+        span in the profile."""
+        sp = self._spans.get(name)
+        if sp is None:
+            sp = self._spans[name] = _HostSpan(
+                name, self.metrics.counter("host_ns/" + name)
+            )
+        return sp
+
+
+class _HostSpan:
+    __slots__ = ("label", "counter", "open", "_ann", "_t0")
+
+    def __init__(self, name: str, counter):
+        self.label = SPAN_PREFIX + name
+        self.counter = counter
+        self.open = False
+
+    def __enter__(self) -> TraceAnnotation:
+        if self.open:
+            raise RuntimeError(f"host span {self.label!r} opened inside itself")
+        self.open = True
+        self._ann = ann = TraceAnnotation(self.label)
+        ann.__enter__()
+        self._t0 = wall_ns()
+        return ann
+
+    def __exit__(self, *exc) -> None:
+        self.counter.value += wall_ns() - self._t0
+        self._ann.__exit__(*exc)
+        self.open = False
+

@@ -143,32 +143,35 @@ def make_train_step(
         params, opt = state["params"], state["opt"]
         inputs, labels = batch["inputs"], batch["labels"]
 
-        if n_micro == 1:
-            (loss, metrics), grads = grad_fn(params, inputs, labels)
-            grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
-        else:
-            inputs_m = _constrain_micro(_microbatch_split(inputs, n_micro))
-            labels_m = _constrain_micro(_microbatch_split(labels, n_micro))
+        # the phases run under name scopes, so a profile attributes each
+        # operation to forward_backward, clip_grads or adamw_update
+        with jax.named_scope("forward_backward"):
+            if n_micro == 1:
+                (loss, metrics), grads = grad_fn(params, inputs, labels)
+                grads = jax.tree.map(lambda g: g.astype(jnp.float32), grads)
+            else:
+                inputs_m = _constrain_micro(_microbatch_split(inputs, n_micro))
+                labels_m = _constrain_micro(_microbatch_split(labels, n_micro))
 
-            def micro(acc, xs):
-                inp, lab = xs
-                (l, m), g = grad_fn(params, inp, lab)
-                acc = jax.tree.map(
-                    lambda a, gg: a + gg.astype(jnp.float32), acc, g
+                def micro(acc, xs):
+                    inp, lab = xs
+                    (l, m), g = grad_fn(params, inp, lab)
+                    acc = jax.tree.map(
+                        lambda a, gg: a + gg.astype(jnp.float32), acc, g
+                    )
+                    acc = jax.lax.with_sharding_constraint(acc, param_sh)
+                    return acc, (l, m["ce"], m["moe_aux"])
+
+                acc0 = jax.tree.map(
+                    lambda p: jnp.zeros(p.shape, jnp.float32), params
                 )
-                acc = jax.lax.with_sharding_constraint(acc, param_sh)
-                return acc, (l, m["ce"], m["moe_aux"])
-
-            acc0 = jax.tree.map(
-                lambda p: jnp.zeros(p.shape, jnp.float32), params
-            )
-            grads, (losses, ces, auxes) = jax.lax.scan(
-                micro, acc0, (inputs_m, labels_m)
-            )
-            grads = jax.tree.map(lambda g: g / n_micro, grads)
-            loss = losses.mean()
-            metrics = {"ce": ces.mean(), "moe_aux": auxes.mean()}
-        grads = jax.lax.with_sharding_constraint(grads, param_sh)
+                grads, (losses, ces, auxes) = jax.lax.scan(
+                    micro, acc0, (inputs_m, labels_m)
+                )
+                grads = jax.tree.map(lambda g: g / n_micro, grads)
+                loss = losses.mean()
+                metrics = {"ce": ces.mean(), "moe_aux": auxes.mean()}
+            grads = jax.lax.with_sharding_constraint(grads, param_sh)
 
         if tcfg.grad_compression == "int8_ef":
             # int8 error-feedback quantization of the cross-device gradient
@@ -185,9 +188,13 @@ def make_train_step(
                 lambda t: t[1], pairs, is_leaf=lambda t: isinstance(t, tuple)
             )
 
-        grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip_norm)
-        lr = schedule(opt["step"])
-        new_params, new_opt = adamw_update(grads, opt, params, lr=lr, cfg=tcfg)
+        with jax.named_scope("clip_grads"):
+            grads, gnorm = clip_by_global_norm(grads, tcfg.grad_clip_norm)
+        with jax.named_scope("adamw_update"):
+            lr = schedule(opt["step"])
+            new_params, new_opt = adamw_update(
+                grads, opt, params, lr=lr, cfg=tcfg
+            )
         new_state = {"params": new_params, "opt": new_opt}
         if tcfg.grad_compression == "int8_ef":
             new_state["err"] = new_err

@@ -46,6 +46,7 @@ from typing import Any, Callable, Iterator, Optional, Union
 import numpy as np
 
 from repro.obs.trace import _num as _jnum
+from repro.obs.trace import wall_ns
 from repro.serving.engine import DECODE_K_BUCKETS, InferenceEngine, Request
 
 __all__ = [
@@ -147,6 +148,15 @@ class EngineRequest:
     finish_time: Optional[float] = None
     finish_reason: Optional[str] = None
     preemptions: int = 0
+    #: wall-clock stamps (``repro.obs.wall_ns``, for observability only;
+    #: the ``*_time`` fields above are on the engine's clock, which the
+    #: policy reads): submission, the FIRST admission, the host's receipt
+    #: of the first token (right after the fetch that delivered it), and
+    #: the finish
+    arrival_wall_ns: Optional[int] = None
+    admit_wall_ns: Optional[int] = None
+    first_token_wall_ns: Optional[int] = None
+    finish_wall_ns: Optional[int] = None
     #: fault-containment bookkeeping (DESIGN.md §9): quarantines survived,
     #: and the engine-clock instant before which admission must not retry
     #: (exponential backoff after each quarantine)
@@ -301,6 +311,9 @@ class StepOutputs:
     #: ``k`` and ``cost_steps`` then reflect the microsteps actually run,
     #: not the plan (exact partial-quantum accounting, DESIGN.md §9)
     revoked: bool = False
+    #: ``seq`` of this step's ``quantum`` trace record (None when the
+    #: tracer kept none); the ``core.step`` host span carries it too
+    seq: Optional[int] = None
 
 
 def largest_bucket(n: int, buckets: tuple = DECODE_K_BUCKETS) -> int:
@@ -570,6 +583,7 @@ class EngineCore:
         #: so a killed engine can replay them into a fresh core
         #: (DESIGN.md §11)
         self.journal = None
+        self._quanta = self.obs.metrics.counter("core/quanta")
 
     # ------------------------------------------------------------------
     # Submission / queries
@@ -601,6 +615,7 @@ class EngineCore:
         cr = EngineRequest(
             prompt=prompt, sampling=sampling, priority=priority,
             request_id=probe.request_id, arrival_time=arrival_time,
+            arrival_wall_ns=wall_ns(),
         )
         self.waiting[priority].append(cr)
         self.requests[cr.request_id] = cr
@@ -646,7 +661,19 @@ class EngineCore:
         prompt completes mid-step starts decoding in the same quantum.  The
         whole mixed batch is priced deterministically BEFORE any device
         work runs, so virtual-clock callers stamp retirements at the true
-        quantum end and no step can exceed its granted budget."""
+        quantum end and no step can exceed its granted budget.
+
+        The step runs inside the ``core.step`` host span, annotated with
+        the ``seq`` of its quantum record (``repro.obs.trace``)."""
+        self._quanta.inc()
+        with self.obs.span("core.step") as span:
+            out = self._step(grant)
+            if out.seq is not None:
+                span.set_metadata(seq=out.seq)
+        return out
+
+    def _step(self, grant: Optional[Grant]) -> StepOutputs:
+        w0 = wall_ns()
         g = grant if grant is not None else Grant()
         if g.now is None:
             g = dataclasses.replace(g, now=self.engine.clock())
@@ -666,37 +693,39 @@ class EngineCore:
         # engine's layout-independent meter prices it identically to the
         # chunk waves, so cost accounting never depends on the layout
         m0 = eng.prefill_metered_tokens
-        self._expire_deadlines(g.now)
-        if g.token_budget <= 0:
-            # degenerate grant (DESIGN.md §9): an explicit no-op quantum —
-            # nothing is planned or driven, but the expiries above still
-            # land, the trace still records the quantum, and the
-            # starvation is counted instead of falling through to planning
-            self.obs.metrics.counter("core/starved_quanta").inc()
-            plan = StepPlan(prefill_tokens=0.0)
-        else:
-            if self.ladder is not None:
-                self.ladder.update(self, g)
-            plan = self.policy.plan(self, g)
-            if self.ladder is not None:
-                self.ladder.apply(self, g, plan)
+        with self.obs.span("core.plan"):
+            self._expire_deadlines(g.now)
+            if g.token_budget <= 0:
+                # degenerate grant (DESIGN.md §9): an explicit no-op quantum —
+                # nothing is planned or driven, but the expiries above still
+                # land, the trace still records the quantum, and the
+                # starvation is counted instead of falling through to planning
+                self.obs.metrics.counter("core/starved_quanta").inc()
+                plan = StepPlan(prefill_tokens=0.0)
+            else:
+                if self.ladder is not None:
+                    self.ladder.update(self, g)
+                plan = self.policy.plan(self, g)
+                if self.ladder is not None:
+                    self.ladder.apply(self, g, plan)
         out = StepOutputs(k=0, gamma=None, cost_steps=0.0)
-        for slot in list(plan.preempt):
-            cr = self.preempt(slot)
-            if cr is not None:
-                out.preempted.append(cr.request_id)
-        for cr in plan.admit:
-            base.setdefault(cr.request_id, len(cr.output_tokens))
-            touched.setdefault(cr.request_id, cr)
-            if self._try_admit(
-                cr,
-                allow_preempt=plan.preempt_to_admit,
-                on_preempt=lambda victim: (
-                    out.preempted.append(victim.request_id),
-                    touched.setdefault(victim.request_id, victim),
-                ),
-            ):
-                out.admitted.append(cr.request_id)
+        with self.obs.span("core.admit"):
+            for slot in list(plan.preempt):
+                cr = self.preempt(slot)
+                if cr is not None:
+                    out.preempted.append(cr.request_id)
+            for cr in plan.admit:
+                base.setdefault(cr.request_id, len(cr.output_tokens))
+                touched.setdefault(cr.request_id, cr)
+                if self._try_admit(
+                    cr,
+                    allow_preempt=plan.preempt_to_admit,
+                    on_preempt=lambda victim: (
+                        out.preempted.append(victim.request_id),
+                        touched.setdefault(victim.request_id, victim),
+                    ),
+                ):
+                    out.admitted.append(cr.request_id)
         pf_take, completing = 0, []
         if eng.prefill_chunk and plan.prefill_tokens > 0:
             # deterministic preview: price the chunk waves before driving
@@ -736,8 +765,9 @@ class EngineCore:
         pf_cost = out.prefill_tokens * plan.prefill_token_cost
         ran_slots: dict = {}
         if k > 0:
-            # the slots the fused loop will decode (for per-slot spans);
-            # captured now because retirements mutate the map mid-loop
+            # the slots the fused loop will decode (the quantum record's
+            # ``decoded``); captured now because retirements mutate the
+            # map mid-loop
             ran_slots = {
                 slot: cr.request_id
                 for slot, cr in self.slot_requests.items()
@@ -785,75 +815,76 @@ class EngineCore:
             out.cost_steps = cost
         out.spec_accepted = eng.spec_accepted - a0
         out.spec_proposed = eng.spec_drafted - p0
-        for slot, cr in list(self.slot_requests.items()):
-            if (cr.state is RequestState.PREFILLING
-                    and not eng.slot_prefilling(slot)):
-                # the final chunk landed during this step's waves, before
-                # the clock advance: flip stamps at quantum start, where
-                # the first token was stamped
-                cr.state = RequestState.RUNNING
-                self.obs.tracer.transition(
-                    cr.request_id, "prefilling", "running", g.now,
-                    priority=cr.priority.value,
-                )
-            self._absorb_running(slot, cr)
-        if inj is not None and inj.should_fire("process/kill"):
-            # mid-quantum death: device work ran and its tokens were
-            # absorbed into host state, but the journal append below never
-            # happens — replay-resume regenerates them byte-identically
-            from repro.resilience.faults import ProcessKilled
+        with self.obs.span("core.collect"):
+            for slot, cr in list(self.slot_requests.items()):
+                if (cr.state is RequestState.PREFILLING
+                        and not eng.slot_prefilling(slot)):
+                    # the final chunk landed during this step's waves, before
+                    # the clock advance: flip stamps at quantum start, where
+                    # the first token was stamped
+                    cr.state = RequestState.RUNNING
+                    self.obs.tracer.transition(
+                        cr.request_id, "prefilling", "running", g.now,
+                        priority=cr.priority.value,
+                    )
+                self._absorb_running(slot, cr)
+            if inj is not None and inj.should_fire("process/kill"):
+                # mid-quantum death: device work ran and its tokens were
+                # absorbed into host state, but the journal append below never
+                # happens — replay-resume regenerates them byte-identically
+                from repro.resilience.faults import ProcessKilled
 
-            raise ProcessKilled("injected process death mid-quantum")
-        m = self.obs.metrics
-        if self.fault_decay_quanta and out.k > 0:
-            # fault-counter decay (DESIGN.md §9): a quarantined request
-            # that then decodes N consecutive clean quanta earns its
-            # retry budget back — transient faults spread across a long
-            # life must not escalate to FINISHED_ERROR
-            for cr in self.slot_requests.values():
-                if cr.faults and cr.state is RequestState.RUNNING:
-                    cr._clean_quanta += 1
-                    if cr._clean_quanta >= self.fault_decay_quanta:
-                        cr.faults = 0
-                        cr._clean_quanta = 0
-                        m.counter("fault/decays").inc()
-        out.finished = list(self._finished_buffer)
-        for cr in out.finished:
-            touched.setdefault(cr.request_id, cr)
-            # queue-side finishes (expiry, load shedding) produced no
-            # tokens this step: their delta baseline is the full stream
-            base.setdefault(cr.request_id, len(cr.output_tokens))
-            pri = cr.priority.value
-            m.counter("core/finished/" + pri).inc()
-            if cr.finish_reason != "expired":
-                # served latency means completed work; shed/expired
-                # requests never ran and would poison the p95
-                m.histogram(f"core/{pri}_latency_s").record(
-                    cr.finish_time - cr.arrival_time
-                )
-        for rid, cr in touched.items():
-            new = cr.output_tokens[base.get(rid, 0):]
-            ttft = None
-            if cr.first_token_time is not None and not cr._ttft_reported:
-                cr._ttft_reported = True
-                ttft = cr.first_token_time - cr.arrival_time
-                self.obs.tracer.instant(
-                    "first_token", cr.first_token_time, request_id=rid,
-                    priority=cr.priority.value,
-                )
-                if cr.priority is Priority.ONLINE:
-                    m.histogram("core/online_ttft_s").record(ttft)
-            if new:
-                m.counter(
-                    "core/generated_tokens/" + cr.priority.value
-                ).inc(len(new))
-            out.outputs.append(RequestOutput(
-                request_id=rid, priority=cr.priority, new_tokens=list(new),
-                state=cr.state, finish_reason=cr.finish_reason, ttft_s=ttft,
-            ))
-        if self.journal is not None:
-            self.journal.record_step(self, out)
-        self._record_quantum(g, plan, out, ran_slots)
+                raise ProcessKilled("injected process death mid-quantum")
+            m = self.obs.metrics
+            if self.fault_decay_quanta and out.k > 0:
+                # fault-counter decay (DESIGN.md §9): a quarantined request
+                # that then decodes N consecutive clean quanta earns its
+                # retry budget back — transient faults spread across a long
+                # life must not escalate to FINISHED_ERROR
+                for cr in self.slot_requests.values():
+                    if cr.faults and cr.state is RequestState.RUNNING:
+                        cr._clean_quanta += 1
+                        if cr._clean_quanta >= self.fault_decay_quanta:
+                            cr.faults = 0
+                            cr._clean_quanta = 0
+                            m.counter("fault/decays").inc()
+            out.finished = list(self._finished_buffer)
+            for cr in out.finished:
+                touched.setdefault(cr.request_id, cr)
+                # queue-side finishes (expiry, load shedding) produced no
+                # tokens this step: their delta baseline is the full stream
+                base.setdefault(cr.request_id, len(cr.output_tokens))
+                pri = cr.priority.value
+                m.counter("core/finished/" + pri).inc()
+                if cr.finish_reason != "expired":
+                    # served latency means completed work; shed/expired
+                    # requests never ran and would poison the p95
+                    m.histogram(f"core/{pri}_latency_s").record(
+                        cr.finish_time - cr.arrival_time
+                    )
+            for rid, cr in touched.items():
+                new = cr.output_tokens[base.get(rid, 0):]
+                ttft = None
+                if cr.first_token_time is not None and not cr._ttft_reported:
+                    cr._ttft_reported = True
+                    ttft = cr.first_token_time - cr.arrival_time
+                    self.obs.tracer.instant(
+                        "first_token", cr.first_token_time, request_id=rid,
+                        priority=cr.priority.value,
+                    )
+                    if cr.priority is Priority.ONLINE:
+                        m.histogram("core/online_ttft_s").record(ttft)
+                if new:
+                    m.counter(
+                        "core/generated_tokens/" + cr.priority.value
+                    ).inc(len(new))
+                out.outputs.append(RequestOutput(
+                    request_id=rid, priority=cr.priority, new_tokens=list(new),
+                    state=cr.state, finish_reason=cr.finish_reason, ttft_s=ttft,
+                ))
+            if self.journal is not None:
+                self.journal.record_step(self, out)
+        out.seq = self._record_quantum(g, plan, out, ran_slots, w0)
         self.policy.observe(out)
         return out
 
@@ -1005,9 +1036,12 @@ class EngineCore:
             request_id=req.request_id,
             arrival_time=req.arrival_time,
             state=RequestState.RUNNING,
+            arrival_wall_ns=wall_ns(),
         )
+        cr.admit_wall_ns = cr.arrival_wall_ns
         cr._internal = req
         cr.first_token_time = req.first_token_time
+        cr.first_token_wall_ns = req.first_token_wall_ns
         slot = next(
             i for i, r in enumerate(self.engine.slots) if r is req
         )
@@ -1043,90 +1077,92 @@ class EngineCore:
     # Internals
     # ------------------------------------------------------------------
     def _record_quantum(
-        self, g: Grant, plan: StepPlan, out: StepOutputs, ran_slots: dict
-    ) -> None:
-        """Per-quantum observability (DESIGN.md §8): sample the gauges and
-        emit the structured trace events for this step — one ``quantum``
-        record plus per-slot prefill/decode/spec spans.  Span boundaries
-        are the engine clock's quantum endpoints; the prefill/decode split
-        inside the quantum follows the plan's deterministic cost model
-        (prefill runs first, before the clock advance)."""
-        eng = self.engine
-        m = self.obs.metrics
-        m.gauge("core/queue_depth/online").set(
-            len(self.waiting[Priority.ONLINE])
-        )
-        m.gauge("core/queue_depth/offline").set(
-            len(self.waiting[Priority.OFFLINE])
-        )
-        m.gauge("engine/slots_active").set(eng.num_active)
-        m.gauge("engine/slots_prefilling").set(eng.num_prefilling)
-        if eng.pool is not None:
-            for key, v in eng.pool.occupancy().items():
-                m.gauge(f"engine/pool/{key}").set(v)
-        tr = self.obs.tracer
-        window, tr.window_state = tr.window_state, None
-        if not tr.enabled:
-            return
-        t0, t1 = g.now, eng.clock()
-        pf_cost = out.prefill_tokens * plan.prefill_token_cost
-        dec_cost = plan.cost_steps if out.k > 0 else 0.0
-        total = pf_cost + dec_cost
-        t_mid = t0 + (t1 - t0) * (pf_cost / total if total > 0 else 0.0)
-        if out.prefill_tokens:
-            if eng.prefill_chunk:
-                for slot, ntok in eng.last_prefill_slot_tokens.items():
-                    cr = self.slot_requests.get(slot)
-                    tr.span(
-                        "prefill_chunk", f"slot{slot}", t0, t_mid,
-                        tokens=ntok,
-                        request_id=None if cr is None else cr.request_id,
-                    )
-            else:
-                for rid in out.admitted:
-                    cr = self.requests.get(rid)
-                    slot = None if cr is None else self.slot_of(cr)
-                    if slot is not None:
-                        tr.span(
-                            "prefill", f"slot{slot}", t0, t_mid,
-                            request_id=rid,
-                        )
-        name = "spec_round" if out.gamma is not None else "decode"
-        for slot, rid in ran_slots.items():
-            tr.span(
-                name, f"slot{slot}", t_mid, t1, k=out.k, gamma=out.gamma,
-                proposer=out.proposer, request_id=rid,
+        self, g: Grant, plan: StepPlan, out: StepOutputs, ran_slots: dict,
+        w0: int,
+    ) -> Optional[int]:
+        """Per-quantum observability (DESIGN.md §8), in the ``core.record``
+        host span: sample the gauges and emit the structured trace events
+        for this step — per-slot prefill spans and one ``quantum`` record,
+        which names the slots the fused loop decoded (``decoded``, drawn
+        per slot at export) and the step's wall-clock interval
+        (``wall_ns``, from ``w0``).  Span boundaries are the engine clock's
+        quantum endpoints; the prefill/decode split inside the quantum
+        follows the plan's deterministic cost model (prefill runs first,
+        before the clock advance).  Returns the quantum record's ``seq``."""
+        with self.obs.span("core.record"):
+            eng = self.engine
+            m = self.obs.metrics
+            m.gauge("core/queue_depth/online").set(
+                len(self.waiting[Priority.ONLINE])
             )
-        tr.quantum(
-            t0, t1,
-            grant={
-                "tokens": _jnum(g.tokens), "online_ok": g.online_ok,
-                "phase": (
-                    None if g.phase is None
-                    else str(getattr(g.phase, "value", g.phase))
-                ),
-                "max_cost_steps": _jnum(g.max_cost_steps),
-                "token_budget": _jnum(g.token_budget),
-            },
-            k=out.k, gamma=out.gamma, proposer=out.proposer,
-            cost_steps=out.cost_steps,
-            prefill_tokens=out.prefill_tokens, revoked=out.revoked,
-            admitted=list(out.admitted), preempted=list(out.preempted),
-            finished=[cr.request_id for cr in out.finished],
-            spec_accepted=out.spec_accepted,
-            spec_proposed=out.spec_proposed,
-            window=window,
-        )
+            m.gauge("core/queue_depth/offline").set(
+                len(self.waiting[Priority.OFFLINE])
+            )
+            m.gauge("engine/slots_active").set(eng.num_active)
+            m.gauge("engine/slots_prefilling").set(eng.num_prefilling)
+            if eng.pool is not None:
+                for key, v in eng.pool.occupancy().items():
+                    m.gauge(f"engine/pool/{key}").set(v)
+            tr = self.obs.tracer
+            window, tr.window_state = tr.window_state, None
+            if not tr.enabled:
+                return None
+            t0, t1 = g.now, eng.clock()
+            pf_cost = out.prefill_tokens * plan.prefill_token_cost
+            dec_cost = plan.cost_steps if out.k > 0 else 0.0
+            total = pf_cost + dec_cost
+            t_mid = t0 + (t1 - t0) * (pf_cost / total if total > 0 else 0.0)
+            if out.prefill_tokens:
+                if eng.prefill_chunk:
+                    for slot, ntok in eng.last_prefill_slot_tokens.items():
+                        cr = self.slot_requests.get(slot)
+                        tr.span(
+                            "prefill_chunk", f"slot{slot}", t0, t_mid,
+                            tokens=ntok,
+                            request_id=None if cr is None else cr.request_id,
+                        )
+                else:
+                    for rid in out.admitted:
+                        cr = self.requests.get(rid)
+                        slot = None if cr is None else self.slot_of(cr)
+                        if slot is not None:
+                            tr.span(
+                                "prefill", f"slot{slot}", t0, t_mid,
+                                request_id=rid,
+                            )
+            return tr.quantum(
+                t0, t1,
+                grant={
+                    "tokens": _jnum(g.tokens), "online_ok": g.online_ok,
+                    "phase": (
+                        None if g.phase is None
+                        else str(getattr(g.phase, "value", g.phase))
+                    ),
+                    "max_cost_steps": _jnum(g.max_cost_steps),
+                    "token_budget": _jnum(g.token_budget),
+                },
+                k=out.k, gamma=out.gamma, proposer=out.proposer,
+                cost_steps=out.cost_steps,
+                prefill_tokens=out.prefill_tokens, revoked=out.revoked,
+                admitted=list(out.admitted), preempted=list(out.preempted),
+                finished=[cr.request_id for cr in out.finished],
+                spec_accepted=out.spec_accepted,
+                spec_proposed=out.spec_proposed,
+                window=window,
+                decoded=ran_slots, decode_t0=t_mid,
+                wall_ns=[w0, wall_ns()],
+            )
 
     def _collect(self, cr: EngineRequest) -> list:
         """Absorb tokens the engine produced since the last collection into
         the canonical stream; returns just the new ones.  Also propagates
-        the engine-side TTFT stamp, which a chunked-prefill admission only
+        the engine-side TTFT stamps, which a chunked-prefill admission only
         produces once the prompt's final chunk lands (monolithic admission
-        stamped it inside ``_try_admit``)."""
+        stamped them inside ``_try_admit``)."""
         if (cr.first_token_time is None
                 and cr._internal.first_token_time is not None):
             cr.first_token_time = cr._internal.first_token_time
+            cr.first_token_wall_ns = cr._internal.first_token_wall_ns
         gen = cr._internal.generated
         new = [int(t) for t in gen[cr._consumed:]]
         cr._consumed = len(gen)
@@ -1153,6 +1189,7 @@ class EngineCore:
         cr.state = state
         cr.finish_reason = FINISH_REASONS[state]
         cr.finish_time = now
+        cr.finish_wall_ns = wall_ns()
         self._finished_buffer.append(cr)
         self.obs.metrics.counter(
             "core/finish_reason/" + cr.finish_reason
@@ -1297,8 +1334,11 @@ class EngineCore:
             RequestState.PREFILLING if self.engine.slot_prefilling(slot)
             else RequestState.RUNNING
         )
+        if cr.admit_wall_ns is None:
+            cr.admit_wall_ns = wall_ns()
         if cr.first_token_time is None:
             cr.first_token_time = internal.first_token_time
+            cr.first_token_wall_ns = internal.first_token_wall_ns
         self.obs.tracer.transition(
             cr.request_id, frm, cr.state.value, self.engine.clock(),
             priority=cr.priority.value,

@@ -64,6 +64,10 @@ added with the default ``arrival_time == 0.0`` are stamped from the engine
 clock at admission, so latency metrics never mix an epoch-zero arrival with
 a monotonic/virtual now (online requests keep their explicit arrivals —
 including a genuine virtual ``t == 0`` — so queueing delay is preserved).
+Beside them, for observability only, ``first_token_wall_ns`` is stamped on
+the wall clock (``repro.obs.wall_ns``) right after the fetch that delivered
+the token, and the drivers run in host spans (``engine.prefill``,
+``engine.decode``, ``engine.fetch``, ``engine.tables``; ``repro.obs.trace``).
 """
 from __future__ import annotations
 
@@ -80,7 +84,7 @@ import numpy as np
 
 from repro.configs.base import ModelConfig, SpecDecodeConfig
 from repro.models import transformer as T
-from repro.obs import Observability
+from repro.obs import Observability, wall_ns
 from repro.serving.kv_pool import PageAllocError, PagePool, RadixCache
 
 _req_counter = itertools.count()
@@ -126,6 +130,19 @@ def _on_one_device(tree: Any) -> Any:
     return jax.device_put(tree, jax.devices()[0]) if sharded else tree
 
 
+def _program(fn: Callable, *args, **kwargs) -> Callable:
+    """``functools.partial(fn, *args, **kwargs)`` named after ``fn``, so its
+    jitted program is ``jit_<fn name>`` in HLO dumps, compile logs and
+    profiles (a bare partial's is ``jit__unknown``).  Only the name is
+    copied: a ``__wrapped__`` would make ``inspect.signature`` report
+    ``fn``'s own parameters, and jit would resolve donated and static
+    argument names against the wrong positions."""
+    p = functools.partial(fn, *args, **kwargs)
+    p.__name__ = fn.__name__
+    p.__qualname__ = fn.__qualname__
+    return p
+
+
 class RegistryCounterView:
     """Thin view (DESIGN.md §8): a historical ``InferenceEngine`` counter
     attribute backed by a ``repro.obs`` registry counter under a stable
@@ -166,6 +183,9 @@ class Request:
     # -- filled by the engine --
     generated: list = dataclasses.field(default_factory=list)
     first_token_time: Optional[float] = None
+    #: ``repro.obs.wall_ns`` right after the fetch that delivered the
+    #: first token
+    first_token_wall_ns: Optional[int] = None
     finish_time: Optional[float] = None
 
 
@@ -341,13 +361,13 @@ class InferenceEngine:
         self.spec_accepted = 0
 
         self._decode = jax.jit(
-            functools.partial(
+            _program(
                 T.decode_step, cfg, compute_dtype=compute_dtype,
                 attn_impl=decode_impl,
             )
         )
         self._decode_loop = jax.jit(
-            functools.partial(
+            _program(
                 T.decode_loop, cfg, compute_dtype=compute_dtype,
                 attn_impl=decode_impl, max_seq=max_seq,
             ),
@@ -356,14 +376,14 @@ class InferenceEngine:
         )
         if self.paged:
             self._prefill_slot = jax.jit(
-                functools.partial(
+                _program(
                     T.prefill_into_slot_paged, cfg,
                     impl=prefill_impl, compute_dtype=compute_dtype,
                 ),
                 donate_argnames=("cache",),
             )
             self._suffix_prefill = jax.jit(
-                functools.partial(
+                _program(
                     T.prefill_suffix_into_slot, cfg,
                     compute_dtype=compute_dtype, attn_impl=decode_impl,
                 ),
@@ -371,7 +391,7 @@ class InferenceEngine:
             )
         else:
             self._prefill_slot = jax.jit(
-                functools.partial(
+                _program(
                     T.prefill_into_slot, cfg, max_seq=max_seq,
                     impl=prefill_impl, compute_dtype=compute_dtype,
                 ),
@@ -382,7 +402,7 @@ class InferenceEngine:
             # a single compile serves every mix of slots / chunk lengths /
             # prefill offsets (dense and paged branch on the cache layout)
             self._prefill_chunks = jax.jit(
-                functools.partial(
+                _program(
                     T.prefill_chunks_into_slots, cfg,
                     compute_dtype=compute_dtype, attn_impl=decode_impl,
                 ),
@@ -408,7 +428,7 @@ class InferenceEngine:
             from repro.spec.loop import spec_decode_loop as _spec_fn
 
             self._spec_loop = jax.jit(
-                functools.partial(
+                _program(
                     _spec_fn, cfg, draft_cfg, mode=self.spec_cfg.mode,
                     max_seq=max_seq, sim_accept_p=self.spec_cfg.sim_accept_p,
                     compute_dtype=compute_dtype, attn_impl=decode_impl,
@@ -419,7 +439,7 @@ class InferenceEngine:
                 ),
             )
             self._draft_prefill = jax.jit(
-                functools.partial(
+                _program(
                     T.prefill_into_slot, draft_cfg, max_seq=max_seq,
                     impl=prefill_impl, compute_dtype=compute_dtype,
                 ),
@@ -431,7 +451,7 @@ class InferenceEngine:
                 # one per admitted request); its first-token logits are
                 # never read, so the program skips the vocab projection
                 self._draft_prefill_chunks = jax.jit(
-                    functools.partial(
+                    _program(
                         T.prefill_chunks_into_slots, draft_cfg,
                         compute_dtype=compute_dtype, attn_impl=decode_impl,
                         need_logits=False,
@@ -714,8 +734,15 @@ class InferenceEngine:
         return self.prefix_cache.load_nodes(nodes, pages)
 
     def _sync_block_tables(self) -> None:
-        self.cache["block_tables"] = jnp.asarray(self._bt_host)
-        self._bt_dirty = False
+        with self.obs.span("engine.tables"):
+            self.cache["block_tables"] = jnp.asarray(self._bt_host)
+            self._bt_dirty = False
+
+    def _fetch(self, tree):
+        """Blocking device->host copy of ``tree``: the host waiting on the
+        device, timed as the ``engine.fetch`` span."""
+        with self.obs.span("engine.fetch"):
+            return jax.device_get(tree)
 
     def _set_block_table_row(
         self, slot: int, pages: list[int], sync: bool = True
@@ -735,25 +762,26 @@ class InferenceEngine:
         DESIGN.md §9) is contained per slot: the failing slot is evicted
         and its request re-queued through the core's fault path; the
         other slots keep decoding."""
-        for i, r in enumerate(self.slots):
-            if r is None:
-                continue
-            cover = min(self._slot_idx[i] + steps, self._slot_horizon[i])
-            need = self.pool.pages_for(cover)
-            cur = len(self._slot_pages[i])
-            if need > cur:
-                try:
-                    got = self.pool.alloc(need - cur, reserved=True)
-                except PageAllocError:
-                    self.obs.metrics.counter("fault/alloc_failures").inc()
-                    req = self.evict_slot(i, sync=False)
-                    if self._core is not None:
-                        self._core._on_slot_fault(i, req)
+        with self.obs.span("engine.tables"):
+            for i, r in enumerate(self.slots):
+                if r is None:
                     continue
-                self._slot_reserved[i] -= len(got)
-                self._bt_host[i, cur: cur + len(got)] = got
-                self._slot_pages[i].extend(got)
-                self._bt_dirty = True
+                cover = min(self._slot_idx[i] + steps, self._slot_horizon[i])
+                need = self.pool.pages_for(cover)
+                cur = len(self._slot_pages[i])
+                if need > cur:
+                    try:
+                        got = self.pool.alloc(need - cur, reserved=True)
+                    except PageAllocError:
+                        self.obs.metrics.counter("fault/alloc_failures").inc()
+                        req = self.evict_slot(i, sync=False)
+                        if self._core is not None:
+                            self._core._on_slot_fault(i, req)
+                        continue
+                    self._slot_reserved[i] -= len(got)
+                    self._bt_host[i, cur: cur + len(got)] = got
+                    self._slot_pages[i].extend(got)
+                    self._bt_dirty = True
         if self._bt_dirty:
             self._sync_block_tables()
 
@@ -1047,13 +1075,34 @@ class InferenceEngine:
         the per-request prefill (and per-request draft prefill) dispatches
         of the monolithic path.  Slots whose prompt completes get their
         first generated token from the completing wave's logits, fetched in
-        ONE batched d2h transfer at the end.  Returns tokens consumed."""
+        ONE batched d2h transfer at the end.  Returns tokens consumed.
+
+        The ``engine.prefill`` host span covers the packing and dispatch,
+        and the first tokens' absorption; the fetch is ``engine.fetch``."""
+        with self.obs.span("engine.prefill"):
+            consumed, completed = self._dispatch_prefill_waves(budget)
+        if completed:
+            toks = self._fetch([self._prefill_tok[i] for i in completed])
+            got_ns = wall_ns()
+            self.d2h_transfers += 1  # one batched fetch covers every finish
+            with self.obs.span("engine.prefill"):
+                now = self.clock()
+                for i, arr in zip(completed, toks):
+                    self._finish_prefill(
+                        i, int(np.asarray(arr)[i]), now, got_ns
+                    )
+        self.prefill_metered_tokens += consumed
+        return consumed
+
+    def _dispatch_prefill_waves(self, budget: float):
+        """The packing and dispatch half of ``_drive_prefill_chunks``:
+        returns ``(tokens consumed, slots whose prefill completed)``."""
         self.last_prefill_slot_tokens = {}
         if not self.prefill_chunk:
-            return 0
+            return 0, []
         waves, consumed, _ = self._plan_prefill_waves(budget)
         if not waves:
-            return 0
+            return 0, []
         for wave in waves:
             for i, tt, dd in wave:
                 self.last_prefill_slot_tokens[i] = (
@@ -1108,20 +1157,16 @@ class InferenceEngine:
                     d is None or len(d) == 0
                 ):
                     completed.append(i)
-        if completed:
-            toks = jax.device_get([self._prefill_tok[i] for i in completed])
-            self.d2h_transfers += 1  # one batched fetch covers every finish
-            now = self.clock()
-            for i, arr in zip(completed, toks):
-                self._finish_prefill(i, int(np.asarray(arr)[i]), now)
-        self.prefill_metered_tokens += consumed
-        return consumed
+        return consumed, completed
 
-    def _finish_prefill(self, i: int, tok: int, now: float) -> None:
+    def _finish_prefill(
+        self, i: int, tok: int, now: float, got_ns: int
+    ) -> None:
         """Transition slot ``i`` PREFILLING -> RUNNING: deliver the first
-        generated token, stamp TTFT, and (paged) insert the prompt's full
-        pages into the radix tree — the same shape monolithic admission
-        produced in one shot."""
+        generated token, stamp TTFT (``now`` on the engine clock, ``got_ns``
+        the wall clock when the host received it), and (paged) insert the
+        prompt's full pages into the radix tree — the same shape monolithic
+        admission produced in one shot."""
         req = self.slots[i]
         self._prefill_left[i] = None
         self._draft_prefill_left[i] = None
@@ -1130,6 +1175,7 @@ class InferenceEngine:
         self.generated_tokens_total += 1
         if req.first_token_time is None:
             req.first_token_time = now
+            req.first_token_wall_ns = got_ns
         self.tokens = self.tokens.at[i].set(tok)
         if self.paged and self.prefix_cache is not None:
             prompt = np.asarray(req.prompt, np.int32)
@@ -1245,11 +1291,14 @@ class InferenceEngine:
                 return False
         else:
             tok = self._dense_admit(slot, req)
-        req.generated.append(int(tok))
+        tok = int(self._fetch(tok))
+        got_ns = wall_ns()
+        req.generated.append(tok)
         self.d2h_transfers += 1
         self.generated_tokens_total += 1
         if req.first_token_time is None:
             req.first_token_time = self.clock()
+            req.first_token_wall_ns = got_ns
         self.tokens = self.tokens.at[slot].set(tok)
         self.slots[slot] = req
         self.steps_executed += 1
@@ -1289,7 +1338,7 @@ class InferenceEngine:
             off = pos % self.kv_page_size
             layers["k"] = layers["k"].at[0, page, off].set(jnp.nan)
         else:
-            pos = int(jax.device_get(self.cache["index"])[slot]) - 1
+            pos = int(self._fetch(self.cache["index"])[slot]) - 1
             layers["k"] = layers["k"].at[0, slot, pos].set(jnp.nan)
 
     def _scrub_slot_kv(self, i: int) -> None:
@@ -1345,48 +1394,51 @@ class InferenceEngine:
             return []
         if self.num_active == self.num_prefilling:
             return []  # every slot is mid-prefill: nothing to decode
-        if self.paged:
-            # extend block tables to cover the loop's k writes per slot
-            self._top_up_pages(k)
-            if self.num_active == 0:
-                return []  # every slot fell to an allocator fault
-        self._maybe_inject_nan()
-        remaining = np.zeros((self.max_slots,), np.int32)
-        for i, r in enumerate(self.slots):
-            if r is not None and not self.slot_prefilling(i):
-                remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
-        tokens, cache, rem, toks_seq, steps, bad = self._decode_loop(
-            self.params, self.tokens, self.cache, jnp.asarray(remaining), k=k
-        )
-        self.tokens, self.cache = tokens, cache
-        toks_np, steps_np, rem_np, idx_np, bad_np = jax.device_get(
+        with self.obs.span("engine.decode"):
+            if self.paged:
+                # extend block tables to cover the loop's k writes per slot
+                self._top_up_pages(k)
+                if self.num_active == 0:
+                    return []  # every slot fell to an allocator fault
+            self._maybe_inject_nan()
+            remaining = np.zeros((self.max_slots,), np.int32)
+            for i, r in enumerate(self.slots):
+                if r is not None and not self.slot_prefilling(i):
+                    remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
+            tokens, cache, rem, toks_seq, steps, bad = self._decode_loop(
+                self.params, self.tokens, self.cache, jnp.asarray(remaining),
+                k=k,
+            )
+            self.tokens, self.cache = tokens, cache
+        toks_np, steps_np, rem_np, idx_np, bad_np = self._fetch(
             (toks_seq, steps, rem, cache["index"], bad)
         )
-        self.d2h_transfers += 1  # the single fused fetch above
-        self.steps_executed += k
-        now = self.clock()
-        finished = []
-        for i, req in enumerate(self.slots):
-            if req is None or self.slot_prefilling(i):
-                continue
-            if bad_np[i]:
-                # NaN screen (DESIGN.md §9): this slot's tokens from the
-                # loop are garbage — drop them all (the screen can't say
-                # which microstep went bad) and quarantine the slot; its
-                # on-device index/remaining are garbage too, so no retire
-                # check either
-                self._quarantine_slot(i)
-                continue
-            n = int(steps_np[i])
-            req.generated.extend(int(t) for t in toks_np[:n, i])
-            self.generated_tokens_total += n
-            if self.paged:
-                self._slot_idx[i] = int(idx_np[i])
-            if rem_np[i] == 0 or idx_np[i] >= self.max_seq - 1:
-                finished.append(self._retire_slot(i, now))
-        if self.paged and self._bt_dirty:
-            self._sync_block_tables()  # one upload covers every retirement
-        return finished
+        with self.obs.span("engine.decode"):
+            self.d2h_transfers += 1  # the single fused fetch above
+            self.steps_executed += k
+            now = self.clock()
+            finished = []
+            for i, req in enumerate(self.slots):
+                if req is None or self.slot_prefilling(i):
+                    continue
+                if bad_np[i]:
+                    # NaN screen (DESIGN.md §9): this slot's tokens from the
+                    # loop are garbage — drop them all (the screen can't say
+                    # which microstep went bad) and quarantine the slot; its
+                    # on-device index/remaining are garbage too, so no retire
+                    # check either
+                    self._quarantine_slot(i)
+                    continue
+                n = int(steps_np[i])
+                req.generated.extend(int(t) for t in toks_np[:n, i])
+                self.generated_tokens_total += n
+                if self.paged:
+                    self._slot_idx[i] = int(idx_np[i])
+                if rem_np[i] == 0 or idx_np[i] >= self.max_seq - 1:
+                    finished.append(self._retire_slot(i, now))
+            if self.paged and self._bt_dirty:
+                self._sync_block_tables()  # one upload covers every retirement
+            return finished
 
     # ------------------------------------------------------------------
     def _drive_spec_loop(self, k: int, gamma: int) -> list[Request]:
@@ -1405,71 +1457,70 @@ class InferenceEngine:
             return []
         if self.num_active == self.num_prefilling:
             return []  # every slot is mid-prefill: nothing to verify
-        if self.paged:
-            # worst case every round accepts the whole chunk: cover
-            # k * (gamma + 1) writes per slot
-            self._top_up_pages(k * (gamma + 1))
-            if self.num_active == 0:
-                return []  # every slot fell to an allocator fault
-        self._maybe_inject_nan()
-        remaining = np.zeros((self.max_slots,), np.int32)
-        for i, r in enumerate(self.slots):
-            if r is not None and not self.slot_prefilling(i):
-                remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
-        (
-            self.tokens, self.cache, self.draft_cache, rem, self._spec_key,
-            out_toks, n_out, accepted, proposed, bad,
-        ) = self._spec_loop(
-            self.params, self.draft_params, self.tokens, self.cache,
-            self.draft_cache, jnp.asarray(remaining), self._spec_key,
-            k=k, gamma=gamma,
-        )
-        toks_np, n_np, acc_np, prop_np, rem_np, idx_np, bad_np = (
-            jax.device_get((
-                out_toks, n_out, accepted, proposed, rem,
-                self.cache["index"], bad,
-            ))
-        )
-        self.d2h_transfers += 1  # the single fused fetch above
-        self.steps_executed += k
-        self.spec_rounds += k
-        now = self.clock()
-        finished = []
-        self._last_spec_slot_stats = {}
-        for i, req in enumerate(self.slots):
-            if req is None or self.slot_prefilling(i):
-                continue
-            if bad_np[i]:
-                # NaN screen (DESIGN.md §9): every round's acceptance for
-                # this slot is suspect — drop the whole loop's output and
-                # quarantine (no acceptance-EWMA pollution either)
-                self._quarantine_slot(i)
-                continue
-            for j in range(k):
-                n = int(n_np[j, i])
-                req.generated.extend(int(t) for t in toks_np[j, i, :n])
-                self.generated_tokens_total += n
-            slot_acc = int(acc_np[:, i].sum())
-            slot_prop = int(prop_np[:, i].sum())
-            self._last_spec_slot_stats[i] = (slot_acc, slot_prop)
-            self.spec_accepted += slot_acc
-            self.spec_drafted += slot_prop
+        with self.obs.span("engine.decode"):
             if self.paged:
-                self._slot_idx[i] = int(idx_np[i])
-            if rem_np[i] == 0 or idx_np[i] + gamma >= self.max_seq:
-                finished.append(self._retire_slot(i, now))
-            elif self.paged:
-                # rollback freed tokens past the accepted prefix: release
-                # the pages the worst-case top-up provisioned beyond them
-                self._trim_slot_pages(i)
-        if self.num_prefilling:
-            # the fused loop pinned every frozen slot's draft index to its
-            # TARGET index; mid-prefill the two streams sit at different
-            # offsets, so restore the draft's own progress
-            self._restore_draft_prefill_indices()
-        if self.paged and self._bt_dirty:
-            self._sync_block_tables()  # one upload covers trims + retires
-        return finished
+                # worst case every round accepts the whole chunk: cover
+                # k * (gamma + 1) writes per slot
+                self._top_up_pages(k * (gamma + 1))
+                if self.num_active == 0:
+                    return []  # every slot fell to an allocator fault
+            self._maybe_inject_nan()
+            remaining = np.zeros((self.max_slots,), np.int32)
+            for i, r in enumerate(self.slots):
+                if r is not None and not self.slot_prefilling(i):
+                    remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
+            (
+                self.tokens, self.cache, self.draft_cache, rem, self._spec_key,
+                out_toks, n_out, accepted, proposed, bad,
+            ) = self._spec_loop(
+                self.params, self.draft_params, self.tokens, self.cache,
+                self.draft_cache, jnp.asarray(remaining), self._spec_key,
+                k=k, gamma=gamma,
+            )
+        toks_np, n_np, acc_np, prop_np, rem_np, idx_np, bad_np = self._fetch((
+            out_toks, n_out, accepted, proposed, rem, self.cache["index"], bad,
+        ))
+        with self.obs.span("engine.decode"):
+            self.d2h_transfers += 1  # the single fused fetch above
+            self.steps_executed += k
+            self.spec_rounds += k
+            now = self.clock()
+            finished = []
+            self._last_spec_slot_stats = {}
+            for i, req in enumerate(self.slots):
+                if req is None or self.slot_prefilling(i):
+                    continue
+                if bad_np[i]:
+                    # NaN screen (DESIGN.md §9): every round's acceptance for
+                    # this slot is suspect — drop the whole loop's output and
+                    # quarantine (no acceptance-EWMA pollution either)
+                    self._quarantine_slot(i)
+                    continue
+                for j in range(k):
+                    n = int(n_np[j, i])
+                    req.generated.extend(int(t) for t in toks_np[j, i, :n])
+                    self.generated_tokens_total += n
+                slot_acc = int(acc_np[:, i].sum())
+                slot_prop = int(prop_np[:, i].sum())
+                self._last_spec_slot_stats[i] = (slot_acc, slot_prop)
+                self.spec_accepted += slot_acc
+                self.spec_drafted += slot_prop
+                if self.paged:
+                    self._slot_idx[i] = int(idx_np[i])
+                if rem_np[i] == 0 or idx_np[i] + gamma >= self.max_seq:
+                    finished.append(self._retire_slot(i, now))
+                elif self.paged:
+                    # rollback freed tokens past the accepted prefix: release
+                    # the pages the worst-case top-up provisioned beyond them
+                    self._trim_slot_pages(i)
+            if self.num_prefilling:
+                # the fused loop pinned every frozen slot's draft index to its
+                # TARGET index; mid-prefill the two streams sit at different
+                # offsets, so restore the draft's own progress
+                self._restore_draft_prefill_indices()
+            if self.paged and self._bt_dirty:
+                self._sync_block_tables()  # one upload covers trims + retires
+            return finished
 
     # ------------------------------------------------------------------
     # Host-proposed tree verification (DESIGN.md §10)
@@ -1483,7 +1534,7 @@ class InferenceEngine:
             from repro.spec.tree import tree_verify_round as _tree_fn
 
             fn = jax.jit(
-                functools.partial(
+                _program(
                     _tree_fn, self.cfg, parents=parents, mode=mode,
                     max_seq=self.max_seq,
                     sim_accept_p=self.spec_cfg.sim_accept_p,
@@ -1545,25 +1596,45 @@ class InferenceEngine:
         width = max(1, self.spec_cfg.tree_width)
         mode = "simulated" if self.spec_cfg.mode == "simulated" else "greedy"
         finished: list[Request] = []
+        span = self.obs.span
         for _ in range(k):
             if self.num_active == 0 or (
                 self.num_active == self.num_prefilling
             ):
                 break
-            remaining = np.zeros((self.max_slots,), np.int32)
-            hists: list[list[int]] = [[] for _ in range(self.max_slots)]
-            for i, r in enumerate(self.slots):
-                if r is not None and not self.slot_prefilling(i):
-                    remaining[i] = max(
-                        r.max_new_tokens - len(r.generated), 0
+            with span("engine.decode"):
+                remaining = np.zeros((self.max_slots,), np.int32)
+                hists: list[list[int]] = [[] for _ in range(self.max_slots)]
+                for i, r in enumerate(self.slots):
+                    if r is not None and not self.slot_prefilling(i):
+                        remaining[i] = max(
+                            r.max_new_tokens - len(r.generated), 0
+                        )
+                        hists[i] = [int(t) for t in r.prompt] + r.generated
+                if not remaining.any():
+                    break
+                tree = prop.propose(ProposeContext(
+                    histories=hists, active=remaining > 0, gamma=gamma,
+                    width=width,
+                ))
+                if tree is not None:
+                    n_nodes = len(tree.parents)
+                    if self.paged:
+                        # worst case the round accepts a whole root-to-leaf
+                        # path; node-index K/V slots need n_nodes positions
+                        # regardless
+                        self._top_up_pages(n_nodes)
+                        if self.num_active == 0:
+                            break  # every slot fell to an allocator fault
+                    self._maybe_inject_nan()
+                    (
+                        self.tokens, self.cache, rem, self._spec_key,
+                        out, n_out, accepted, proposed, bad,
+                    ) = self._tree_round_fn(tree.parents, mode)(
+                        self.params, self.tokens, self.cache,
+                        jnp.asarray(tree.tail), jnp.asarray(remaining),
+                        self._spec_key,
                     )
-                    hists[i] = [int(t) for t in r.prompt] + r.generated
-            if not remaining.any():
-                break
-            tree = prop.propose(ProposeContext(
-                histories=hists, active=remaining > 0, gamma=gamma,
-                width=width,
-            ))
             if tree is None:
                 # no slot matched: the round IS zero-acceptance evidence —
                 # without it the optimistic prior would route a useless
@@ -1576,70 +1647,55 @@ class InferenceEngine:
                     self._router.observe(int(i), proposer, 0, gamma)
                 finished.extend(self._drive_decode_loop(1))
                 continue
-            n_nodes = len(tree.parents)
-            if self.paged:
-                # worst case the round accepts a whole root-to-leaf path;
-                # node-index K/V slots need n_nodes positions regardless
-                self._top_up_pages(n_nodes)
-                if self.num_active == 0:
-                    break  # every slot fell to an allocator fault
-            self._maybe_inject_nan()
-            (
-                self.tokens, self.cache, rem, self._spec_key,
-                out, n_out, accepted, proposed, bad,
-            ) = self._tree_round_fn(tree.parents, mode)(
-                self.params, self.tokens, self.cache,
-                jnp.asarray(tree.tail), jnp.asarray(remaining),
-                self._spec_key,
-            )
             toks_np, n_np, acc_np, prop_np, rem_np, idx_np, bad_np = (
-                jax.device_get((
+                self._fetch((
                     out, n_out, accepted, proposed, rem,
                     self.cache["index"], bad,
                 ))
             )
-            self.d2h_transfers += 1  # one per round: proposals need history
-            self.steps_executed += 1
-            self.spec_rounds += 1
-            self.obs.metrics.gauge("spec/proposer/tree_nodes").set(n_nodes)
-            round_acc = round_prop = 0
-            now = self.clock()
-            for i, req in enumerate(self.slots):
-                if req is None or self.slot_prefilling(i):
-                    continue
-                if bad_np[i]:
-                    self._quarantine_slot(i)
-                    continue
-                n = int(n_np[i])
-                req.generated.extend(int(t) for t in toks_np[i, :n])
-                self.generated_tokens_total += n
-                if self.paged:
-                    self._slot_idx[i] = int(idx_np[i])
-                if tree.matched[i]:
-                    acc, prp = int(acc_np[i]), int(prop_np[i])
-                    round_acc += acc
-                    round_prop += prp
-                    self._router.observe(i, proposer, acc, prp)
-                    prop.observe(i, acc, prp)
-                elif remaining[i] > 0:
-                    # the proposer declined THIS slot while serving others:
-                    # zero-acceptance routing evidence for the slot, but
-                    # not a drafted proposal (its filler row was always
-                    # going to be rejected), so the counters stay clean
-                    self._router.observe(i, proposer, 0, gamma)
-                if rem_np[i] == 0 or idx_np[i] + (
-                    n_nodes - 1
-                ) >= self.max_seq:
-                    finished.append(self._retire_slot(i, now))
-                elif self.paged:
-                    # rejected siblings past the accepted path: release the
-                    # pages the worst-case top-up provisioned beyond it
-                    self._trim_slot_pages(i)
-            self.spec_accepted += round_acc
-            self.spec_drafted += round_prop
-            self._note_proposer_round(proposer, 1, round_acc, round_prop)
-            if self.paged and self._bt_dirty:
-                self._sync_block_tables()
+            with span("engine.decode"):
+                self.d2h_transfers += 1  # one per round: proposals need history
+                self.steps_executed += 1
+                self.spec_rounds += 1
+                self.obs.metrics.gauge("spec/proposer/tree_nodes").set(n_nodes)
+                round_acc = round_prop = 0
+                now = self.clock()
+                for i, req in enumerate(self.slots):
+                    if req is None or self.slot_prefilling(i):
+                        continue
+                    if bad_np[i]:
+                        self._quarantine_slot(i)
+                        continue
+                    n = int(n_np[i])
+                    req.generated.extend(int(t) for t in toks_np[i, :n])
+                    self.generated_tokens_total += n
+                    if self.paged:
+                        self._slot_idx[i] = int(idx_np[i])
+                    if tree.matched[i]:
+                        acc, prp = int(acc_np[i]), int(prop_np[i])
+                        round_acc += acc
+                        round_prop += prp
+                        self._router.observe(i, proposer, acc, prp)
+                        prop.observe(i, acc, prp)
+                    elif remaining[i] > 0:
+                        # the proposer declined THIS slot while serving others:
+                        # zero-acceptance routing evidence for the slot, but
+                        # not a drafted proposal (its filler row was always
+                        # going to be rejected), so the counters stay clean
+                        self._router.observe(i, proposer, 0, gamma)
+                    if rem_np[i] == 0 or idx_np[i] + (
+                        n_nodes - 1
+                    ) >= self.max_seq:
+                        finished.append(self._retire_slot(i, now))
+                    elif self.paged:
+                        # rejected siblings past the accepted path: release the
+                        # pages the worst-case top-up provisioned beyond it
+                        self._trim_slot_pages(i)
+                self.spec_accepted += round_acc
+                self.spec_drafted += round_prop
+                self._note_proposer_round(proposer, 1, round_acc, round_prop)
+                if self.paged and self._bt_dirty:
+                    self._sync_block_tables()
         return finished
 
     # ------------------------------------------------------------------
@@ -1676,9 +1732,7 @@ class InferenceEngine:
                 np.asarray(slots_)
             ].set(np.asarray(values, np.int32))
         finished = []
-        host_tokens, idx_np = jax.device_get(
-            (next_tokens, self.cache["index"])
-        )
+        host_tokens, idx_np = self._fetch((next_tokens, self.cache["index"]))
         self.d2h_transfers += 1  # tokens + finish-check indices, batched
         now = self.clock()
         for i, req in enumerate(self.slots):

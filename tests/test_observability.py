@@ -25,6 +25,7 @@ from repro.obs import (
     StepTracer,
     StreamingHistogram,
     attribute,
+    chrome_trace,
     validate_events,
     validate_jsonl,
 )
@@ -314,3 +315,90 @@ def test_trace_export_roundtrip(traced_run, tmp_path):
         "per-slot tracks must exist"
     assert any(e.get("name") == "quantum" for e in doc["traceEvents"])
     assert any(e.get("name") == "train_compute" for e in doc["traceEvents"])
+
+
+# ----------------------------------------------------------------------
+# host spans, wall stamps, per-slot decode drawn at export
+# ----------------------------------------------------------------------
+def test_host_span_counters_advance_and_nest(traced_run):
+    engine, _ = traced_run
+    m = engine.obs.metrics
+    ns = {name: m.counter("host_ns/" + name).value for name in (
+        "runtime.train_step", "runtime.fill", "runtime.monitor", "core.step",
+        "core.plan", "core.admit", "core.collect", "core.record",
+        "engine.tables", "engine.prefill", "engine.decode", "engine.fetch",
+    )}
+    assert all(v > 0 for v in ns.values()), ns
+    assert ns["runtime.fill"] >= ns["core.step"] >= ns["engine.fetch"]
+    inner = sum(ns[n] for n in ("core.plan", "core.admit", "engine.prefill",
+                                "engine.decode", "engine.fetch",
+                                "core.collect", "core.record"))
+    assert inner <= ns["core.step"]
+    quanta = [ev for ev in engine.obs.tracer.events if ev["type"] == "quantum"]
+    assert m.counter("core/quanta").value == len(quanta) > 0
+    assert all(name in STABLE_NAMES for name in m.names()
+               if name.startswith("host_ns/"))
+
+
+def test_span_records_annotation_and_refuses_to_nest_in_itself():
+    obs = Observability()
+    with obs.span("engine.tables") as sp:
+        sp.set_metadata(seq=1)
+        with pytest.raises(RuntimeError, match="inside itself"):
+            with obs.span("engine.tables"):
+                pass
+    t = obs.metrics.counter("host_ns/engine.tables").value
+    assert obs.metrics.names() == ["host_ns/engine.tables"] and t > 0
+    with obs.span("engine.tables"):  # closed again: reusable
+        pass
+    assert obs.metrics.counter("host_ns/engine.tables").value > t
+
+
+def test_wall_stamps_are_ordered(traced_run):
+    engine, metrics = traced_run
+    done = [cr for cr in engine.core.requests.values()
+            if cr.state.finished and cr.output_tokens]
+    assert len(done) >= metrics.online_served >= 2
+    for cr in done:
+        stamps = (cr.arrival_wall_ns, cr.admit_wall_ns,
+                  cr.first_token_wall_ns, cr.finish_wall_ns)
+        assert all(isinstance(x, int) for x in stamps), stamps
+        assert stamps[0] <= stamps[1] <= stamps[2] <= stamps[3], stamps
+    quanta = [ev for ev in engine.obs.tracer.events if ev["type"] == "quantum"]
+    walls = [ev["args"]["wall_ns"] for ev in quanta]
+    assert all(a <= b for a, b in walls)
+    assert all(b0 <= a1 for (_, b0), (a1, _) in zip(walls, walls[1:]))
+
+
+def test_chrome_draws_slot_tracks_without_per_slot_decode_spans(traced_run):
+    engine, _ = traced_run
+    events = engine.obs.tracer.events
+    assert not [ev for ev in events if ev["type"] == "span"
+                and ev["name"] in ("decode", "spec_round")]
+    decoded = [ev for ev in events if ev["type"] == "quantum"
+               and ev["args"]["decoded"]]
+    assert decoded
+    doc = chrome_trace(json.loads(json.dumps(events)))  # as a JSONL reader
+    threads = {e["tid"]: e["args"]["name"] for e in doc["traceEvents"]
+               if e.get("name") == "thread_name"}
+    drawn = [e for e in doc["traceEvents"]
+             if e.get("ph") == "X" and e["name"] == "decode"]
+    assert len(drawn) == sum(len(ev["args"]["decoded"]) for ev in decoded)
+    assert {threads[e["tid"]] for e in drawn} <= {
+        f"slot{i}" for i in range(engine.max_slots)}
+    first = decoded[0]
+    slot, rid = next(iter(first["args"]["decoded"].items()))
+    match = [e for e in drawn if e["args"]["request_id"] == rid
+             and threads[e["tid"]] == f"slot{slot}"
+             and e["ts"] == first["args"]["decode_t0"] * 1e6]
+    assert match and match[0]["args"]["k"] == first["args"]["k"]
+
+
+def test_schema_checks_quantum_wall_and_decoded_args():
+    ok = {"type": "quantum", "t0": 0.0, "t1": 1.0, "seq": 0,
+          "args": {"decoded": {"0": 3}, "decode_t0": 0.5, "wall_ns": [5, 9]}}
+    assert validate_events([ok]) == []
+    for bad_args in ({"decoded": [0, 3]}, {"wall_ns": [9, 5]},
+                     {"wall_ns": [1.5, 2.0]}, {"decode_t0": "x"}):
+        bad = dict(ok, args=bad_args)
+        assert validate_events([bad]), bad_args

@@ -128,11 +128,34 @@ KERNELS = (
 )
 
 
+@pytest.fixture(scope="module")
+def kernel_hlo(one_chip):
+    """name -> the compiled HLO text of that kernel (compiled once)."""
+    cache = {}
+
+    def get(name):
+        if name not in cache:
+            fn, args = _kernel_cases(one_chip)[name]
+            cache[name] = jax.jit(fn).lower(*args).compile().as_text()
+        return cache[name]
+
+    return get
+
+
 @pytest.mark.parametrize("name", KERNELS)
-def test_attention_kernel_compiles_for_v5e(name, one_chip):
-    fn, args = _kernel_cases(one_chip)[name]
-    compiled = jax.jit(fn).lower(*args).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+def test_attention_kernel_compiles_for_v5e(name, kernel_hlo):
+    assert "tpu_custom_call" in kernel_hlo(name)
+
+
+@pytest.mark.parametrize("name", KERNELS)
+def test_attention_kernel_ops_keep_the_kernel_name(name, kernel_hlo):
+    """The device trace names a kernel's operations by their HLO
+    instruction, ``<kernel>.<n>``; the benchmark's roofline readers find
+    the kernels by that prefix (``paged_decode_attention``, ...)."""
+    ops = [line.split("=", 1)[0].split()[-1].lstrip("%")
+           for line in kernel_hlo(name).splitlines()
+           if "custom_call_target=\"tpu_custom_call\"" in line]
+    assert ops and all(op.startswith(f"{name}_attention.") for op in ops), ops
 
 
 def test_flash_attention_compiles_for_v5e(one_chip):
