@@ -16,9 +16,9 @@ compiled for the chip, not interpreted).
 
 The collocated phase builds its ``SpecInFRuntime`` with
 ``launch.train.collocated_runtime``, the code behind ``launch/train.py
---collocate`` — the engine serves the trainer's own params in the bubbles
-of a ``dp_profile`` — at qwen3-1.7b widths with the depth cut to what fits
-one chip.  The same train steps run first without filling, from
+--collocate`` — the engine serves a bf16 copy of the trainer's params in
+the bubbles of a ``dp_profile`` — at qwen3-1.7b widths with the depth cut
+to what fits one chip.  The same train steps run first without filling, from
 the same state and data; the losses must agree bit for bit.
 
 Times printed are a smoke, not a benchmark.  Every phase that fails ends
@@ -273,8 +273,8 @@ def collocated_phase(mesh, *, layers: int, steps: int, seq_len: int,
         f"{[round(t * 1e3, 2) for t in base_times]} ms; losses {base_losses}")
 
     # -- with filling, through the entry point of ``launch/train.py
-    # --collocate``: the engine serves the trainer's own params (on a
-    # multi-chip mesh, a copy of them on the first chip)
+    # --collocate``: the engine serves its bf16 copy of the trainer's
+    # params (on a multi-chip mesh, made on the first chip)
     trainer.state = put_state()
     fill_times = []
     rt = collocated_runtime(cfg, trainer, max_seq=seq_len,

@@ -548,10 +548,14 @@ class SpecInFRuntime:
         """Run ``num_iterations`` training iterations: the train step (the
         ``runtime.train_step`` host span, its loss's fetch included), then
         the profile's segments, each bubble filled in a ``runtime.fill``
-        span."""
+        span.  The engine's serving copy of the params is freed before each
+        train step, which then has the memory it has without filling, and
+        made again by the iteration's first quantum (DESIGN.md §3)."""
         span = self.obs.span
         for _ in range(num_iterations):
             batch = next(self.batch_iter)
+            if self.engine is not None:
+                self.engine.release_params()
             with span("runtime.train_step"):
                 self.state, step_metrics = self.train_step(self.state, batch)
                 loss = step_metrics.get("loss")

@@ -57,6 +57,8 @@ STABLE_NAMES = {
     "engine/spec_rounds": "counter",
     "engine/spec_drafted": "counter",
     "engine/spec_accepted": "counter",
+    # serving copies the engine cast to its compute dtype
+    "engine/serving_param_casts": "counter",
     # pluggable speculation proposers (DESIGN.md §10)
     "spec/proposer/rounds/draft": "counter",
     "spec/proposer/rounds/ngram": "counter",

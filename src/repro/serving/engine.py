@@ -122,7 +122,8 @@ def _on_one_device(tree: Any) -> Any:
     the default device, and the TPU lowering cannot partition its Pallas
     kernels over a mesh.  Params sharded over several devices (a collocated
     trainer's) are copied onto the default device; params already on one
-    device are served as they are, without a copy."""
+    device are left where they are (``InferenceEngine._serving`` then
+    serves a copy in its compute dtype)."""
     sharded = any(
         isinstance(x, jax.Array) and len(x.sharding.device_set) > 1
         for x in jax.tree.leaves(tree)
@@ -245,7 +246,12 @@ class InferenceEngine:
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.compute_dtype = compute_dtype
-        self.params = _on_one_device(params)
+        #: the trees the engine was handed, on one device; the programs are
+        #: fed compute-dtype copies of them (``params``, ``draft_params``)
+        self._given = {"target": _on_one_device(params),
+                       "draft": _on_one_device(draft_params)}
+        self._served: dict = {}
+        self._cast = jax.jit(_program(T.cast_params, dtype=compute_dtype))
         self.clock: Callable[[], float] = clock or time.monotonic
         self.min_prefill_bucket = min_prefill_bucket
         #: decode-path attention impl, kept for programs built after
@@ -411,7 +417,6 @@ class InferenceEngine:
 
         # --- speculative decoding (draft/target pairing) ---------------
         self.draft_cfg = draft_cfg
-        self.draft_params = _on_one_device(draft_params)
         self.draft_cache = None
         self.spec_cfg = spec or SpecDecodeConfig()
         #: PRNG stream for simulated-acceptance modes (spec loop AND the
@@ -496,8 +501,43 @@ class InferenceEngine:
 
     # ------------------------------------------------------------------
     @property
+    def params(self) -> Any:
+        """The target params the programs are fed (``_serving``)."""
+        return self._serving("target")
+
+    @property
+    def draft_params(self) -> Any:
+        """The draft params the programs are fed, None without a draft."""
+        return self._serving("draft")
+
+    def _serving(self, model: str) -> Any:
+        """The serving copy of a given tree: its float32 matrices cast to
+        the compute dtype by ONE program on first use, instead of by
+        ``T.cast_params`` inside every program on every call (their casts
+        of the copy are no-ops), then kept until ``release_params``.  The
+        values are the ones those casts compute, and the copy shares no
+        buffer with the given tree (DESIGN.md §3, "Serving params").  A
+        float32 engine serves the given tree as it is."""
+        tree = self._served.get(model)
+        if tree is None:
+            tree = self._given[model]
+            if tree is not None and (
+                jnp.dtype(self.compute_dtype) != jnp.float32
+            ):
+                self.obs.metrics.counter("engine/serving_param_casts").inc()
+                tree = self._cast(tree)
+            self._served[model] = tree
+        return tree
+
+    def release_params(self) -> None:
+        """Free the serving copies; the next program call makes them
+        again.  A collocated runtime calls this before each train step, so
+        the step runs with the device memory it has without filling."""
+        self._served.clear()
+
+    @property
     def spec_enabled(self) -> bool:
-        return self.draft_params is not None
+        return self._given["draft"] is not None
 
     @property
     def host_spec_enabled(self) -> bool:
@@ -1766,9 +1806,9 @@ class InferenceEngine:
     def memory_bytes(self) -> int:
         """Weights + cache footprint (Principle-I input).
 
-        Counts the target params and KV cache (dense rows or paged pool +
-        block tables) AND — when a draft pairing is attached — the draft
-        params and draft cache, which earlier revisions omitted,
+        Counts the target params as served and the KV cache (dense rows or
+        paged pool + block tables) AND — when a draft pairing is attached —
+        the draft params and draft cache, which earlier revisions omitted,
         understating the capacity Algorithm 1 budgets against."""
         leaves = list(jax.tree.leaves(self.params)) + list(
             jax.tree.leaves(self.cache)

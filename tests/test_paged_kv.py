@@ -477,12 +477,18 @@ def test_memory_bytes_counts_draft_and_pool():
         x.size * x.dtype.itemsize for x in jax.tree.leaves(t)
     )
     assert plain.memory_bytes() == (
-        leaf_bytes(PARAMS) + leaf_bytes(plain.cache)
+        leaf_bytes(plain.params) + leaf_bytes(plain.cache)
     )
     # the pool (inside cache) is accounted, and the draft side no longer
     # disappears from the capacity input
     assert spec.memory_bytes() == (
-        leaf_bytes(PARAMS) + leaf_bytes(spec.cache)
-        + leaf_bytes(DPARAMS) + leaf_bytes(spec.draft_cache)
+        leaf_bytes(spec.params) + leaf_bytes(spec.cache)
+        + leaf_bytes(spec.draft_params) + leaf_bytes(spec.draft_cache)
     )
     assert spec.memory_bytes() > plain.memory_bytes()
+    # the bf16 engine holds its serving copy, not the f32 tree: matrices
+    # at half their f32 bytes, vectors as they are
+    matrix_bytes = lambda t: sum(
+        x.size * x.dtype.itemsize for x in jax.tree.leaves(t) if x.ndim > 1
+    )
+    assert 2 * matrix_bytes(plain.params) == matrix_bytes(PARAMS)

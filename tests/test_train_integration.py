@@ -174,19 +174,34 @@ def test_train_step_auto_attention_is_xla_where_forward_auto_is_the_kernel(
 
 
 def test_collocated_runtime_serves_the_trainer_params(mesh):
-    """``launch/train.py --collocate``: on a one-device mesh the engine
-    serves the trainer's own param buffers, and filling leaves the
-    training trajectory finite."""
+    """``launch/train.py --collocate``: the engine serves the trainer's
+    initial params cast once to its bf16 compute dtype, in buffers of its
+    own, and filling leaves the training trajectory finite."""
     from repro.launch.train import collocated_runtime
+    from repro.models import transformer as T
 
     cfg = configs.smoke_config("qwen3-1.7b")
     tcfg = TrainConfig(fsdp=False, zero1=False)
     trainer = Trainer(cfg, tcfg, mesh, seq_len=32, global_batch=4)
     rt = collocated_runtime(cfg, trainer, max_seq=32)
-    assert rt.engine.params is trainer.state["params"]
+    given = trainer.state["params"]
+    want = T.cast_params(given, jnp.bfloat16)
+    served = rt.engine.params
+    assert jax.tree.structure(served) == jax.tree.structure(want)
+    for s, w in zip(jax.tree.leaves(served), jax.tree.leaves(want)):
+        assert s.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(s, np.float32),
+                                      np.asarray(w, np.float32))
+    trainer_buffers = {x.unsafe_buffer_pointer()
+                       for x in jax.tree.leaves(given)}
+    assert not any(x.unsafe_buffer_pointer() in trainer_buffers
+                   for x in jax.tree.leaves(served))
     metrics = rt.run(2)
     assert metrics.train_iterations == 2
     assert np.isfinite(metrics.train_losses).all()
+    # freed before each train step, made again by each iteration's fill
+    casts = rt.engine.obs.metrics.counter("engine/serving_param_casts")
+    assert casts.value == 1 + metrics.train_iterations
 
 
 def _trainer(mesh, tmp_path):
