@@ -80,9 +80,10 @@ def collocated_runtime(cfg, trainer, *, max_seq: int, train_step=None,
                        batch_iter=None):
     """SpecInF end-to-end: the trainer's step runs under the
     speculative-filling runtime, and an inference engine serving the
-    trainer's initial params (a bf16 copy on one device, made while it
-    serves; see ``InferenceEngine``) decodes four offline requests in the
-    bubbles of a ``dp_profile``.
+    trainer's initial params decodes four offline requests in the bubbles
+    of a ``dp_profile``.  The engine serves a bf16 copy on one device: cast
+    while it serves from params on one device, or gathered once and kept
+    from params sharded over the trainer's mesh (see ``InferenceEngine``).
 
     ``train_step`` and ``batch_iter`` default to the trainer's jitted step
     and data stream; the runtime starts from ``trainer.state``."""

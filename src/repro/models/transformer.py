@@ -20,7 +20,7 @@ cycle.
 from __future__ import annotations
 
 import functools
-from typing import Any, Optional
+from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
@@ -327,6 +327,34 @@ def is_paged_cache(cache: Params) -> bool:
     return isinstance(cache, dict) and "block_tables" in cache
 
 
+def _scan_paged_layers(layer_fn: Callable, x: jax.Array, layers: Params,
+                       cache: Params) -> tuple[jax.Array, Params]:
+    """``lax.scan`` of ``layer_fn(x, lp, (k_pool, v_pool), tables) -> (x,
+    (k_pool, v_pool))`` over the layers of a paged cache, its pools carried
+    whole.  The [L, P, page, kvH, hd] pools are viewed as one [L * P, ...]
+    pool and layer ``l`` reads and writes it through its block table offset
+    by ``l * P``, so every layer's K/V write lands in place in the one
+    carried buffer.  Scanning the pools as per-layer slices instead makes
+    XLA slice each layer's pool out and stack the updated ones into a new
+    pool: a copy of the whole pool a call.  Each layer's page 0 stays its
+    sentinel page."""
+    kp, vp = cache["layers"]["k"], cache["layers"]["v"]
+    n, pages = kp.shape[:2]
+    flat = lambda a: a.reshape((n * pages,) + a.shape[2:])
+    bt = cache["block_tables"]
+
+    def body(carry, per_layer):
+        xc, kv = carry
+        lp, layer = per_layer
+        return layer_fn(xc, lp, kv, bt + layer * pages), None
+
+    (x, (k_all, v_all)), _ = jax.lax.scan(
+        body, (x, (flat(kp), flat(vp))),
+        (layers, jnp.arange(n, dtype=jnp.int32)),
+    )
+    return x, {"k": k_all.reshape(kp.shape), "v": v_all.reshape(vp.shape)}
+
+
 # ---------------------------------------------------------------------------
 # Decode step
 # ---------------------------------------------------------------------------
@@ -351,18 +379,16 @@ def decode_step(
     cast = functools.partial(cast_params, dtype=compute_dtype)
 
     if cfg.family in ("dense", "moe", "audio", "vlm"):
-        bt = cache.get("block_tables")  # paged cache: [B, W] page map
 
-        def body(xc, per_layer):
-            lp, k_c, v_c = per_layer
+        def layer(xc, lp, kv, tables):
             h = L.norm(cfg, xc, lp.get("ln1"))
-            if bt is not None:
-                y, (k_c, v_c) = L.attention_decode_paged(
-                    cfg, lp["attn"], h, (k_c, v_c), bt, idx, impl=attn_impl
+            if tables is not None:  # paged cache: [B, W] page map
+                y, kv = L.attention_decode_paged(
+                    cfg, lp["attn"], h, kv, tables, idx, impl=attn_impl
                 )
             else:
-                y, (k_c, v_c) = L.attention_decode(
-                    cfg, lp["attn"], h, (k_c, v_c), idx, impl=attn_impl
+                y, kv = L.attention_decode(
+                    cfg, lp["attn"], h, kv, idx, impl=attn_impl
                 )
             xc = xc + y
             h = L.norm(cfg, xc, lp.get("ln2"))
@@ -370,12 +396,18 @@ def decode_step(
                 y2, _, _ = MOE.moe_block(cfg, lp["ffn"], h)
             else:
                 y2 = L.mlp_block(lp["ffn"], h)
-            return xc + y2, (k_c, v_c)
+            return xc + y2, kv
 
-        x, (k_new, v_new) = jax.lax.scan(
-            body, x, (cast(params["layers"]), cache["layers"]["k"], cache["layers"]["v"])
-        )
-        new_layers = {"k": k_new, "v": v_new}
+        if is_paged_cache(cache):
+            x, new_layers = _scan_paged_layers(
+                layer, x, cast(params["layers"]), cache
+            )
+        else:
+            x, (k_new, v_new) = jax.lax.scan(
+                lambda xc, per: layer(xc, per[0], per[1:], None), x,
+                (cast(params["layers"]), cache["layers"]["k"], cache["layers"]["v"]),
+            )
+            new_layers = {"k": k_new, "v": v_new}
     elif cfg.family == "ssm":
 
         def body(xc, per_layer):
@@ -952,20 +984,17 @@ def prefill_chunks_into_slots(
     x = embed_tokens(cfg, params, tokens, compute_dtype)  # [B, C, d]
     idx = cache["index"]
     lens = jnp.asarray(chunk_lens, jnp.int32)
-    bt = cache.get("block_tables")  # paged cache: [B, W] page map
     cast = functools.partial(cast_params, dtype=compute_dtype)
 
-    def body(xc, per_layer):
-        lp, k_c, v_c = per_layer
+    def layer(xc, lp, kv, tables):
         h = L.norm(cfg, xc, lp.get("ln1"))
-        if bt is not None:
-            y, (k_c, v_c) = L.attention_prefill_chunk_paged(
-                cfg, lp["attn"], h, (k_c, v_c), bt, idx, lens,
-                impl=attn_impl,
+        if tables is not None:  # paged cache: [B, W] page map
+            y, kv = L.attention_prefill_chunk_paged(
+                cfg, lp["attn"], h, kv, tables, idx, lens, impl=attn_impl,
             )
         else:
-            y, (k_c, v_c) = L.attention_prefill_chunk(
-                cfg, lp["attn"], h, (k_c, v_c), idx, lens, impl=attn_impl
+            y, kv = L.attention_prefill_chunk(
+                cfg, lp["attn"], h, kv, idx, lens, impl=attn_impl
             )
         xc = xc + y
         h = L.norm(cfg, xc, lp.get("ln2"))
@@ -973,14 +1002,20 @@ def prefill_chunks_into_slots(
             y2, _, _ = MOE.moe_block(cfg, lp["ffn"], h)
         else:
             y2 = L.mlp_block(lp["ffn"], h)
-        return xc + y2, (k_c, v_c)
+        return xc + y2, kv
 
-    x, (k_new, v_new) = jax.lax.scan(
-        body, x,
-        (cast(params["layers"]), cache["layers"]["k"], cache["layers"]["v"]),
-    )
+    if is_paged_cache(cache):
+        x, new_layers = _scan_paged_layers(
+            layer, x, cast(params["layers"]), cache
+        )
+    else:
+        x, (k_new, v_new) = jax.lax.scan(
+            lambda xc, per: layer(xc, per[0], per[1:], None), x,
+            (cast(params["layers"]), cache["layers"]["k"], cache["layers"]["v"]),
+        )
+        new_layers = {"k": k_new, "v": v_new}
     index = idx + lens
-    new_cache = dict(cache, index=index, layers={"k": k_new, "v": v_new})
+    new_cache = dict(cache, index=index, layers=new_layers)
     if not need_logits:
         return jnp.zeros((b,), jnp.int32), new_cache
     x = L.norm(cfg, x, params.get("final_norm"))

@@ -57,8 +57,12 @@ STABLE_NAMES = {
     "engine/spec_rounds": "counter",
     "engine/spec_drafted": "counter",
     "engine/spec_accepted": "counter",
-    # serving copies the engine cast to its compute dtype
+    # serving copies the engine cast to its compute dtype, or gathered
+    # from a sharded tree; block tables its decode loop moved onto its
+    # device
     "engine/serving_param_casts": "counter",
+    "engine/serving_param_gathers": "counter",
+    "engine/placement_moves": "counter",
     # pluggable speculation proposers (DESIGN.md §10)
     "spec/proposer/rounds/draft": "counter",
     "spec/proposer/rounds/ngram": "counter",
@@ -101,6 +105,7 @@ STABLE_NAMES = {
     "host_ns/engine.prefill": "counter",
     "host_ns/engine.decode": "counter",
     "host_ns/engine.fetch": "counter",
+    "host_ns/engine.gather_params": "counter",
     # failure containment + graceful degradation (DESIGN.md §9)
     "fault/injected": "counter",
     "fault/nan_quarantines": "counter",

@@ -62,7 +62,8 @@ first:
   ``core.record`` (gauges and the tracer).
 * ``engine.tables`` — block-table top-ups and uploads; ``engine.fetch`` —
   every blocking device-to-host copy of the engine: the host waiting on
-  the device.
+  the device; ``engine.gather_params`` — at construction, the cast and
+  gather of a sharded tree into the engine's serving copy.
 
 ``core/quanta`` counts ``EngineCore.step`` calls.
 """

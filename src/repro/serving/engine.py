@@ -117,18 +117,18 @@ DEFAULT_PREFILL_CHUNK = 32
 _ATTENTION_FAMILIES = ("dense", "moe", "audio", "vlm")
 
 
-def _on_one_device(tree: Any) -> Any:
-    """The engine runs on one device: its cache and token arrays are made on
-    the default device, and the TPU lowering cannot partition its Pallas
-    kernels over a mesh.  Params sharded over several devices (a collocated
-    trainer's) are copied onto the default device; params already on one
-    device are left where they are (``InferenceEngine._serving`` then
-    serves a copy in its compute dtype)."""
-    sharded = any(
+def _on_one_device(tree: Any) -> bool:
+    """Whether every array of ``tree`` lives on one device.  The engine runs
+    on one device: its cache and token arrays are made on the default
+    device, and the TPU lowering cannot partition its Pallas kernels over a
+    mesh.  A tree on one device is served as ``InferenceEngine._serving``
+    says; a tree sharded over several (a collocated trainer's) is gathered
+    once, already in the compute dtype, into a copy the engine keeps
+    (``InferenceEngine._gathered``)."""
+    return not any(
         isinstance(x, jax.Array) and len(x.sharding.device_set) > 1
         for x in jax.tree.leaves(tree)
     )
-    return jax.device_put(tree, jax.devices()[0]) if sharded else tree
 
 
 def _program(fn: Callable, *args, **kwargs) -> Callable:
@@ -246,12 +246,21 @@ class InferenceEngine:
         self.max_slots = max_slots
         self.max_seq = max_seq
         self.compute_dtype = compute_dtype
-        #: the trees the engine was handed, on one device; the programs are
-        #: fed compute-dtype copies of them (``params``, ``draft_params``)
-        self._given = {"target": _on_one_device(params),
-                       "draft": _on_one_device(draft_params)}
-        self._served: dict = {}
+        #: the device every program runs on
+        self.device = jax.devices()[0]
         self._cast = jax.jit(_program(T.cast_params, dtype=compute_dtype))
+        #: the trees the engine was handed if on one device, else the
+        #: compute-dtype copies gathered from them (``_resident``, kept for
+        #: the engine's life); the programs are fed ``params`` and
+        #: ``draft_params``
+        self._resident: set = set()
+        self._given = {}
+        for model, tree in (("target", params), ("draft", draft_params)):
+            if tree is not None and not _on_one_device(tree):
+                tree = self._gathered(tree)
+                self._resident.add(model)
+            self._given[model] = tree
+        self._served: dict = {}
         self.clock: Callable[[], float] = clock or time.monotonic
         self.min_prefill_bucket = min_prefill_bucket
         #: decode-path attention impl, kept for programs built after
@@ -380,6 +389,8 @@ class InferenceEngine:
             static_argnames=("k",),
             donate_argnames=("tokens", "cache", "remaining"),
         )
+        if self._resident and self.paged:
+            self._decode_loop = self._tables_on_device(self._decode_loop)
         if self.paged:
             self._prefill_slot = jax.jit(
                 _program(
@@ -517,11 +528,12 @@ class InferenceEngine:
         of the copy are no-ops), then kept until ``release_params``.  The
         values are the ones those casts compute, and the copy shares no
         buffer with the given tree (DESIGN.md §3, "Serving params").  A
-        float32 engine serves the given tree as it is."""
+        float32 engine serves the given tree as it is, and a gathered
+        copy (``_resident``) is served as it is, always."""
         tree = self._served.get(model)
         if tree is None:
             tree = self._given[model]
-            if tree is not None and (
+            if tree is not None and model not in self._resident and (
                 jnp.dtype(self.compute_dtype) != jnp.float32
             ):
                 self.obs.metrics.counter("engine/serving_param_casts").inc()
@@ -529,10 +541,43 @@ class InferenceEngine:
             self._served[model] = tree
         return tree
 
+    def _gathered(self, tree: Any) -> Any:
+        """The engine's own copy of a tree sharded over a mesh, on its
+        device, in the compute dtype: ``jit_cast_params`` casts each shard
+        where it lives, then the compute-dtype shards are moved onto the
+        engine's device, so that device never holds the float32 tree.
+        Made once, at construction, and counted on
+        ``engine/serving_param_gathers``."""
+        with self.obs.span("engine.gather_params"):
+            ours = jax.device_put(self._cast(tree), self.device)
+            jax.block_until_ready(ours)
+        self.obs.metrics.counter("engine/serving_param_gathers").inc()
+        return ours
+
+    def _tables_on_device(self, loop: Callable) -> Callable:
+        """``loop`` (the jitted decode loop) taking a cache whose block
+        tables may be placed on a trainer's mesh, as a sharded trainer's
+        harness warms them: such tables are moved onto the engine's device
+        first, one count of ``engine/placement_moves`` each; tables already
+        there pass as they are."""
+        moves = self.obs.metrics.counter("engine/placement_moves")
+
+        def run(params, tokens, cache, *args, **kwargs):
+            bt = cache["block_tables"]
+            if bt.sharding.device_set != {self.device}:
+                cache = dict(cache,
+                             block_tables=jax.device_put(bt, self.device))
+                moves.inc()
+            return loop(params, tokens, cache, *args, **kwargs)
+
+        return run
+
     def release_params(self) -> None:
-        """Free the serving copies; the next program call makes them
-        again.  A collocated runtime calls this before each train step, so
-        the step runs with the device memory it has without filling."""
+        """Free the serving copies cast from trees on one device; the next
+        program call makes them again.  A collocated runtime calls this
+        before each train step, so the step runs with the device memory it
+        has without filling.  A gathered copy stays: it shares no buffer
+        with the trainer and is never made again."""
         self._served.clear()
 
     @property

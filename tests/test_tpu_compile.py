@@ -196,3 +196,106 @@ def test_qwen3_layer_train_step_compiles_for_v5e(topo):
     # params + AdamW moments in f32 are the step's arguments
     n = cfg.param_count()
     assert compiled.memory_analysis().argument_size_in_bytes >= 12 * n
+
+
+OLMO = configs.get_config("olmo-1b")
+
+
+@pytest.mark.parametrize("name", ["paged_decode", "paged_prefill"])
+def test_paged_kernels_compile_at_olmo_widths(name, one_chip):
+    """The serving kernels at OLMo-1B's attention: 16 query and 16 KV
+    heads of 128 (GQA group 1, so every query head reads its own KV)."""
+    h, kvh, hd = OLMO.num_heads, OLMO.num_kv_heads, OLMO.resolved_head_dim
+    bf = lambda *shape: _sds(one_chip, shape)
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    kv = (bf(POOL, PAGE, kvh, hd), bf(POOL, PAGE, kvh, hd))
+    fn, args = {
+        "paged_decode": (paged_decode_attention, (bf(B, h, hd), *kv, i32(B, W), i32(B))),
+        "paged_prefill": (paged_prefill_attention,
+                          (bf(B, C, h, hd), *kv, i32(B, W), i32(B), i32(B))),
+    }[name]
+    assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
+
+
+def _olmo_cell_engine() -> dict:
+    """The engine block (``max_slots``, ``max_seq``) of the benchmark cell
+    that serves OLMo-1B, from its traffic file."""
+    import json
+    from pathlib import Path
+
+    repo = Path(__file__).resolve().parents[1]
+    spec = json.loads((repo / "BENCHMARK.json").read_text())
+    cell = next(w for w in spec["workloads"] if w["name"] == "olmo-colloc-dp2tp2")
+    traffic = json.loads(
+        (repo / "bench" / "traffic" / f"{cell['traffic']}.json").read_text())
+    return traffic["engine"]
+
+
+def _olmo_pool_bytes(es: dict) -> int:
+    """The K and V pools of an engine with the cell's slots and max_seq."""
+    return (es["max_slots"] * es["max_seq"] * OLMO.num_layers * 2
+            * OLMO.num_kv_heads * OLMO.resolved_head_dim * 2)
+
+
+@pytest.mark.parametrize("program", ["decode_loop", "prefill_chunks"])
+def test_olmo_engine_programs_update_the_pool_in_place(program, one_chip,
+                                                       monkeypatch):
+    """The engine's decode loop (k = 8) and chunked prefill at OLMo-1B's
+    widths and the cell's slots, compiled for a described v5e with the
+    Pallas kernels the chip runs: the donated KV pool is updated in place,
+    so the programs' temporaries stay a small share of the pool instead of
+    holding a copy of it."""
+    from repro.kernels import ops
+    from repro.models import transformer as TM
+    from repro.serving.engine import _program
+
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)  # the chip's kernels
+    es = _olmo_cell_engine()
+    slots, max_seq = es["max_slots"], es["max_seq"]
+    pps = -(-max_seq // PAGE)
+    place = lambda x: _sds(one_chip, x.shape, x.dtype)
+    params = jax.tree.map(place, jax.eval_shape(lambda: TM.cast_params(
+        TM.init_params(OLMO, jax.random.PRNGKey(0)), jnp.bfloat16)))
+    cache = jax.tree.map(place, jax.eval_shape(lambda: TM.init_paged_cache(
+        OLMO, slots, slots * pps + 1, PAGE, pps, jnp.bfloat16)))
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    if program == "decode_loop":
+        fn = jax.jit(_program(TM.decode_loop, OLMO, max_seq=max_seq, k=8),
+                     donate_argnames=("tokens", "cache", "remaining"))
+        args = (params, i32(slots), cache, i32(slots))
+    else:
+        fn = jax.jit(_program(TM.prefill_chunks_into_slots, OLMO),
+                     donate_argnames=("cache",))
+        args = (params, i32(slots, C), i32(slots), cache)
+    mem = fn.lower(*args).compile().memory_analysis()
+    pool = _olmo_pool_bytes(es)
+    assert mem.alias_size_in_bytes >= pool
+    assert mem.temp_size_in_bytes < pool / 4, mem.temp_size_in_bytes / 2**30
+
+
+def test_olmo_train_step_on_2x2_leaves_chip0_room_to_serve(topo):
+    """The whole OLMo-1B train step, FSDP + ZeRO-1 on the described
+    ``v5e:2x2`` at the benchmark cell's seq 2048 x batch 4: per device, its
+    arguments, outputs and temporaries, with the initial params the
+    benchmark keeps through its checked steps, leave chip 0 room for the
+    engine's bf16 copy and the cell's KV pool (16 GB chip, 15.75 GiB
+    usable)."""
+    mesh = Mesh(
+        np.array(topo.devices).reshape(2, 2), ("data", "model"),
+        axis_types=(AxisType.Auto, AxisType.Auto),
+    )
+    tcfg = TrainConfig(fsdp=True, zero1=True, remat_policy="full")
+    art = make_train_step(OLMO, tcfg, mesh)
+    shape = ShapeConfig("t", seq_len=2048, global_batch=4, kind="train")
+    mem = art.jitted(donate=False).lower(
+        art.abstract_state(), art.abstract_batch(shape)
+    ).compile().memory_analysis()
+    gib = 2**30
+    n = OLMO.param_count()
+    step = (mem.argument_size_in_bytes + mem.output_size_in_bytes
+            + mem.temp_size_in_bytes)
+    params0 = 4 * n / 4  # f32 params over four devices
+    copy = 2 * n  # the engine's bf16 serving copy
+    pool = _olmo_pool_bytes(_olmo_cell_engine())
+    assert mem.argument_size_in_bytes >= 3 * 4 * n / 4  # params, mu, nu
+    assert (step + params0 + copy + pool) / gib < 15.75, (step / gib, pool / gib)
