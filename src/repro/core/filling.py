@@ -332,6 +332,8 @@ class SpecInFRuntime:
         # timestamp then comes from ONE timebase (never mixed with
         # time.monotonic), and latencies are internally consistent.
         self._vnow = 0.0
+        #: quanta dispatched whose outputs are not folded in yet
+        self._uncollected: list = []
         self.core = None
         self.recovery = None
         if engine is not None:
@@ -409,7 +411,8 @@ class SpecInFRuntime:
 
     def _fill_bubble(self, bubble_s: float) -> None:
         """Fill a virtual bubble of ``bubble_s`` with real engine compute,
-        one ``EngineCore.step()`` quantum at a time.
+        one quantum at a time (``EngineCore.step_overlapped``: each call
+        dispatches a quantum and collects the ones dispatched before it).
 
         Each pass observes one 2 ms monitor window, converts the
         Algorithm-1 decision into a ``Grant`` (token grant, IDLE gate for
@@ -417,9 +420,10 @@ class SpecInFRuntime:
         room as ``max_cost_steps``), and lets ``SpecInFPolicy`` decide what
         the quantum does: admit (preempting offline slots when an online
         arrival is capacity-blocked), pick the k bucket / draft length, and
-        drive the fused loop.  The step's cost in microstep-equivalents
-        advances the virtual clock and the monitor window count — the same
-        accounting whether the quantum was plain or speculative.
+        drive the fused loop.  The step's cost in microstep-equivalents,
+        priced when it is dispatched, advances the virtual clock and the
+        monitor window count — the same accounting whether the quantum was
+        plain or speculative; its outputs are folded in when collected.
 
         Revocation (DESIGN.md §9): when the bubble's ``RevocationSignal``
         is armed (seeded early-resume chaos) every grant carries it — a
@@ -467,7 +471,9 @@ class SpecInFRuntime:
                 revocation=sig,
                 revoke_check_steps=max(self.cfg.revocation_check_steps, 1),
             )
-            out = self.core.step(grant)
+            out = self.core.step_overlapped(grant)
+            self._uncollected.append(out)
+            self._fold_collected()
             if out.cost_steps <= 0:
                 if out.revoked:
                     revoked = True
@@ -480,7 +486,6 @@ class SpecInFRuntime:
             # the outer observe covered the quantum's first window
             quanta = max(out.k, int(round(out.cost_steps)))
             self._observe_windows(quanta - 1)
-            self._record_step(out)
             if out.revoked or (sig is not None and sig.check(self._vnow)):
                 # cut mid-plan, or tripped right as the quantum completed
                 revoked = True
@@ -527,6 +532,12 @@ class SpecInFRuntime:
             return RevocationSignal(), math.inf
         return None, math.inf
 
+    def _fold_collected(self) -> None:
+        """Fold the quanta collected since the last call into the run-local
+        metrics (``_record_step``), in dispatch order."""
+        while self._uncollected and self._uncollected[0].collected:
+            self._record_step(self._uncollected.pop(0))
+
     def _record_step(self, out: StepOutputs) -> None:
         """Fold one quantum's StepOutputs into the RUN-LOCAL metrics.  The
         engine-level quantities the old version stamped here (TTFT/latency
@@ -548,9 +559,15 @@ class SpecInFRuntime:
         """Run ``num_iterations`` training iterations: the train step (the
         ``runtime.train_step`` host span, its loss's fetch included), then
         the profile's segments, each bubble filled in a ``runtime.fill``
-        span.  The engine's serving copy of the params is freed before each
-        train step, which then has the memory it has without filling, and
-        made again by the iteration's first quantum (DESIGN.md §3)."""
+        span.  The fill keeps a quantum's device work in flight
+        (``EngineCore.step_overlapped``), across bubbles too; each
+        iteration ends by collecting what is in flight, in a
+        ``runtime.fill`` span, so
+        every token is on the host when ``run`` returns and no decode loop
+        runs beside the next train step.  The engine's serving copy of the
+        params is freed before each train step, which then has the memory
+        it has without filling, and made again by the iteration's first
+        quantum (DESIGN.md §3)."""
         span = self.obs.span
         for _ in range(num_iterations):
             batch = next(self.batch_iter)
@@ -573,6 +590,10 @@ class SpecInFRuntime:
                 else:
                     with span("runtime.fill"):
                         self._fill_bubble(dur)
+            if self.core is not None:
+                with span("runtime.fill"):
+                    self.core.drain()
+                    self._fold_collected()
             self.metrics.train_iterations += 1
         return self.metrics
 

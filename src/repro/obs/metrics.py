@@ -63,6 +63,9 @@ STABLE_NAMES = {
     "engine/serving_param_casts": "counter",
     "engine/serving_param_gathers": "counter",
     "engine/placement_moves": "counter",
+    # decode readbacks collected after the next quantum was dispatched
+    # (the fill's one-in-flight order, DESIGN.md §6)
+    "engine/overlapped_readbacks": "counter",
     # pluggable speculation proposers (DESIGN.md §10)
     "spec/proposer/rounds/draft": "counter",
     "spec/proposer/rounds/ngram": "counter",

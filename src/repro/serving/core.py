@@ -165,6 +165,7 @@ class EngineRequest:
     # -- core internals --
     _internal: Optional[Request] = None  # engine-side record while RUNNING
     _consumed: int = 0  # tokens of _internal.generated already absorbed
+    _reported: int = 0  # tokens of output_tokens already in a StepOutputs
     _ttft_reported: bool = False
     #: consecutive clean decode quanta since the last quarantine — once it
     #: reaches ``EngineCore.fault_decay_quanta`` the fault counter resets,
@@ -174,7 +175,9 @@ class EngineRequest:
 
     @property
     def remaining_budget(self) -> int:
-        return self.sampling.max_new_tokens - len(self.output_tokens)
+        """Tokens the request may still produce, less those in flight."""
+        pending = self._internal.pending if self._internal is not None else 0
+        return self.sampling.max_new_tokens - len(self.output_tokens) - pending
 
 
 class RevocationSignal:
@@ -314,6 +317,48 @@ class StepOutputs:
     #: ``seq`` of this step's ``quantum`` trace record (None when the
     #: tracer kept none); the ``core.step`` host span carries it too
     seq: Optional[int] = None
+    #: True once the quantum was collected: ``outputs``, ``finished``,
+    #: ``seq`` and the spec counts are final (at once after ``step()``, one
+    #: call later after ``step_overlapped()``)
+    collected: bool = False
+
+
+@dataclasses.dataclass(eq=False)
+class _Quantum:
+    """One quantum between its dispatch half (``EngineCore._begin``) and
+    its collect half (``EngineCore._end``)."""
+
+    g: Grant
+    w0: int  # wall_ns at the dispatch half's start
+    window: Any = None  # the monitor window state behind the grant
+    plan: StepPlan = dataclasses.field(default_factory=StepPlan)
+    out: StepOutputs = dataclasses.field(default_factory=StepOutputs)
+    #: request_id -> request whose delta the quantum reports
+    touched: dict = dataclasses.field(default_factory=dict)
+    #: slot -> request_id the fused loop decoded
+    ran_slots: dict = dataclasses.field(default_factory=dict)
+    #: slot -> (prefill tokens, request_id) of the chunk waves
+    prefill_slots: dict = dataclasses.field(default_factory=dict)
+    readback: Any = None  # the engine's ``Readback``
+    #: engine clock at the quantum's end: recorded at dispatch when the
+    #: quantum is collected later, else read at collection
+    t1: Optional[float] = None
+    finished: list = dataclasses.field(default_factory=list)
+
+    @property
+    def busy(self) -> bool:
+        """The quantum dispatched device work: a decode loop or prefill."""
+        return self.readback.packed is not None or self.out.prefill_tokens > 0
+
+    @property
+    def ready(self) -> bool:
+        """Collecting the quantum waits on nothing: its outputs are on the
+        device already."""
+        rb = self.readback
+        arrays = [t for _, _, t in rb.first]
+        if rb.packed is not None:
+            arrays.append(rb.packed)
+        return all(a.is_ready() for a in arrays)
 
 
 def largest_bucket(n: int, buckets: tuple = DECODE_K_BUCKETS) -> int:
@@ -584,6 +629,15 @@ class EngineCore:
         #: (DESIGN.md §11)
         self.journal = None
         self._quanta = self.obs.metrics.counter("core/quanta")
+        #: decode readbacks collected after the next quantum was dispatched
+        self._overlapped = self.obs.metrics.counter(
+            "engine/overlapped_readbacks"
+        )
+        #: quanta ``step_overlapped`` dispatched and has not collected, in
+        #: dispatch order
+        self._in_flight: collections.deque = collections.deque()
+        #: ``seq`` of the quantum records collected in the open ``core.step``
+        self._collected: list = []
 
     # ------------------------------------------------------------------
     # Submission / queries
@@ -663,16 +717,96 @@ class EngineCore:
         work runs, so virtual-clock callers stamp retirements at the true
         quantum end and no step can exceed its granted budget.
 
-        The step runs inside the ``core.step`` host span, annotated with
-        the ``seq`` of its quantum record (``repro.obs.trace``)."""
+        The quantum's dispatch half (``_begin``) and its collect half
+        (``_end``) run back to back, after any quantum ``step_overlapped``
+        left in flight is collected.  The step runs inside the
+        ``core.step`` host span, annotated with the ``seq`` of its quantum
+        record (``repro.obs.trace``)."""
+        self.drain()
         self._quanta.inc()
         with self.obs.span("core.step") as span:
-            out = self._step(grant)
-            if out.seq is not None:
-                span.set_metadata(seq=out.seq)
-        return out
+            q = self._begin(grant, overlap=False)
+            self._end(q)
+            self._annotate(span)
+        return q.out
 
-    def _step(self, grant: Optional[Grant]) -> StepOutputs:
+    def step_overlapped(self, grant: Grant) -> StepOutputs:
+        """One quantum in the overlapped order (DESIGN.md §6): dispatch this
+        quantum's device work, then collect the quanta dispatched before
+        it, so collecting them, tracing them and the caller's work until
+        the next call run while the device computes.  Both halves run
+        inside one ``core.step`` span, annotated with the ``seq`` of the
+        first quantum record collected in it and how many (``quanta``).
+
+        A quantum that dispatched device work stays in flight.  One that
+        dispatched none (its grant paid for nothing, or nothing can run
+        until the quantum in flight is collected) collects only quanta whose
+        outputs are on the device already (``_Quantum.ready``): waiting there
+        would leave the device with nothing queued.  Returns this quantum's
+        ``StepOutputs``: priced at once (``k``, ``cost_steps``,
+        ``prefill_tokens``, ``admitted``, ``preempted``), its token deltas
+        and finishes filled in when it is collected (``collected``).
+        ``drain()`` collects every quantum in flight.
+
+        The order needs stamps that are known at dispatch, so it runs only
+        where the caller prices the quantum onto its clock
+        (``Grant.advance_clock``) and the quantum is a plain decode loop;
+        elsewhere — speculative engines, a revocable grant, an attached
+        fault injector, journal or overload ladder — this is ``step()``.  A
+        quantum whose admission may preempt collects the quanta in flight
+        before it admits."""
+        eng = self.engine
+        if (grant.advance_clock is None or grant.revocation is not None
+                or eng.fault_injector is not None or self.journal is not None
+                or self.ladder is not None or eng.spec_enabled
+                or eng.host_spec_enabled):
+            return self.step(grant)
+        self._quanta.inc()
+        with self.obs.span("core.step") as span:
+            q = self._begin(grant, overlap=True)
+            self._in_flight.append(q)
+            while self._in_flight:
+                head = self._in_flight[0]
+                if head is q and q.busy or not (q.busy or head.ready):
+                    break
+                self._in_flight.popleft()
+                if q.busy and head.readback.packed is not None:
+                    self._overlapped.inc()  # q's work is queued behind it
+                self._end(head)
+            self._annotate(span)
+        return q.out
+
+    def drain(self) -> None:
+        """Collect the quanta ``step_overlapped`` left in flight, if any,
+        in a ``core.step`` span of its own (or in the one open around
+        this call).  Counts no quantum."""
+        if not self._in_flight:
+            return
+        span = self.obs.span("core.step")
+        if span.open:
+            while self._in_flight:
+                self._end(self._in_flight.popleft())
+            return
+        with span as ann:
+            while self._in_flight:
+                self._end(self._in_flight.popleft())
+            self._annotate(ann)
+
+    def _annotate(self, span) -> None:
+        """Join the open ``core.step`` span to the quantum records collected
+        in it: the first one's ``seq`` and their count."""
+        if self._collected:
+            span.set_metadata(seq=self._collected[0],
+                              quanta=len(self._collected))
+        self._collected = []
+
+    def _begin(self, grant: Optional[Grant], overlap: bool) -> "_Quantum":
+        """The dispatch half of a quantum: plan, preempt, admit, dispatch
+        the prefill waves and the fused loop, and advance the caller's
+        clock by the planned cost.  The synchronous order delivers the
+        completing prompts' first tokens before the loop is dispatched;
+        ``overlap`` leaves them on the quantum's readback, and records the
+        clock at the quantum's end for its stamps."""
         w0 = wall_ns()
         g = grant if grant is not None else Grant()
         if g.now is None:
@@ -685,10 +819,12 @@ class EngineCore:
             from repro.resilience.faults import ProcessKilled
 
             raise ProcessKilled("injected process death between quanta")
-        self._finished_buffer = []
-        active = list(self.slot_requests.values())
-        base = {cr.request_id: len(cr.output_tokens) for cr in active}
-        touched = {cr.request_id: cr for cr in active}
+        tr = self.obs.tracer
+        q = _Quantum(g=g, w0=w0, window=tr.window_state)
+        tr.window_state = None
+        out = q.out
+        self._finished_buffer = q.finished
+        q.touched = {cr.request_id: cr for cr in self.slot_requests.values()}
         # monolithic engines run prefill compute inside admission; the
         # engine's layout-independent meter prices it identically to the
         # chunk waves, so cost accounting never depends on the layout
@@ -708,21 +844,27 @@ class EngineCore:
                 plan = self.policy.plan(self, g)
                 if self.ladder is not None:
                     self.ladder.apply(self, g, plan)
-        out = StepOutputs(k=0, gamma=None, cost_steps=0.0)
+        q.plan = plan
+        if self._in_flight and (plan.preempt or (
+                plan.preempt_to_admit and any(
+                    self.policy.pick_victim(self, cr) is not None
+                    for cr in plan.admit))):
+            # a preemption reads the victim's stream: collect the quanta in
+            # flight first (their retirements may also free the room)
+            self.drain()
         with self.obs.span("core.admit"):
             for slot in list(plan.preempt):
                 cr = self.preempt(slot)
                 if cr is not None:
                     out.preempted.append(cr.request_id)
             for cr in plan.admit:
-                base.setdefault(cr.request_id, len(cr.output_tokens))
-                touched.setdefault(cr.request_id, cr)
+                q.touched.setdefault(cr.request_id, cr)
                 if self._try_admit(
                     cr,
                     allow_preempt=plan.preempt_to_admit,
                     on_preempt=lambda victim: (
                         out.preempted.append(victim.request_id),
-                        touched.setdefault(victim.request_id, victim),
+                        q.touched.setdefault(victim.request_id, victim),
                     ),
                 ):
                     out.admitted.append(cr.request_id)
@@ -733,12 +875,16 @@ class EngineCore:
                 plan.prefill_tokens
             )
         # decode only runs when some slot will be RUNNING after the waves
+        # with tokens left to make: a slot whose budget the quantum in
+        # flight spends retires when that quantum is collected
         still_prefilling = {
             i for i in range(eng.max_slots) if eng.slot_prefilling(i)
         } - set(completing)
         runnable = sum(
             1 for i, r in enumerate(eng.slots)
-            if r is not None and i not in still_prefilling
+            if r is not None and i not in still_prefilling and not (
+                r.pending and r.max_new_tokens - len(r.generated) <= r.pending
+            )
         )
         k = plan.k if runnable > 0 else 0
         if k == 0 and plan.k > 0 and eng.prefill_chunk:
@@ -760,15 +906,23 @@ class EngineCore:
         # first token stamps at quantum start, the same convention as a
         # monolithic admission's (retirements still stamp at quantum end)
         if pf_take > 0:
-            eng._drive_prefill_chunks(plan.prefill_tokens)
+            eng._dispatch_prefill_waves(plan.prefill_tokens)
+            if not overlap:
+                eng._absorb(eng._readback())
         out.prefill_tokens = eng.prefill_metered_tokens - m0
+        if out.prefill_tokens:
+            for slot, ntok in eng.last_prefill_slot_tokens.items():
+                cr = self.slot_requests.get(slot)
+                q.prefill_slots[slot] = (
+                    ntok, None if cr is None else cr.request_id
+                )
         pf_cost = out.prefill_tokens * plan.prefill_token_cost
-        ran_slots: dict = {}
+        rb = None
         if k > 0:
             # the slots the fused loop will decode (the quantum record's
             # ``decoded``); captured now because retirements mutate the
             # map mid-loop
-            ran_slots = {
+            q.ran_slots = {
                 slot: cr.request_id
                 for slot, cr in self.slot_requests.items()
                 if not eng.slot_prefilling(slot)
@@ -788,7 +942,7 @@ class EngineCore:
                     out.gamma = plan.gamma
                     eng._drive_spec_loop(k, plan.gamma)
                 else:
-                    eng._drive_decode_loop(k)
+                    rb = eng._dispatch_decode_loop(k)
         else:
             # revocable quantum (DESIGN.md §9): pay the prefill cost
             # first, then decode in sub-dispatches, re-checking the
@@ -815,19 +969,35 @@ class EngineCore:
             out.cost_steps = cost
         out.spec_accepted = eng.spec_accepted - a0
         out.spec_proposed = eng.spec_drafted - p0
+        q.readback = eng._readback(rb)
+        if overlap:
+            q.t1 = eng.clock()
+            q.readback.first_at, q.readback.done_at = g.now, q.t1
+        return q
+
+    def _end(self, q: "_Quantum") -> None:
+        """The collect half of a quantum: absorb its readback (stamped at the
+        quantum's own start and end where ``_begin`` recorded the end), then
+        the per-request deltas, finishes, the journal append and the trace
+        record.  Fills ``q.out``."""
+        eng = self.engine
+        out, g = q.out, q.g
+        outer, self._finished_buffer = self._finished_buffer, q.finished
+        if q.readback.packed is not None:
+            # the decode loop's outputs reach the host through
+            # ``_drive_decode_loop`` in either order, the quantum's first
+            # tokens with them: a wrapper of it (the benchmark's altered-token
+            # control) sees every decoded token
+            eng._ahead = q.readback
+            eng._drive_decode_loop(q.readback.k)
+        else:
+            eng._absorb(q.readback)
+        if q.t1 is None:
+            q.t1 = eng.clock()
+        inj = eng.fault_injector
         with self.obs.span("core.collect"):
             for slot, cr in list(self.slot_requests.items()):
-                if (cr.state is RequestState.PREFILLING
-                        and not eng.slot_prefilling(slot)):
-                    # the final chunk landed during this step's waves, before
-                    # the clock advance: flip stamps at quantum start, where
-                    # the first token was stamped
-                    cr.state = RequestState.RUNNING
-                    self.obs.tracer.transition(
-                        cr.request_id, "prefilling", "running", g.now,
-                        priority=cr.priority.value,
-                    )
-                self._absorb_running(slot, cr)
+                self._absorb_running(slot, cr, q.t1)
             if inj is not None and inj.should_fire("process/kill"):
                 # mid-quantum death: device work ran and its tokens were
                 # absorbed into host state, but the journal append below never
@@ -848,12 +1018,10 @@ class EngineCore:
                             cr.faults = 0
                             cr._clean_quanta = 0
                             m.counter("fault/decays").inc()
-            out.finished = list(self._finished_buffer)
+            out.finished = list(q.finished)
+            touched = q.touched
             for cr in out.finished:
                 touched.setdefault(cr.request_id, cr)
-                # queue-side finishes (expiry, load shedding) produced no
-                # tokens this step: their delta baseline is the full stream
-                base.setdefault(cr.request_id, len(cr.output_tokens))
                 pri = cr.priority.value
                 m.counter("core/finished/" + pri).inc()
                 if cr.finish_reason != "expired":
@@ -863,7 +1031,12 @@ class EngineCore:
                         cr.finish_time - cr.arrival_time
                     )
             for rid, cr in touched.items():
-                new = cr.output_tokens[base.get(rid, 0):]
+                if cr.state.finished and cr not in q.finished:
+                    # finished in the quantum collected before this one,
+                    # which reported its last delta
+                    continue
+                new = cr.output_tokens[cr._reported:]
+                cr._reported = len(cr.output_tokens)
                 ttft = None
                 if cr.first_token_time is not None and not cr._ttft_reported:
                     cr._ttft_reported = True
@@ -884,9 +1057,12 @@ class EngineCore:
                 ))
             if self.journal is not None:
                 self.journal.record_step(self, out)
-        out.seq = self._record_quantum(g, plan, out, ran_slots, w0)
+        out.seq = self._record_quantum(q)
+        out.collected = True
+        if out.seq is not None:
+            self._collected.append(out.seq)
+        self._finished_buffer = outer
         self.policy.observe(out)
-        return out
 
     # ------------------------------------------------------------------
     def _drive_revocable(
@@ -970,9 +1146,11 @@ class EngineCore:
     def abort(self, req: EngineRequest) -> None:
         """Terminal ABORT from any non-finished state.  A RUNNING request
         is evicted immediately — its pages return to the pool and its
-        draft-cache slot state is reset (mid-decode abort never leaks)."""
+        draft-cache slot state is reset (mid-decode abort never leaks).
+        The quanta in flight are collected first."""
         if req.state.finished:
             return
+        self.drain()
         if req.state in (RequestState.RUNNING, RequestState.PREFILLING):
             slot = self.slot_of(req)
             self._collect(req)
@@ -996,7 +1174,9 @@ class EngineCore:
         front of its priority class.  Pages go back to the pool; the
         radix-cached prompt pages survive, so resume recomputes only the
         suffix.  Returns the preempted request (None if the slot is empty).
+        The quanta in flight are collected first, so no token is lost.
         """
+        self.drain()
         slot = target if isinstance(target, int) else self.slot_of(target)
         cr = self.slot_requests.pop(slot, None) if slot is not None else None
         if cr is None:
@@ -1027,6 +1207,7 @@ class EngineCore:
         ``req`` immediately (no queueing), returning False on capacity.
         The request still joins the core lifecycle, so shim- and
         core-driven streams share one bookkeeping path."""
+        self.drain()
         if not self.engine._admit_request(req):
             return False
         cr = EngineRequest(
@@ -1062,6 +1243,7 @@ class EngineCore:
         """Deprecated ``decode_loop`` / ``spec_decode_loop`` contract: run
         exactly one fused loop (no admission, no preemption) and return the
         engine-side ``Request`` records that finished."""
+        self.drain()
         if self.engine.num_active == 0 or k <= 0:
             return []
         self._finished_buffer = []
@@ -1076,19 +1258,17 @@ class EngineCore:
     # ------------------------------------------------------------------
     # Internals
     # ------------------------------------------------------------------
-    def _record_quantum(
-        self, g: Grant, plan: StepPlan, out: StepOutputs, ran_slots: dict,
-        w0: int,
-    ) -> Optional[int]:
+    def _record_quantum(self, q: "_Quantum") -> Optional[int]:
         """Per-quantum observability (DESIGN.md §8), in the ``core.record``
         host span: sample the gauges and emit the structured trace events
         for this step — per-slot prefill spans and one ``quantum`` record,
         which names the slots the fused loop decoded (``decoded``, drawn
         per slot at export) and the step's wall-clock interval
-        (``wall_ns``, from ``w0``).  Span boundaries are the engine clock's
-        quantum endpoints; the prefill/decode split inside the quantum
-        follows the plan's deterministic cost model (prefill runs first,
-        before the clock advance).  Returns the quantum record's ``seq``."""
+        (``wall_ns``, from its dispatch to this record).  Span boundaries
+        are the engine clock's quantum endpoints; the prefill/decode split
+        inside the quantum follows the plan's deterministic cost model
+        (prefill runs first, before the clock advance).  Returns the
+        quantum record's ``seq``."""
         with self.obs.span("core.record"):
             eng = self.engine
             m = self.obs.metrics
@@ -1104,22 +1284,20 @@ class EngineCore:
                 for key, v in eng.pool.occupancy().items():
                     m.gauge(f"engine/pool/{key}").set(v)
             tr = self.obs.tracer
-            window, tr.window_state = tr.window_state, None
             if not tr.enabled:
                 return None
-            t0, t1 = g.now, eng.clock()
+            g, plan, out = q.g, q.plan, q.out
+            t0, t1 = g.now, q.t1
             pf_cost = out.prefill_tokens * plan.prefill_token_cost
             dec_cost = plan.cost_steps if out.k > 0 else 0.0
             total = pf_cost + dec_cost
             t_mid = t0 + (t1 - t0) * (pf_cost / total if total > 0 else 0.0)
             if out.prefill_tokens:
                 if eng.prefill_chunk:
-                    for slot, ntok in eng.last_prefill_slot_tokens.items():
-                        cr = self.slot_requests.get(slot)
+                    for slot, (ntok, rid) in q.prefill_slots.items():
                         tr.span(
                             "prefill_chunk", f"slot{slot}", t0, t_mid,
-                            tokens=ntok,
-                            request_id=None if cr is None else cr.request_id,
+                            tokens=ntok, request_id=rid,
                         )
                 else:
                     for rid in out.admitted:
@@ -1148,9 +1326,9 @@ class EngineCore:
                 finished=[cr.request_id for cr in out.finished],
                 spec_accepted=out.spec_accepted,
                 spec_proposed=out.spec_proposed,
-                window=window,
-                decoded=ran_slots, decode_t0=t_mid,
-                wall_ns=[w0, wall_ns()],
+                window=q.window,
+                decoded=q.ran_slots, decode_t0=t_mid,
+                wall_ns=[q.w0, wall_ns()],
             )
 
     def _collect(self, cr: EngineRequest) -> list:
@@ -1198,13 +1376,27 @@ class EngineCore:
             cr.request_id, frm, state.value, now, priority=cr.priority.value,
         )
 
-    def _absorb_running(self, slot: int, cr: EngineRequest) -> None:
+    def _absorb_running(
+        self, slot: int, cr: EngineRequest, now: Optional[float] = None
+    ) -> None:
+        """Absorb a slot's new tokens; a PREFILLING request whose first
+        token arrived turns RUNNING, stamped at that token's time, and a
+        stop token finishes the request at ``now`` (None: the clock)."""
         new = self._collect(cr)
+        if cr.state is RequestState.PREFILLING and cr._internal.generated:
+            cr.state = RequestState.RUNNING
+            self.obs.tracer.transition(
+                cr.request_id, "prefilling", "running",
+                cr._internal.first_token_time, priority=cr.priority.value,
+            )
         if self._apply_stop(cr, new):
             del self.slot_requests[slot]
             self.engine.evict_slot(slot)
             cr._internal = None
-            self._finish(cr, RequestState.FINISHED_STOPPED, self.engine.clock())
+            self._finish(
+                cr, RequestState.FINISHED_STOPPED,
+                self.engine.clock() if now is None else now,
+            )
 
     def _expire_deadlines(self, now: float) -> None:
         """Deadline sweep at quantum start (DESIGN.md §9): WAITING or

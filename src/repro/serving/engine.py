@@ -144,6 +144,53 @@ def _program(fn: Callable, *args, **kwargs) -> Callable:
     return p
 
 
+def decode_loop(cfg, params, tokens, cache, remaining, *, k: int, **kwargs):
+    """``transformer.decode_loop`` with what the host reads of it packed
+    into ONE int32 array ``[k + 4, slots]``: the token after each microstep
+    (rows ``0 .. k-1``), then each slot's microsteps run, budget left, cache
+    index and NaN flag.  The array is a fresh output of the program, so it
+    aliases no buffer that the next loop donates (the cache's index does).
+    Returns ``(tokens, cache, packed)``."""
+    tokens, cache, rem, toks_seq, steps, bad = T.decode_loop(
+        cfg, params, tokens, cache, remaining, k=k, **kwargs
+    )
+    tail = jnp.stack([steps, rem, cache["index"], bad.astype(jnp.int32)])
+    return tokens, cache, jnp.concatenate([toks_seq, tail])
+
+
+def put_first_tokens(tokens, first, mask):
+    """The decode batch's tokens with the slots in ``mask`` taking their
+    first generated token from a completing prefill wave's ``first``."""
+    return jnp.where(mask, first, tokens)
+
+
+@dataclasses.dataclass(eq=False)
+class Readback:
+    """One quantum's device outputs on their way to the host (DESIGN.md
+    §6).  Their copies start when they are dispatched; ``_absorb`` reads
+    them in one fetch.
+
+    ``first``: ``(slot, request, tokens)`` for each slot whose prefill
+    completed, ``tokens`` the completing wave's ``[slots]`` argmax.
+    ``packed``: the decode loop's ``[k + 4, slots]`` array (``decode_loop``),
+    ``reqs`` the request each slot decoded for (None: frozen or empty),
+    ``steps`` the microsteps predicted for it at dispatch.  ``first_at`` and
+    ``done_at`` stamp its first tokens and finishes on the engine clock
+    (None: the clock when the outputs arrive)."""
+
+    first: list = dataclasses.field(default_factory=list)
+    packed: Optional[jax.Array] = None
+    k: int = 0
+    reqs: list = dataclasses.field(default_factory=list)
+    steps: Optional[np.ndarray] = None
+    first_at: Optional[float] = None
+    done_at: Optional[float] = None
+
+    @property
+    def empty(self) -> bool:
+        return not self.first and self.packed is None
+
+
 class RegistryCounterView:
     """Thin view (DESIGN.md §8): a historical ``InferenceEngine`` counter
     attribute backed by a ``repro.obs`` registry counter under a stable
@@ -188,6 +235,9 @@ class Request:
     #: first token
     first_token_wall_ns: Optional[int] = None
     finish_time: Optional[float] = None
+    #: tokens dispatched for this request whose readback the host has not
+    #: absorbed yet (dense engines: a bound where a loop reaches max_seq)
+    pending: int = 0
 
 
 class InferenceEngine:
@@ -289,8 +339,14 @@ class InferenceEngine:
             [None] * max_slots
         )
         #: device [B] next-token array from the wave that completed each
-        #: slot's target prefill, fetched in ONE batched d2h at completion
+        #: slot's target prefill
         self._prefill_tok: list = [None] * max_slots
+        #: ``Readback.first`` entries of the slots whose prefill completed
+        #: since the last ``_readback``
+        self._landed: list = []
+        #: a dispatched decode loop's readback for ``_drive_decode_loop`` to
+        #: absorb instead of dispatching one
+        self._ahead: Optional[Readback] = None
         #: slot -> metered tokens taken by the LAST _drive_prefill_chunks
         #: call (the core turns these into per-slot prefill-chunk spans)
         self.last_prefill_slot_tokens: dict[int, int] = {}
@@ -383,11 +439,14 @@ class InferenceEngine:
         )
         self._decode_loop = jax.jit(
             _program(
-                T.decode_loop, cfg, compute_dtype=compute_dtype,
+                decode_loop, cfg, compute_dtype=compute_dtype,
                 attn_impl=decode_impl, max_seq=max_seq,
             ),
             static_argnames=("k",),
-            donate_argnames=("tokens", "cache", "remaining"),
+            donate_argnames=("tokens", "cache"),
+        )
+        self._put_first = jax.jit(
+            _program(put_first_tokens), donate_argnames=("tokens",)
         )
         if self._resident and self.paged:
             self._decode_loop = self._tables_on_device(self._decode_loop)
@@ -913,6 +972,7 @@ class InferenceEngine:
         self._prefill_left[i] = None
         self._draft_prefill_left[i] = None
         self._prefill_tok[i] = None
+        req.pending = 0
         self.cache["index"] = self.cache["index"].at[i].set(0)
         if self.spec_enabled:
             self.draft_cache["index"] = (
@@ -1155,119 +1215,116 @@ class InferenceEngine:
     def _drive_prefill_chunks(self, budget: float = math.inf) -> int:
         """Stream chunk waves into every PREFILLING slot, consuming at most
         ``budget`` metered tokens (per slot per wave: max of the target and
-        draft takes).  Each wave is ONE batched target dispatch plus — when
-        a draft pairing is attached — ONE batched draft dispatch, replacing
-        the per-request prefill (and per-request draft prefill) dispatches
-        of the monolithic path.  Slots whose prompt completes get their
-        first generated token from the completing wave's logits, fetched in
-        ONE batched d2h transfer at the end.  Returns tokens consumed.
+        draft takes), and deliver the first token of every slot whose
+        prompt completed, in ONE batched d2h transfer.  Returns tokens
+        consumed.  The dispatch half is ``_dispatch_prefill_waves``."""
+        consumed = self._dispatch_prefill_waves(budget)
+        self._absorb(self._readback())
+        return consumed
 
-        The ``engine.prefill`` host span covers the packing and dispatch,
-        and the first tokens' absorption; the fetch is ``engine.fetch``."""
+    def _dispatch_prefill_waves(self, budget: float) -> int:
+        """The packing and dispatch half of ``_drive_prefill_chunks``,
+        in the ``engine.prefill`` host span: each wave is ONE batched target
+        dispatch plus — when a draft pairing is attached — ONE batched
+        draft dispatch, replacing the per-request prefill (and per-request
+        draft prefill) dispatches of the monolithic path.  Slots whose
+        prompt completes start RUNNING at once (``_land``).  Returns tokens
+        consumed."""
+        self.last_prefill_slot_tokens = {}
+        if not self.prefill_chunk:
+            return 0
+        waves, consumed, _ = self._plan_prefill_waves(budget)
+        if not waves:
+            return 0
         with self.obs.span("engine.prefill"):
-            consumed, completed = self._dispatch_prefill_waves(budget)
-        if completed:
-            toks = self._fetch([self._prefill_tok[i] for i in completed])
-            got_ns = wall_ns()
-            self.d2h_transfers += 1  # one batched fetch covers every finish
-            with self.obs.span("engine.prefill"):
-                now = self.clock()
-                for i, arr in zip(completed, toks):
-                    self._finish_prefill(
-                        i, int(np.asarray(arr)[i]), now, got_ns
+            for wave in waves:
+                for i, tt, dd in wave:
+                    self.last_prefill_slot_tokens[i] = (
+                        self.last_prefill_slot_tokens.get(i, 0) + max(tt, dd)
                     )
+            if self.paged and self._bt_dirty:
+                self._sync_block_tables()  # one h2d wave covers every admission
+            chunk = self.prefill_chunk
+            completed: list[int] = []
+            for wave in waves:
+                t_lens = np.zeros((self.max_slots,), np.int32)
+                d_lens = np.zeros((self.max_slots,), np.int32)
+                t_toks = np.zeros((self.max_slots, chunk), np.int32)
+                d_toks = np.zeros((self.max_slots, chunk), np.int32)
+                t_done: list[int] = []
+                for i, tt, dd in wave:
+                    if tt:
+                        buf = self._prefill_left[i]
+                        t_toks[i, :tt] = buf[:tt]
+                        t_lens[i] = tt
+                        self._prefill_left[i] = buf[tt:]
+                        if len(self._prefill_left[i]) == 0:
+                            t_done.append(i)
+                        if self.paged:
+                            self._slot_idx[i] += tt
+                    if dd:
+                        dbuf = self._draft_prefill_left[i]
+                        d_toks[i, :dd] = dbuf[:dd]
+                        d_lens[i] = dd
+                        self._draft_prefill_left[i] = dbuf[dd:]
+                if t_lens.any():
+                    self._record_prefill_program("target", "chunk", chunk)
+                    next_toks, self.cache = self._prefill_chunks(
+                        self.params, jnp.asarray(t_toks), jnp.asarray(t_lens),
+                        self.cache,
+                    )
+                    for i in t_done:
+                        # hold the completing wave's device logits-argmax; the
+                        # slot may still owe draft chunks before finalizing
+                        self._prefill_tok[i] = next_toks
+                if d_lens.any():
+                    self._record_prefill_program("draft", "chunk", chunk)
+                    _, self.draft_cache = self._draft_prefill_chunks(
+                        self.draft_params, jnp.asarray(d_toks),
+                        jnp.asarray(d_lens), self.draft_cache,
+                    )
+                self.steps_executed += 1
+                for i, _, _ in wave:
+                    t = self._prefill_left[i]
+                    d = self._draft_prefill_left[i]
+                    if (t is not None and len(t) == 0) and (
+                        d is None or len(d) == 0
+                    ):
+                        completed.append(i)
+            self._land(completed)
         self.prefill_metered_tokens += consumed
         return consumed
 
-    def _dispatch_prefill_waves(self, budget: float):
-        """The packing and dispatch half of ``_drive_prefill_chunks``:
-        returns ``(tokens consumed, slots whose prefill completed)``."""
-        self.last_prefill_slot_tokens = {}
-        if not self.prefill_chunk:
-            return 0, []
-        waves, consumed, _ = self._plan_prefill_waves(budget)
-        if not waves:
-            return 0, []
-        for wave in waves:
-            for i, tt, dd in wave:
-                self.last_prefill_slot_tokens[i] = (
-                    self.last_prefill_slot_tokens.get(i, 0) + max(tt, dd)
+    def _land(self, slots: list[int]) -> None:
+        """Turn PREFILLING slots whose prompt streamed in whole RUNNING
+        without waiting for the device: each slot's first generated token
+        (its completing wave's argmax) goes into the decode batch's tokens
+        on the device, one ``put_first_tokens`` program per wave, and its
+        copy to the host starts.  The host appends it when the quantum's
+        readback is absorbed (``Readback.first``); until then it counts in
+        ``pending``.  Paged: the prompt's full pages enter the radix tree —
+        the same shape monolithic admission produced in one shot."""
+        waves: dict = {}
+        for i in slots:
+            req = self.slots[i]
+            toks = self._prefill_tok[i]
+            self._prefill_left[i] = None
+            self._draft_prefill_left[i] = None
+            self._prefill_tok[i] = None
+            req.pending += 1
+            self._landed.append((i, req, toks))
+            waves.setdefault(id(toks), (toks, []))[1].append(i)
+            if self.paged and self.prefix_cache is not None:
+                prompt = np.asarray(req.prompt, np.int32)
+                self.prefix_cache.insert(
+                    prompt,
+                    self._slot_pages[i][: len(prompt) // self.kv_page_size],
                 )
-        if self.paged and self._bt_dirty:
-            self._sync_block_tables()  # one h2d wave covers every admission
-        chunk = self.prefill_chunk
-        completed: list[int] = []
-        for wave in waves:
-            t_lens = np.zeros((self.max_slots,), np.int32)
-            d_lens = np.zeros((self.max_slots,), np.int32)
-            t_toks = np.zeros((self.max_slots, chunk), np.int32)
-            d_toks = np.zeros((self.max_slots, chunk), np.int32)
-            t_done: list[int] = []
-            for i, tt, dd in wave:
-                if tt:
-                    buf = self._prefill_left[i]
-                    t_toks[i, :tt] = buf[:tt]
-                    t_lens[i] = tt
-                    self._prefill_left[i] = buf[tt:]
-                    if len(self._prefill_left[i]) == 0:
-                        t_done.append(i)
-                    if self.paged:
-                        self._slot_idx[i] += tt
-                if dd:
-                    dbuf = self._draft_prefill_left[i]
-                    d_toks[i, :dd] = dbuf[:dd]
-                    d_lens[i] = dd
-                    self._draft_prefill_left[i] = dbuf[dd:]
-            if t_lens.any():
-                self._record_prefill_program("target", "chunk", chunk)
-                next_toks, self.cache = self._prefill_chunks(
-                    self.params, jnp.asarray(t_toks), jnp.asarray(t_lens),
-                    self.cache,
-                )
-                for i in t_done:
-                    # hold the completing wave's device logits-argmax; the
-                    # slot may still owe draft chunks before finalizing
-                    self._prefill_tok[i] = next_toks
-            if d_lens.any():
-                self._record_prefill_program("draft", "chunk", chunk)
-                _, self.draft_cache = self._draft_prefill_chunks(
-                    self.draft_params, jnp.asarray(d_toks),
-                    jnp.asarray(d_lens), self.draft_cache,
-                )
-            self.steps_executed += 1
-            for i, _, _ in wave:
-                t = self._prefill_left[i]
-                d = self._draft_prefill_left[i]
-                if (t is not None and len(t) == 0) and (
-                    d is None or len(d) == 0
-                ):
-                    completed.append(i)
-        return consumed, completed
-
-    def _finish_prefill(
-        self, i: int, tok: int, now: float, got_ns: int
-    ) -> None:
-        """Transition slot ``i`` PREFILLING -> RUNNING: deliver the first
-        generated token, stamp TTFT (``now`` on the engine clock, ``got_ns``
-        the wall clock when the host received it), and (paged) insert the
-        prompt's full pages into the radix tree — the same shape monolithic
-        admission produced in one shot."""
-        req = self.slots[i]
-        self._prefill_left[i] = None
-        self._draft_prefill_left[i] = None
-        self._prefill_tok[i] = None
-        req.generated.append(tok)
-        self.generated_tokens_total += 1
-        if req.first_token_time is None:
-            req.first_token_time = now
-            req.first_token_wall_ns = got_ns
-        self.tokens = self.tokens.at[i].set(tok)
-        if self.paged and self.prefix_cache is not None:
-            prompt = np.asarray(req.prompt, np.int32)
-            self.prefix_cache.insert(
-                prompt,
-                self._slot_pages[i][: len(prompt) // self.kv_page_size],
-            )
+        for toks, idx in waves.values():
+            mask = np.zeros((self.max_slots,), bool)
+            mask[idx] = True
+            self.tokens = self._put_first(self.tokens, toks, mask)
+            toks.copy_to_host_async()
 
     def _restore_draft_prefill_indices(self) -> None:
         """Re-pin the draft cache index of PREFILLING slots to their draft
@@ -1465,9 +1522,26 @@ class InferenceEngine:
         return req
 
     # ------------------------------------------------------------------
+    def _budgets(self) -> np.ndarray:
+        """Per-slot token budgets for the next fused loop: what each RUNNING
+        slot's request may still generate, less what is already in flight
+        (``Request.pending``); zero for empty and PREFILLING slots, which
+        the loop freezes."""
+        remaining = np.zeros((self.max_slots,), np.int32)
+        for i, r in enumerate(self.slots):
+            if r is not None and not self.slot_prefilling(i):
+                remaining[i] = max(
+                    r.max_new_tokens - len(r.generated) - r.pending, 0
+                )
+        return remaining
+
     def _drive_decode_loop(self, k: int) -> list[Request]:
         """Run ``k`` fused decode microsteps on-device; returns requests that
-        finished.  One device->host transfer total, regardless of ``k``.
+        finished.  One device->host transfer total, regardless of ``k``: the
+        dispatch half (``_dispatch_decode_loop``), then its absorption.  A
+        loop already dispatched and handed back in ``_ahead`` (the core's
+        collect half does so, in either order: DESIGN.md §6) is absorbed
+        instead, so every decoded token reaches the host here.
 
         Finished slots freeze mid-loop on device (token, index, and budget
         held in place), so the host never needs to intervene between
@@ -1475,55 +1549,114 @@ class InferenceEngine:
         way (zero budget) and never retire mid-prefill.  Callers should
         pick ``k`` from ``DECODE_K_BUCKETS`` to bound the number of
         compiled programs."""
+        rb, self._ahead = self._ahead, None
+        if rb is None:
+            rb = self._dispatch_decode_loop(k)
+            if rb is None:
+                return []
+            rb = self._readback(rb)
+        return self._absorb(rb)
+
+    def _dispatch_decode_loop(self, k: int) -> Optional[Readback]:
+        """Dispatch ``k`` fused decode microsteps and start the copy of their
+        packed outputs to the host; returns the loop's ``Readback`` (None if
+        nothing can decode).  Reads nothing back: the budgets come from
+        ``_budgets``, and each decoding slot's ``pending`` (and, paged, its
+        host index, which the next top-up reads) advance by the microsteps
+        the loop will run, so the next loop can be dispatched before this
+        one is absorbed."""
         if self.num_active == 0 or k <= 0:
-            return []
+            return None
         if self.num_active == self.num_prefilling:
-            return []  # every slot is mid-prefill: nothing to decode
+            return None  # every slot is mid-prefill: nothing to decode
         with self.obs.span("engine.decode"):
             if self.paged:
                 # extend block tables to cover the loop's k writes per slot
                 self._top_up_pages(k)
                 if self.num_active == 0:
-                    return []  # every slot fell to an allocator fault
+                    return None  # every slot fell to an allocator fault
             self._maybe_inject_nan()
-            remaining = np.zeros((self.max_slots,), np.int32)
-            for i, r in enumerate(self.slots):
-                if r is not None and not self.slot_prefilling(i):
-                    remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
-            tokens, cache, rem, toks_seq, steps, bad = self._decode_loop(
+            remaining = self._budgets()
+            self.tokens, self.cache, packed = self._decode_loop(
                 self.params, self.tokens, self.cache, jnp.asarray(remaining),
                 k=k,
             )
-            self.tokens, self.cache = tokens, cache
-        toks_np, steps_np, rem_np, idx_np, bad_np = self._fetch(
-            (toks_seq, steps, rem, cache["index"], bad)
-        )
-        with self.obs.span("engine.decode"):
-            self.d2h_transfers += 1  # the single fused fetch above
+            packed.copy_to_host_async()
             self.steps_executed += k
-            now = self.clock()
-            finished = []
-            for i, req in enumerate(self.slots):
-                if req is None or self.slot_prefilling(i):
+            steps = np.minimum(remaining, k)
+            if self.paged:
+                # the loop's own freeze rule: budget spent, or max_seq - 1
+                idx = np.asarray(self._slot_idx, np.int64)
+                steps = np.minimum(steps, np.maximum(self.max_seq - 1 - idx, 0))
+                self._slot_idx = (idx + steps).tolist()
+            reqs = [
+                None if r is None or self.slot_prefilling(i) else r
+                for i, r in enumerate(self.slots)
+            ]
+            for r, n in zip(reqs, steps.tolist()):
+                if r is not None:
+                    r.pending += n
+        return Readback(packed=packed, k=k, reqs=reqs, steps=steps)
+
+    def _readback(self, rb: Optional[Readback] = None) -> Readback:
+        """``rb`` (or an empty readback) carrying the first tokens landed
+        since the last call."""
+        rb = rb if rb is not None else Readback()
+        rb.first, self._landed = self._landed, []
+        return rb
+
+    def _absorb(self, rb: Readback) -> list[Request]:
+        """Read a quantum's outputs back in ONE blocking fetch (the copies
+        started at dispatch) and fold them into host state: first tokens,
+        then each decoding slot's tokens, NaN quarantines and retirements.
+        Returns the requests that finished.  A slot whose request changed
+        since dispatch (retired or evicted meanwhile) is skipped."""
+        if rb.empty:
+            return []
+        arrays = {id(t): t for _, _, t in rb.first}
+        if rb.packed is not None:
+            arrays[id(rb.packed)] = rb.packed
+        host = dict(zip(arrays, self._fetch(list(arrays.values()))))
+        got_ns = wall_ns()
+        self.d2h_transfers += 1  # one fetch covers the whole quantum
+        now = self.clock()
+        first_at = now if rb.first_at is None else rb.first_at
+        done_at = now if rb.done_at is None else rb.done_at
+        finished = []
+        span = "engine.decode" if rb.packed is not None else "engine.prefill"
+        with self.obs.span(span):
+            for i, req, toks in rb.first:
+                if self.slots[i] is not req:
                     continue
-                if bad_np[i]:
-                    # NaN screen (DESIGN.md §9): this slot's tokens from the
-                    # loop are garbage — drop them all (the screen can't say
-                    # which microstep went bad) and quarantine the slot; its
-                    # on-device index/remaining are garbage too, so no retire
-                    # check either
-                    self._quarantine_slot(i)
-                    continue
-                n = int(steps_np[i])
-                req.generated.extend(int(t) for t in toks_np[:n, i])
-                self.generated_tokens_total += n
-                if self.paged:
-                    self._slot_idx[i] = int(idx_np[i])
-                if rem_np[i] == 0 or idx_np[i] >= self.max_seq - 1:
-                    finished.append(self._retire_slot(i, now))
+                req.pending -= 1
+                req.generated.append(int(host[id(toks)][i]))
+                self.generated_tokens_total += 1
+                if req.first_token_time is None:
+                    req.first_token_time = first_at
+                    req.first_token_wall_ns = got_ns
+            if rb.packed is not None:
+                packed, k = host[id(rb.packed)], rb.k
+                toks_np, (steps, rem, idx, bad) = packed[:k], packed[k:]
+                for i, req in enumerate(rb.reqs):
+                    if req is None or self.slots[i] is not req:
+                        continue
+                    req.pending -= int(rb.steps[i])
+                    if bad[i]:
+                        # NaN screen (DESIGN.md §9): this slot's tokens from
+                        # the loop are garbage — drop them all (the screen
+                        # can't say which microstep went bad) and quarantine
+                        # the slot; its on-device index/remaining are garbage
+                        # too, so no retire check either
+                        self._quarantine_slot(i)
+                        continue
+                    n = int(steps[i])
+                    req.generated.extend(toks_np[:n, i].tolist())
+                    self.generated_tokens_total += n
+                    if rem[i] == 0 or idx[i] >= self.max_seq - 1:
+                        finished.append(self._retire_slot(i, done_at))
             if self.paged and self._bt_dirty:
                 self._sync_block_tables()  # one upload covers every retirement
-            return finished
+        return finished
 
     # ------------------------------------------------------------------
     def _drive_spec_loop(self, k: int, gamma: int) -> list[Request]:
@@ -1550,10 +1683,7 @@ class InferenceEngine:
                 if self.num_active == 0:
                     return []  # every slot fell to an allocator fault
             self._maybe_inject_nan()
-            remaining = np.zeros((self.max_slots,), np.int32)
-            for i, r in enumerate(self.slots):
-                if r is not None and not self.slot_prefilling(i):
-                    remaining[i] = max(r.max_new_tokens - len(r.generated), 0)
+            remaining = self._budgets()
             (
                 self.tokens, self.cache, self.draft_cache, rem, self._spec_key,
                 out_toks, n_out, accepted, proposed, bad,
@@ -1688,13 +1818,10 @@ class InferenceEngine:
             ):
                 break
             with span("engine.decode"):
-                remaining = np.zeros((self.max_slots,), np.int32)
+                remaining = self._budgets()
                 hists: list[list[int]] = [[] for _ in range(self.max_slots)]
                 for i, r in enumerate(self.slots):
                     if r is not None and not self.slot_prefilling(i):
-                        remaining[i] = max(
-                            r.max_new_tokens - len(r.generated), 0
-                        )
                         hists[i] = [int(t) for t in r.prompt] + r.generated
                 if not remaining.any():
                     break

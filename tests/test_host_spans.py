@@ -3,9 +3,10 @@
 A tiny collocated run (virtual-clock SpecInF fill over a real engine) under
 ``jax.profiler`` must show every ``specinf.*`` span of ``repro.obs.trace``
 on the host plane, each nested in the span that calls it, with every
-``core.step`` annotated by the ``seq`` of its quantum record.  The engine's
-jitted programs carry their model function's name (``jit_decode_loop``,
-not ``jit__unknown``) with their buffer donation intact.
+quantum record joined by its ``seq`` to the ``core.step`` that collected
+it.  The engine's jitted programs carry their model function's name
+(``jit_decode_loop``, not ``jit__unknown``) with their buffer donation
+intact.
 """
 import glob
 import itertools
@@ -100,9 +101,16 @@ def test_profile_holds_every_span_nested_and_joined_by_seq(engine, tmp_path):
         parent = stack[-1][2] if stack else None
         assert parent in PARENTS[name], (name, parent)
         stack.append((s, e, name))
+    # the fill keeps a quantum in flight: a core.step span carries the seq
+    # of the first quantum record it collected and how many, and each
+    # record is collected in exactly one
     seqs = [ev["seq"] for ev in tracer.events[ev0:] if ev["type"] == "quantum"]
-    assert seqs and [st.get("seq") for _, _, name, st in spans
-                     if name == "core.step"] == seqs
+    joined = []
+    for _, _, name, st in spans:
+        if name == "core.step" and "seq" in st:
+            i = seqs.index(st["seq"])
+            joined += seqs[i:i + st["quanta"]]
+    assert seqs and joined == seqs
 
 
 def test_engine_programs_carry_their_model_function_names(engine):

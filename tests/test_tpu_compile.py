@@ -247,7 +247,7 @@ def test_olmo_engine_programs_update_the_pool_in_place(program, one_chip,
     holding a copy of it."""
     from repro.kernels import ops
     from repro.models import transformer as TM
-    from repro.serving.engine import _program
+    from repro.serving.engine import _program, decode_loop
 
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)  # the chip's kernels
     es = _olmo_cell_engine()
@@ -260,8 +260,8 @@ def test_olmo_engine_programs_update_the_pool_in_place(program, one_chip,
         OLMO, slots, slots * pps + 1, PAGE, pps, jnp.bfloat16)))
     i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
     if program == "decode_loop":
-        fn = jax.jit(_program(TM.decode_loop, OLMO, max_seq=max_seq, k=8),
-                     donate_argnames=("tokens", "cache", "remaining"))
+        fn = jax.jit(_program(decode_loop, OLMO, max_seq=max_seq, k=8),
+                     donate_argnames=("tokens", "cache"))
         args = (params, i32(slots), cache, i32(slots))
     else:
         fn = jax.jit(_program(TM.prefill_chunks_into_slots, OLMO),
