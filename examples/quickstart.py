@@ -3,7 +3,8 @@
   PYTHONPATH=src python examples/quickstart.py [--arch qwen3-1.7b]
 
 Runs the REDUCED (smoke) config so it finishes on CPU in seconds; on real
-hardware drop ``smoke_config`` for ``get_config`` and a production mesh.
+hardware drop ``smoke_config`` for ``get_config`` and size ``make_dev_mesh``
+to the chips.
 """
 import argparse
 

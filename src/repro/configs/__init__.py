@@ -52,16 +52,6 @@ def get_shape(shape: str) -> ShapeConfig:
     return SHAPES[shape]
 
 
-def all_cells(include_skipped: bool = False):
-    """Yield (arch_id, shape_name, applicable, reason) for the 40-cell matrix."""
-    for arch in ARCH_IDS:
-        cfg = get_config(arch)
-        for shape in SHAPES.values():
-            ok, reason = shape_applicable(cfg, shape)
-            if ok or include_skipped:
-                yield arch, shape.name, ok, reason
-
-
 # ---------------------------------------------------------------------------
 # Reduced smoke configs: same family/block layout, tiny dims, CPU-runnable.
 # ---------------------------------------------------------------------------
@@ -148,7 +138,6 @@ __all__ = [
     "SpecInFConfig",
     "get_config",
     "get_shape",
-    "all_cells",
     "smoke_config",
     "shape_applicable",
     "mesh_axes",

@@ -22,12 +22,3 @@ V5E = HardwareSpec(
     link_bandwidth=50e9,
     hbm_bytes=16 * 1024**3,
 )
-
-# A100-40GB — the paper's testbed; used only by the paper-fidelity benches.
-A100_40G = HardwareSpec(
-    name="a100-40g",
-    peak_flops=312e12,
-    hbm_bandwidth=1555e9,
-    link_bandwidth=300e9,
-    hbm_bytes=40 * 1024**3,
-)

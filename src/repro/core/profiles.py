@@ -1,10 +1,12 @@
-"""Training-iteration and inference profiles for the timeline simulator.
+"""Training-iteration and inference profiles: the bubble timelines
+``SpecInFRuntime`` fills and the timeline simulator replays.
 
 Two sources:
-  * analytic  -- 6ND-style napkin math over a HardwareSpec (used by the
-    paper-fidelity benches: deterministic, no dry-run artifacts needed);
-  * dry-run   -- roofline terms of the actually-compiled step (used by the
-    §Roofline/§Perf pipeline; see benchmarks/roofline.py).
+  * measured  -- ``dp_profile`` / ``mp_profile`` / ``pp_profile`` from a
+    step's compute and communication seconds (the benchmark sizes its
+    ``dp_profile`` from a step measured on the chip);
+  * analytic  -- 6ND-style napkin math over a HardwareSpec
+    (``analytic_iteration``, ``analytic_inference_profile``).
 
 A profile is the per-iteration segment structure one accelerator observes:
 alternating (compute | bubble) spans.  Parallel modes shape it differently
@@ -218,19 +220,5 @@ def analytic_inference_profile(
         name=name or f"{cfg.name}-{kind}",
         peak_memory_bytes=int(mem),
         min_exec_time_s=float(latency),
-        online=online,
-    )
-
-
-# -- CV inference workloads from the paper (ResNet152 / VGG19) enter as cost
-#    profiles only; there is no CNN in the LM model zoo (DESIGN.md §3).
-def cv_profile(name: str, hw: HardwareSpec, *, online: bool = False):
-    GFLOPS = {"resnet152": 11.5e9, "vgg19": 19.6e9}
-    MEM = {"resnet152": 0.9e9, "vgg19": 1.2e9}
-    flops = GFLOPS[name] * 8  # batch 8 per microstep
-    return InstanceProfile(
-        name=name,
-        peak_memory_bytes=int(MEM[name]),
-        min_exec_time_s=flops / (hw.peak_flops * 0.25),  # CNNs reach lower MFU
         online=online,
     )

@@ -1,1 +1,1 @@
-"""Launchers: production mesh, multi-pod dry-run, train / serve CLIs."""
+"""Launchers: mesh construction, compile cache, train / serve CLIs."""

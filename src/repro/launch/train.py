@@ -19,7 +19,7 @@ import numpy as np
 from repro import configs
 from repro.launch.compile_cache import enable_compile_cache
 from repro.configs.base import SpecInFConfig, TrainConfig
-from repro.launch.mesh import make_dev_mesh, make_production_mesh
+from repro.launch.mesh import make_dev_mesh
 from repro.runtime.trainer import Trainer
 
 
@@ -35,8 +35,6 @@ def main() -> None:
     ap.add_argument("--lr", type=float, default=3e-3)
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--ckpt-every", type=int, default=50)
-    ap.add_argument("--production-mesh", action="store_true",
-                    help="16x16 mesh (needs 256 devices)")
     ap.add_argument("--collocate", action="store_true",
                     help="fill training bubbles with a collocated inference "
                          "engine (SpecInF)")
@@ -50,11 +48,8 @@ def main() -> None:
         fsdp=not args.smoke, zero1=not args.smoke,
         remat_policy="dots" if args.smoke else "full",
     )
-    mesh = (
-        make_production_mesh() if args.production_mesh else make_dev_mesh()
-    )
     trainer = Trainer(
-        cfg, tcfg, mesh, seq_len=args.seq_len, global_batch=args.global_batch,
+        cfg, tcfg, make_dev_mesh(), seq_len=args.seq_len, global_batch=args.global_batch,
         checkpoint_dir=args.ckpt_dir, checkpoint_every=args.ckpt_every,
     )
     if args.ckpt_dir and trainer.restore_latest():

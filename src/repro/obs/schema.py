@@ -1,6 +1,7 @@
 """Trace schema (DESIGN.md §8): the authoritative field list for every
-event kind the step tracer emits, plus a dependency-free validator CI runs
-over the JSONL artifact (``scripts/check_trace_schema.py``).
+event kind the step tracer emits, plus a dependency-free validator over
+events and JSONL exports (``tests/test_observability.py`` runs it on the
+tracer's output).
 
 The schema is deliberately plain data — ``{kind: {field: type-spec}}`` —
 so the validator needs no third-party jsonschema package (nothing may be

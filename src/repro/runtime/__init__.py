@@ -4,7 +4,7 @@ from repro.runtime.sharding import (
     param_specs,
     state_specs,
 )
-from repro.runtime.step import make_serve_step, make_train_step
+from repro.runtime.step import make_train_step
 
 __all__ = [
     "param_specs",
@@ -12,5 +12,4 @@ __all__ = [
     "cache_specs",
     "state_specs",
     "make_train_step",
-    "make_serve_step",
 ]

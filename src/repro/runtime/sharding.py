@@ -1,4 +1,4 @@
-"""Partition-spec rules: DP / TP / EP / SP / FSDP over the production mesh.
+"""Partition-spec rules: DP / TP / EP / SP / FSDP over a device mesh.
 
 Axes: ``("data", "model")`` single-pod, ``("pod", "data", "model")``
 multi-pod.  Batch rides ``(pod, data)``; weights ride ``model``:

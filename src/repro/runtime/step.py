@@ -1,20 +1,18 @@
-"""Train / serve step builders over the production mesh.
+"""The training step over a device mesh.
 
 ``make_train_step`` assembles the full training step (microbatched grad
 accumulation -> global-norm clip -> AdamW with schedule) and returns it with
-matching sharding trees, so callers (launcher, dry-run, tests) never
-re-derive specs by hand.  ``make_serve_step`` / ``make_prefill_step`` build
-the inference programs the decode/prefill shapes lower.
+matching sharding trees, so callers (trainer, launcher, tests) never
+re-derive specs by hand.
 
-All builders are allocation-free: ``abstract_*`` products are
-ShapeDtypeStructs via ``jax.eval_shape``, which is what the 512-device
-dry-run feeds to ``.lower()``.
+The ``abstract_*`` helpers are allocation-free: ShapeDtypeStructs via
+``jax.eval_shape``, which the sharding tests feed to ``.lower()``.
 """
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Callable, Optional
+from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
@@ -271,179 +269,3 @@ def abstract_cache(
     return jax.eval_shape(
         functools.partial(T.init_cache, cfg, batch, max_seq, jnp.dtype(dtype))
     )
-
-
-# ---------------------------------------------------------------------------
-# Serve steps (decode / prefill)
-# ---------------------------------------------------------------------------
-
-
-@dataclasses.dataclass
-class ServeStepArtifacts:
-    step: Callable
-    cfg: ModelConfig
-    mesh: Any
-    shape: ShapeConfig
-    param_specs: Tree
-    input_specs: Tree  # tokens / prompt inputs
-    cache_specs: Optional[Tree]
-    out_specs: Tree
-    compute_dtype: Any
-
-    def jitted(self, donate_cache: bool = True):
-        if self.cache_specs is not None:
-            in_sh = (
-                S.named(self.mesh, self.param_specs),
-                S.named(self.mesh, self.input_specs),
-                S.named(self.mesh, self.cache_specs),
-            )
-            donate = (2,) if donate_cache else ()
-        else:
-            in_sh = (
-                S.named(self.mesh, self.param_specs),
-                S.named(self.mesh, self.input_specs),
-            )
-            donate = ()
-        return jax.jit(
-            self.step,
-            in_shardings=in_sh,
-            out_shardings=S.named(self.mesh, self.out_specs),
-            donate_argnums=donate,
-        )
-
-    def abstract_inputs(self) -> tuple:
-        raise NotImplementedError  # built by the factory below
-
-
-def _serve_fsdp(cfg: ModelConfig, mesh, override: Optional[bool]) -> bool:
-    """FSDP serve weights when the model-sharded copy alone would crowd HBM
-    (> ~8 GiB/chip in bf16)."""
-    if override is not None:
-        return override
-    model = S.axis_size(mesh, "model")
-    return cfg.param_count() * 2 / model > 8 * 1024**3
-
-
-def make_serve_step(
-    cfg: ModelConfig,
-    mesh,
-    shape: ShapeConfig,
-    *,
-    compute_dtype=jnp.bfloat16,
-    fsdp: Optional[bool] = None,
-    cache_dtype=None,
-) -> ServeStepArtifacts:
-    """One-token decode microstep: (params, tokens [B], cache) ->
-    (next_tokens [B], cache).  This is SpecInF's admission quantum.
-    ``cache_dtype`` (e.g. float8_e4m3fn) stores the KV cache quantized —
-    halves the dominant decode memory term (§Perf)."""
-    cache_dtype = cache_dtype or compute_dtype
-
-    dp_size = 1
-    for a in S.dp_axes(mesh):
-        dp_size *= mesh.shape[a]
-    batch_sharded = (
-        shape.global_batch % dp_size == 0 and shape.global_batch >= dp_size
-    )
-    act_specs = S.activation_specs(cfg, mesh, batch_sharded=batch_sharded)
-
-    def serve_step(params, tokens, cache):
-        with activation_sharding(mesh, act_specs):
-            logits, cache = T.decode_step(
-                cfg, params, tokens, cache, compute_dtype=compute_dtype
-            )
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return next_tokens, cache
-
-    params_abs = abstract_params(cfg, compute_dtype)
-    cache_abs = abstract_cache(cfg, shape.global_batch, shape.seq_len, cache_dtype)
-    p_specs = S.param_specs(
-        cfg, params_abs, mesh=mesh, fsdp=_serve_fsdp(cfg, mesh, fsdp)
-    )
-    c_specs = S.cache_specs(cfg, cache_abs, shape, mesh)
-    dp = S.dp_axes(mesh)
-    tok_spec = P(dp) if batch_sharded else P()
-    art = ServeStepArtifacts(
-        step=serve_step,
-        cfg=cfg,
-        mesh=mesh,
-        shape=shape,
-        param_specs=p_specs,
-        input_specs=tok_spec,
-        cache_specs=c_specs,
-        out_specs=(tok_spec, c_specs),
-        compute_dtype=compute_dtype,
-    )
-
-    def abstract_inputs():
-        tokens = jax.ShapeDtypeStruct((shape.global_batch,), jnp.int32)
-        return params_abs, tokens, cache_abs
-
-    art.abstract_inputs = abstract_inputs
-    return art
-
-
-def make_prefill_step(
-    cfg: ModelConfig,
-    mesh,
-    shape: ShapeConfig,
-    *,
-    compute_dtype=jnp.bfloat16,
-    impl: str = "auto",
-    fsdp: Optional[bool] = None,
-    cache_dtype=None,
-) -> ServeStepArtifacts:
-    """Full-sequence prefill: (params, inputs [B, S]) ->
-    (last logits [B, V], cache at seq_len)."""
-    cache_dtype = cache_dtype or compute_dtype
-
-    dp_size = 1
-    for a in S.dp_axes(mesh):
-        dp_size *= mesh.shape[a]
-    batch_sharded = (
-        shape.global_batch % dp_size == 0 and shape.global_batch >= dp_size
-    )
-    act_specs = S.activation_specs(cfg, mesh, batch_sharded=batch_sharded)
-
-    def prefill_step(params, inputs):
-        with activation_sharding(mesh, act_specs):
-            return T.prefill(
-                cfg, params, inputs, shape.seq_len, impl=impl,
-                compute_dtype=compute_dtype, cache_dtype=cache_dtype,
-            )
-
-    params_abs = abstract_params(cfg, compute_dtype)
-    cache_abs = abstract_cache(cfg, shape.global_batch, shape.seq_len, cache_dtype)
-    p_specs = S.param_specs(
-        cfg, params_abs, mesh=mesh, fsdp=_serve_fsdp(cfg, mesh, fsdp)
-    )
-    c_specs = S.cache_specs(cfg, cache_abs, shape, mesh)
-    dp = S.dp_axes(mesh)
-    if cfg.embed_inputs:
-        in_spec = P(dp, None, None)
-    else:
-        in_spec = P(dp, None)
-    plan = S.ShardingPlan(cfg, mesh)
-    logits_spec = P(dp, plan.vocab())
-    art = ServeStepArtifacts(
-        step=prefill_step,
-        cfg=cfg,
-        mesh=mesh,
-        shape=shape,
-        param_specs=p_specs,
-        input_specs=in_spec,
-        cache_specs=None,
-        out_specs=(logits_spec, c_specs),
-        compute_dtype=compute_dtype,
-    )
-
-    def abstract_inputs():
-        b, s = shape.global_batch, shape.seq_len
-        if cfg.embed_inputs:
-            inp = jax.ShapeDtypeStruct((b, s, cfg.d_model), jnp.float32)
-        else:
-            inp = jax.ShapeDtypeStruct((b, s), jnp.int32)
-        return params_abs, inp
-
-    art.abstract_inputs = abstract_inputs
-    return art

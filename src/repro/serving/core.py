@@ -1461,8 +1461,7 @@ class EngineCore:
 
     def _on_slot_finished(self, slot: int, internal: Request) -> None:
         """Engine retirement callback (budget exhausted or max_seq horizon
-        reached) — also covers retirements driven through the legacy
-        ``decode_microstep`` path."""
+        reached), for every fused loop the engine runs."""
         cr = self.slot_requests.pop(slot, None)
         if cr is None:
             return

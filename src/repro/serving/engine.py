@@ -27,8 +27,7 @@ Fast path (DESIGN.md §3):
 
 * ``decode_loop(k)`` fuses k microsteps into one jitted ``lax.scan`` with
   per-slot active/done masking and donated cache buffers — exactly ONE
-  device->host transfer per loop, vs the per-step transfer of the legacy
-  ``decode_microstep`` (kept for comparison and single-step callers).
+  device->host transfer per loop, whatever k is.
 * Chunked prefill (DESIGN.md §7, default for attention families): admission
   only *reserves* a slot; the prompt streams as fixed-width chunks
   (``prefill_chunk``) through ONE compiled batched program per model —
@@ -371,7 +370,7 @@ class InferenceEngine:
             self.pages_per_slot = -(-max_seq // kv_page_size)
             # default pool: dense-equivalent logical capacity (+ sentinel);
             # callers shrink it (or raise max_slots) to trade layout slack
-            # for concurrency — see benchmarks/engine_micro.py
+            # for concurrency
             num_pages = kv_pool_pages or (
                 max_slots * self.pages_per_slot + 1
             )
@@ -407,7 +406,7 @@ class InferenceEngine:
         self.slots: list[Optional[Request]] = [None] * max_slots
         self.tokens = jnp.zeros((max_slots,), jnp.int32)
         self.steps_executed = 0
-        # perf counters (benchmarks/engine_micro.py reads these)
+        # perf counters (registry views; the tests and bench/ read these)
         self.d2h_transfers = 0  # device->host syncs issued by engine code
         self.generated_tokens_total = 0
         #: (model, impl) -> distinct program widths compiled, where model is
@@ -431,12 +430,6 @@ class InferenceEngine:
         self.spec_drafted = 0
         self.spec_accepted = 0
 
-        self._decode = jax.jit(
-            _program(
-                T.decode_step, cfg, compute_dtype=compute_dtype,
-                attn_impl=decode_impl,
-            )
-        )
         self._decode_loop = jax.jit(
             _program(
                 decode_loop, cfg, compute_dtype=compute_dtype,
@@ -992,10 +985,9 @@ class InferenceEngine:
         return req
 
     def _retire_slot(self, i: int, now: float) -> Request:
-        """Single retirement path for the fused loops and
-        ``decode_microstep``: evict the slot, stamp the finish time, and
-        notify the lifecycle core (if one is attached) so the request's
-        state machine advances to FINISHED."""
+        """Single retirement path for the fused loops: evict the slot,
+        stamp the finish time, and notify the lifecycle core (if one is
+        attached) so the request's state machine advances to FINISHED."""
         req = self.evict_slot(i, sync=False)
         req.finish_time = now
         if self._core is not None:
@@ -1908,58 +1900,6 @@ class InferenceEngine:
                 self._note_proposer_round(proposer, 1, round_acc, round_prop)
                 if self.paged and self._bt_dirty:
                     self._sync_block_tables()
-        return finished
-
-    # ------------------------------------------------------------------
-    def decode_microstep(self) -> list[Request]:
-        """One decode step over all slots; returns requests that finished.
-
-        Legacy single-step path: syncs to host every step, but the token
-        batch and the per-slot finish-check indices come down in ONE batched
-        transfer (the old code paid 1 + num_active transfers per step).
-        Kept for single-step callers and as the benchmark baseline — the
-        fast path is ``decode_loop``."""
-        if self.num_active == 0 or self.num_active == self.num_prefilling:
-            return []
-        if self.paged:
-            self._top_up_pages(1)
-        logits, self.cache = self._decode(self.params, self.tokens, self.cache)
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        self.tokens = next_tokens
-        self.steps_executed += 1
-        if self.num_prefilling:
-            # the single-step program advances EVERY slot's index; restore
-            # PREFILLING slots' prefill progress in one batched scatter
-            # (their garbage K/V write at the old index is overwritten by
-            # the next chunk, the usual stale-overwrite invariant)
-            slots_, values = [], []
-            for i in range(self.max_slots):
-                if self.slot_prefilling(i):
-                    left = self._prefill_left[i]
-                    slots_.append(i)
-                    values.append(len(self.slots[i].prompt) - (
-                        len(left) if left is not None else 0
-                    ))
-            self.cache["index"] = self.cache["index"].at[
-                np.asarray(slots_)
-            ].set(np.asarray(values, np.int32))
-        finished = []
-        host_tokens, idx_np = self._fetch((next_tokens, self.cache["index"]))
-        self.d2h_transfers += 1  # tokens + finish-check indices, batched
-        now = self.clock()
-        for i, req in enumerate(self.slots):
-            if req is None or self.slot_prefilling(i):
-                continue
-            req.generated.append(int(host_tokens[i]))
-            self.generated_tokens_total += 1
-            if self.paged:
-                self._slot_idx[i] = int(idx_np[i])
-            if len(req.generated) >= req.max_new_tokens or int(
-                idx_np[i]
-            ) >= (self.max_seq - 1):
-                finished.append(self._retire_slot(i, now))
-        if self.paged and self._bt_dirty:
-            self._sync_block_tables()  # one upload covers every retirement
         return finished
 
     # ------------------------------------------------------------------

@@ -27,6 +27,7 @@ from repro.kernels import ops
 from repro.models import transformer as T
 from repro.serving.core import (
     Grant,
+    Priority,
     PriorityPolicy,
     RequestState,
     SamplingParams,
@@ -394,3 +395,83 @@ def test_spec_draft_index_survives_mixed_spec_steps():
         guard -= 1
     assert short.output_tokens == r1.generated
     assert long.output_tokens == r2.generated
+
+
+# ---------------------------------------------------------------------------
+# Core: chunked vs monolithic admission under mixed load
+# ---------------------------------------------------------------------------
+
+#: the token grant every quantum of the mixed-load runs gets
+MIXED_BUDGET = 32
+
+
+@pytest.fixture(scope="module")
+def mixed_load():
+    """Long-prompt OFFLINE churn (160-token prompts) beside short ONLINE
+    arrivals on a virtual clock (one microstep == 2 ms, a 16-token chunk
+    priced as one microstep), run twice: prompts streamed as budgeted
+    chunks, and admitted as one monolithic prefill.  Same arrivals,
+    prompts and grants; only ``prefill_chunk`` differs."""
+    step_s = 0.002
+
+    def run(chunk):
+        vnow = [0.0]
+        eng = InferenceEngine(CFG, PARAMS, max_slots=2, max_seq=256,
+                              clock=lambda: vnow[0], prefill_chunk=chunk)
+        core = eng.core
+        core.policy = PriorityPolicy(prefill_token_cost_steps=1.0 / 16.0)
+        rng = np.random.default_rng(0)
+        for _ in range(6):
+            core.submit(rng.integers(0, CFG.vocab_size, 160),
+                        SamplingParams(max_new_tokens=12),
+                        priority=Priority.OFFLINE, arrival_time=0.0)
+        online = [
+            core.submit(rng.integers(0, CFG.vocab_size, 8),
+                        SamplingParams(max_new_tokens=4),
+                        priority=Priority.ONLINE, arrival_time=float(t))
+            for t in np.cumsum(rng.exponential(0.02, 12))
+        ]
+        max_step_tokens = 0
+        while core.has_unfinished:
+            g0 = eng.generated_tokens_total
+            out = core.step(Grant(
+                now=vnow[0], token_budget=MIXED_BUDGET,
+                advance_clock=lambda steps: vnow.__setitem__(
+                    0, vnow[0] + steps * step_s),
+            ))
+            max_step_tokens = max(
+                max_step_tokens,
+                out.prefill_tokens + (eng.generated_tokens_total - g0),
+            )
+            if out.cost_steps == 0 and not out.admitted:
+                vnow[0] += step_s  # idle until the next arrival
+        assert all(r.state.finished for r in online)
+        return {
+            "max_step_tokens": max_step_tokens,
+            "ttft_p95": eng.obs.metrics.histogram(
+                "core/online_ttft_s").percentile(95),
+            "programs": eng.prefill_compile_count,
+        }
+
+    return {"chunked": run(None), "monolithic": run(0)}
+
+
+@pytest.mark.parametrize("invariant", [
+    "chunked_step_within_grant",
+    "monolithic_step_overruns_grant",
+    "chunked_ttft_p95_no_worse",
+    "chunked_compiles_one_program",
+])
+def test_chunked_prefill_under_mixed_load(mixed_load, invariant):
+    """Exact virtual-clock invariants of the unified token-budget step
+    (DESIGN.md §7).  The monolithic overrun is the staleness guard: if it
+    stops overrunning, the workload no longer tests the grant."""
+    chunked, mono = mixed_load["chunked"], mixed_load["monolithic"]
+    if invariant == "chunked_step_within_grant":
+        assert chunked["max_step_tokens"] <= MIXED_BUDGET
+    elif invariant == "monolithic_step_overruns_grant":
+        assert mono["max_step_tokens"] > MIXED_BUDGET
+    elif invariant == "chunked_ttft_p95_no_worse":
+        assert chunked["ttft_p95"] <= mono["ttft_p95"]
+    else:
+        assert chunked["programs"] == 1

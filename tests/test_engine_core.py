@@ -204,6 +204,44 @@ def test_no_preemption_policy_queues_online():
     assert on.state.finished and off.state.finished
 
 
+def _mixed_load_online_p95(preemption, step_s=0.002):
+    """Two long OFFLINE requests plus ten short ONLINE arrivals on a virtual
+    clock (one microstep == 2 ms); returns (online latency p95,
+    preemptions)."""
+    vnow = [0.0]
+    eng = _engine(max_slots=2, clock=lambda: vnow[0])
+    core = EngineCore(eng, policy=PriorityPolicy(preemption=preemption))
+    rng = np.random.default_rng(0)
+    for _ in range(2):
+        core.submit(rng.integers(0, CFG.vocab_size, 8),
+                    SamplingParams(max_new_tokens=48),
+                    priority=Priority.OFFLINE, arrival_time=0.0)
+    for t in np.cumsum(rng.exponential(0.02, 10)):
+        core.submit(rng.integers(0, CFG.vocab_size, 8),
+                    SamplingParams(max_new_tokens=4),
+                    priority=Priority.ONLINE, arrival_time=float(t))
+    while core.has_unfinished:
+        out = core.step(Grant(
+            now=vnow[0],
+            advance_clock=lambda steps: vnow.__setitem__(
+                0, vnow[0] + steps * step_s),
+        ))
+        if out.cost_steps == 0 and not out.admitted:
+            vnow[0] += step_s  # idle until the next arrival
+    assert all(r.state.finished for r in core.requests.values())
+    p95 = eng.obs.metrics.histogram("core/online_latency_s").percentile(95)
+    return p95, core.preemption_count
+
+
+def test_preemption_does_not_worsen_online_p95_under_mixed_load():
+    """Same arrivals, prompts and budgets; only the policy's preemption
+    switch differs.  Exact on the virtual clock."""
+    p95, n_preempt = _mixed_load_online_p95(preemption=True)
+    p95_queued, n_queued = _mixed_load_online_p95(preemption=False)
+    assert n_preempt > 0 and n_queued == 0  # the workload does preempt
+    assert p95 <= p95_queued
+
+
 # ---------------------------------------------------------------------------
 # Abort
 # ---------------------------------------------------------------------------
@@ -287,19 +325,6 @@ def test_shim_vs_core_equivalence(paged, spec):
     # the shim registers its requests in the same lifecycle
     for lr in legacy:
         assert eng_a.core.requests[lr.request_id].state.finished
-
-
-def test_legacy_microstep_path_updates_core_state():
-    eng = _engine(paged=False)
-    r = Request(prompt=np.arange(4), max_new_tokens=2)
-    assert eng.add_request(r)
-    for _ in range(4):
-        eng.decode_microstep()
-        if eng.num_active == 0:
-            break
-    cr = eng.core.requests[r.request_id]
-    assert cr.state is RequestState.FINISHED_LENGTH
-    assert cr.output_tokens == [int(t) for t in r.generated]
 
 
 # ---------------------------------------------------------------------------

@@ -203,7 +203,7 @@ def test_engine_continuous_batching(tiny):
     assert engine.num_active == 2
     done = []
     for _ in range(8):
-        done += engine.decode_microstep()
+        done += engine.decode_loop(1)
         if engine.num_active == 0:
             break
     done_ids = {r.request_id for r in done}

@@ -128,6 +128,5 @@ def test_engine_programs_carry_their_model_function_names(engine):
     assert "module @jit_prefill_chunks_into_slots" in prefill
     assert "tf.aliasing_output" in prefill
     names = {prog.__wrapped__.__name__ for prog in (
-        engine._decode, engine._prefill_slot, engine._suffix_prefill)}
-    assert names == {"decode_step", "prefill_into_slot_paged",
-                     "prefill_suffix_into_slot"}
+        engine._prefill_slot, engine._suffix_prefill)}
+    assert names == {"prefill_into_slot_paged", "prefill_suffix_into_slot"}

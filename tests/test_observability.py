@@ -40,13 +40,11 @@ def tiny():
     return cfg, params
 
 
-@pytest.fixture(scope="module")
-def traced_run(tiny):
-    """One collocated virtual-clock run with tracing on: 2 offline
-    requests filling bubbles, 6 online Poisson-ish arrivals."""
+def _collocated_run(tiny, obs=None):
+    """One collocated virtual-clock run: 2 offline requests filling
+    bubbles, 6 online Poisson-ish arrivals."""
     cfg, params = tiny
-    engine = InferenceEngine(cfg, params, max_slots=2, max_seq=96)
-    assert engine.obs.tracer.enabled, "engines trace by default"
+    engine = InferenceEngine(cfg, params, max_slots=2, max_seq=96, obs=obs)
     for _ in range(2):
         engine.core.submit(
             np.arange(8), SamplingParams(max_new_tokens=32),
@@ -65,6 +63,20 @@ def traced_run(tiny):
         cfg=SpecInFConfig(busy_hold_ms=5.0), decode_microstep_s=0.002,
     )
     metrics = rt.run(num_iterations=12)
+    return engine, metrics
+
+
+@pytest.fixture(scope="module")
+def traced_run(tiny):
+    engine, metrics = _collocated_run(tiny)
+    assert engine.obs.tracer.enabled, "engines trace by default"
+    return engine, metrics
+
+
+@pytest.fixture(scope="module")
+def untraced_run(tiny):
+    engine, metrics = _collocated_run(tiny, Observability(tracing=False))
+    assert not engine.obs.tracer.enabled and not engine.obs.tracer.events
     return engine, metrics
 
 
@@ -273,6 +285,17 @@ def test_collocated_attribution_sums_to_latency(traced_run):
     from_trace = sorted(ra.ttft_s for ra in online)
     from_registry = sorted(metrics.online_ttft_s)
     assert from_trace == pytest.approx(from_registry, abs=1e-12)
+
+
+@pytest.mark.parametrize("row", ["virtual_time_s", "online_served",
+                                 "online_ttft_s"])
+def test_tracing_leaves_the_schedule_unchanged(traced_run, untraced_run,
+                                               row):
+    """The tracer only records: the same collocated run with it off ends at
+    the same virtual time, serves the same online requests, and stamps the
+    same TTFTs, exactly."""
+    traced, untraced = traced_run[1], untraced_run[1]
+    assert getattr(traced, row) == getattr(untraced, row)
 
 
 def test_filling_metrics_are_registry_views(traced_run):

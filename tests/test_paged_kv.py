@@ -435,9 +435,9 @@ def test_spec_greedy_paged_identical_with_rollback():
 
 
 def test_retirement_resets_draft_index_on_all_paths():
-    """Regression: plain decode_loop / decode_microstep retirements left the
-    draft cache index stale on spec-enabled engines."""
-    for path in ("loop", "microstep"):
+    """Regression: plain decode_loop retirements (multi-step and one-step
+    loops) left the draft cache index stale on spec-enabled engines."""
+    for path in ("loop", "single"):
         eng = InferenceEngine(
             CFG, PARAMS, max_slots=1, max_seq=64,
             draft_cfg=DCFG, draft_params=DPARAMS,
@@ -447,7 +447,7 @@ def test_retirement_resets_draft_index_on_all_paths():
         )
         guard = 20
         while eng.num_active and guard:
-            eng.decode_loop(2) if path == "loop" else eng.decode_microstep()
+            eng.decode_loop(2 if path == "loop" else 1)
             guard -= 1
         assert int(np.asarray(eng.draft_cache["index"])[0]) == 0, path
         # slot reuse after the reset must still be exact

@@ -102,6 +102,43 @@ def _journal_streams(path):
 # ---------------------------------------------------------------------------
 
 
+@pytest.fixture(scope="module")
+def journaled_pair(tmp_path_factory):
+    """The same mixed online/offline workload drained twice on the virtual
+    clock, with a journal attached and without one."""
+    def run(path):
+        vnow = [0.0]
+        core = _engine(vnow).core
+        journal = None
+        if path is not None:
+            journal = RequestJournal(path, fsync_interval=8)
+            journal.attach(core)
+        reqs = _submit(core, n_offline=2, n_online=8)
+        _drain(core, vnow)
+        if journal is not None:
+            journal.close()
+        return {
+            "output_tokens": [list(r.output_tokens) for r in reqs],
+            "finished": [r.finish_reason for r in reqs],
+            "virtual_time_s": vnow[0],
+            "appends": core.obs.metrics.counter("journal/appends").value,
+        }
+
+    path = str(tmp_path_factory.mktemp("journal") / "j.jsonl")
+    return run(path), run(None)
+
+
+@pytest.mark.parametrize("row", ["output_tokens", "finished",
+                                 "virtual_time_s"])
+def test_journal_leaves_the_schedule_unchanged(journaled_pair, row):
+    """Journal I/O is host-side, between quanta: the journaled run emits
+    the same tokens, finishes the same requests the same way and ends at
+    the same virtual time, exactly — and it did write the journal."""
+    journaled, plain = journaled_pair
+    assert journaled["appends"] >= 1 and plain["appends"] == 0
+    assert journaled[row] == plain[row]
+
+
 @pytest.mark.parametrize("paged", [True, False], ids=["paged", "dense"])
 def test_crash_recover_byte_identical(tmp_path, paged):
     """Kill mid-run, replay the journal into a FRESH engine, drain: every

@@ -19,8 +19,10 @@ from repro.resilience import (
     OverloadLadder,
 )
 from repro.serving.core import (
+    EngineCore,
     Grant,
     Priority,
+    PriorityPolicy,
     RequestState,
     RevocationSignal,
     SamplingParams,
@@ -521,6 +523,53 @@ def test_ladder_in_step_loop_recovers_service():
     assert m.counter("fault/ladder_escalations").value >= 3
 
 
+def _burst_metrics(ladder, step_s=0.002):
+    """An OFFLINE backlog plus a burst of deadline-bearing ONLINE arrivals
+    at 10x the slot concurrency, on a virtual clock (one microstep ==
+    2 ms), with preemption off so the ladder is the only mitigation.
+    Returns the metrics registry."""
+    vnow = [0.0]
+    core = EngineCore(_engine(paged=True, clock=lambda: vnow[0]),
+                      policy=PriorityPolicy(preemption=False))
+    if ladder:
+        core.ladder = OverloadLadder(LadderConfig(
+            high_queue_depth=6, low_queue_depth=2, up_dwell=2,
+            down_dwell=6, offline_keep_depth=2, online_slack_s=0.05,
+        ))
+    rng = np.random.default_rng(0)
+    for _ in range(10):
+        core.submit(rng.integers(0, CFG.vocab_size, 8),
+                    SamplingParams(max_new_tokens=32),
+                    priority=Priority.OFFLINE, arrival_time=0.0)
+    for t in 0.05 + np.cumsum(rng.exponential(0.002, 20)):
+        core.submit(rng.integers(0, CFG.vocab_size, 8),
+                    SamplingParams(max_new_tokens=4, deadline_s=0.25),
+                    priority=Priority.ONLINE, arrival_time=float(t))
+    while core.has_unfinished:
+        base = vnow[0]
+        out = core.step(Grant(
+            now=base, token_budget=16,
+            advance_clock=lambda steps, b=base: vnow.__setitem__(
+                0, b + steps * step_s),
+        ))
+        if out.cost_steps == 0 and not out.admitted:
+            vnow[0] += step_s  # idle until the next arrival
+    return core.obs.metrics
+
+
+def test_ladder_does_not_worsen_online_p95_under_burst():
+    """Same burst with and without the overload ladder: the ladder sheds,
+    and served-online p95 is no worse.  Exact on the virtual clock."""
+    with_ladder = _burst_metrics(ladder=True)
+    without = _burst_metrics(ladder=False)
+    shed = (with_ladder.counter("fault/shed/offline").value
+            + with_ladder.counter("fault/shed/online").value)
+    assert shed > 0
+    p95 = with_ladder.histogram("core/online_latency_s").percentile(95)
+    p95_without = without.histogram("core/online_latency_s").percentile(95)
+    assert p95 <= p95_without
+
+
 # ---------------------------------------------------------------------------
 # Runtime early-resume (training comes back before the predicted bubble end)
 # ---------------------------------------------------------------------------
@@ -576,3 +625,38 @@ def test_runtime_early_resume_bounded_overrun():
     rt2.run(num_iterations=4)
     h2 = rt2.engine.obs.metrics.histogram("fault/revocation_overrun_s")
     assert h2.values() == h.values()
+
+
+@pytest.mark.parametrize("resumes", [0, 2], ids=["fault_free", "early_resume"])
+def test_collocated_training_holds_the_no_serving_baseline(resumes):
+    """The training guarantee on the virtual clock: with bubbles filled,
+    fault-free or with training resuming early (revocation makes serving
+    pay for the overrun), training's virtual time is no longer than the
+    no-serving baseline of compute plus exposed communication."""
+    import itertools
+
+    from repro.core import SpecInFRuntime
+    from repro.core.profiles import dp_profile
+
+    iterations, compute_s, comm_s = 6, 0.02, 0.04
+    eng = _engine(paged=True)
+    for _ in range(2):
+        eng.core.submit(np.arange(8), SamplingParams(max_new_tokens=10**9),
+                        priority=Priority.OFFLINE, arrival_time=0.0)
+    inj = FaultInjector(seed=4, specs=(
+        FaultSpec("runtime/early_resume", probability=1.0,
+                  max_fires=resumes),
+    )) if resumes else None
+    rt = SpecInFRuntime(
+        train_step=lambda s, b: (s, {"loss": 0.0}), train_state={},
+        batch_iter=itertools.repeat({}),
+        profile=dp_profile("tiny", compute_s=compute_s, comm_s=comm_s),
+        engine=eng, cfg=SpecInFConfig(), decode_microstep_s=0.004,
+        faults=inj,
+    )
+    metrics = rt.run(num_iterations=iterations)
+    assert eng.obs.metrics.counter("fault/early_resume").value == resumes
+    assert eng.generated_tokens_total > 0  # the bubbles were filled
+    # dp_profile exposes comm_s * (1 - overlap) per iteration (overlap 0.3)
+    baseline_s = iterations * (compute_s + comm_s * 0.7)
+    assert metrics.virtual_time_s <= baseline_s + 1e-12
