@@ -25,8 +25,13 @@ length-awareness levers make the kernel ragged-batch fast:
     past the length, so their FLOPs are skipped too.
 
 The per-tile helpers below (``init_scratch`` / ``tile_update`` /
-``finalize`` / ``scratch_shapes``) are shared by every attention kernel of
-this package; each kernel only supplies its visibility mask.
+``fold_head`` / ``finalize`` / ``scratch_shapes``) are shared by every
+attention kernel of this package; each kernel only supplies its visibility
+mask.  The paged verify, tree-verify and prefill kernels also share their
+dense kernel's body.  The paged decode kernel has its own (it copies its
+pages by hand, many a block, in a loop bounded by the slot's length, and
+loads each kv head's rows by strided loads) and folds each head through
+``fold_head``.
 
 ``interpret=True`` runs the body in the Pallas interpreter off the TPU, for
 tests only: the interpreter does not apply the TPU lowering rules, which
@@ -65,28 +70,40 @@ def tile_update(q_ref, k_ref, v_ref, acc_ref, m_ref, l_ref, mask, sm_scale):
 
     q_ref [1, kvH, R, hd] (R query rows per head); k_ref / v_ref
     [1, bk, kvH, hd]; mask [R, bk] bool — which (row, key) pairs are
-    visible, the same for every head.  Masked pairs contribute exactly 0, so
-    a row whose window is empty keeps ``l == 0`` and finalizes to zeros
-    (without the guard ``exp(s - m_new)`` would be 1 for a fully-masked row
-    and the output an unweighted mean of V)."""
+    visible, the same for every head."""
     for h in range(k_ref.shape[2]):
-        q = q_ref[0, h].astype(jnp.float32)  # [R, hd]
-        k = k_ref[0, :, h].astype(jnp.float32)  # [bk, hd]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
-        ) * sm_scale  # [R, bk]
-        s = jnp.where(mask, s, NEG_INF)
-        m_prev = m_ref[h]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
-        corr = jnp.exp(m_prev - m_new)
-        l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
-        v = v_ref[0, :, h].astype(jnp.float32)
-        pv = jax.lax.dot_general(
-            p, v, (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32
+        fold_head(
+            h, q_ref[0, h].astype(jnp.float32),  # [R, hd]
+            k_ref[0, :, h].astype(jnp.float32),  # [bk, hd]
+            lambda h=h: v_ref[0, :, h].astype(jnp.float32),
+            acc_ref, m_ref, l_ref, mask, sm_scale,
         )
-        acc_ref[h] = acc_ref[h] * corr + pv
-        m_ref[h] = m_new
+
+
+def fold_head(h, q, k, load_v, acc_ref, m_ref, l_ref, mask, sm_scale):
+    """Fold kv head ``h``'s tile into its online softmax.
+
+    q [R, hd] and k [bk, hd] f32; ``load_v()`` gives the head's V rows
+    [bk, hd] f32, loaded once the scores are done; mask [R, bk] bool.
+    Masked pairs contribute exactly 0, so a row whose window is empty keeps
+    ``l == 0`` and finalizes to zeros (without the guard ``exp(s - m_new)``
+    would be 1 for a fully-masked row and the output an unweighted mean of
+    V)."""
+    s = jax.lax.dot_general(
+        q, k, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+    ) * sm_scale  # [R, bk]
+    s = jnp.where(mask, s, NEG_INF)
+    m_prev = m_ref[h]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
+    p = jnp.where(mask, jnp.exp(s - m_new), 0.0)
+    corr = jnp.exp(m_prev - m_new)
+    l_ref[h] = l_ref[h] * corr + jnp.sum(p, axis=1, keepdims=True)
+    pv = jax.lax.dot_general(
+        p, load_v(), (((1,), (0,)), ((), ())),
+        preferred_element_type=jnp.float32,
+    )
+    acc_ref[h] = acc_ref[h] * corr + pv
+    m_ref[h] = m_new
 
 
 def finalize(o_ref, acc_ref, l_ref) -> None:

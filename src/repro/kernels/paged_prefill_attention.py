@@ -2,7 +2,7 @@
 
 ``prefill_attention`` generalized the chunk-verify kernel to prefill-sized
 query chunks; this kernel applies the same block-table indirection as
-``paged_decode_attention`` / ``paged_verify_attention`` on top, so chunked
+``paged_verify_attention`` on top, so chunked
 prefill streams straight into the paged KV pool: each chunk query attends
 the slot's previously-written *pages* (including radix-shared prefix pages)
 plus the chunk's own causal triangle.  The chunk's real K/V has already

@@ -1,8 +1,8 @@
 """Paged chunk-verify Pallas TPU kernel: block-table KV gather.
 
 ``verify_attention`` generalized the flash-decode kernel from one query
-token to a ``T = gamma + 1`` speculative chunk; this kernel applies the same
-block-table indirection as ``paged_decode_attention`` on top, so speculative
+token to a ``T = gamma + 1`` speculative chunk; this kernel adds a
+block-table indirection in the KV index_map on top, so speculative
 verification runs directly against the paged KV pool.  The chunk's own K/V
 has already been scattered into the slot's pages at logical positions
 ``lengths - T .. lengths - 1``.

@@ -150,6 +150,79 @@ def test_paged_decode_matches_dense_kernel(impl):
         )
 
 
+def _share_prefix(k, v, bt, pages, page):
+    """Make slot 1's first ``pages`` logical pages the very physical pages
+    of slot 0 (a radix-shared prefix): the dense cache copies slot 0's
+    rows, the block table points at slot 0's pages."""
+    n = pages * page
+    k = k.at[1, :n].set(k[0, :n])
+    v = v.at[1, :n].set(v[0, :n])
+    bt = bt.at[1, :pages].set(bt[0, :pages])
+    return k, v, bt
+
+
+# Lengths around the kernel's block of 8 16-token pages, in a table of 11
+# logical pages (not a multiple of 8): empty, one token, one page, one past
+# it, a block edge, one past it, mid second block, the full 11 pages.
+BLOCK_EDGE_LENGTHS = [0, 1, 16, 17, 128, 129, 150, 176]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("layout", ["shuffled", "prefix_shared"])
+@pytest.mark.parametrize("h,kvh", [(4, 4), (4, 2)], ids=["mha", "gqa2"])
+def test_paged_decode_blocks_match_dense(h, kvh, layout, dtype):
+    """The paged decode kernel, several pages a block and a loop bounded by
+    each slot's length, equals dense XLA attention at every block edge:
+    W-1 = 11 pages is not a multiple of the 8 pages a block, physical pages
+    are shuffled, and (``prefix_shared``) two tables name the same pages.
+    bfloat16 pages take the kernel's two-heads-a-word loads; the reference
+    then attends the same rounded values in float32, and the kernel's
+    output is rounded to bfloat16 (2**-8 relative)."""
+    from repro.kernels.paged_decode_attention import pages_per_block
+
+    page, hd, nk = 16, 16, 11
+    dt = jnp.dtype(dtype)
+    assert pages_per_block(page, kvh, hd, dt.itemsize, nk) == 8
+    b, s = len(BLOCK_EDGE_LENGTHS), nk * page
+    q, k, v = (x.astype(dt).astype(jnp.float32)
+               for x in _rand_case(7, b, h, kvh, s, hd))
+    rng = np.random.RandomState(7)
+    k_pool, v_pool, bt = _paged_from_dense(k, v, page, rng)
+    lengths = BLOCK_EDGE_LENGTHS
+    if layout == "prefix_shared":
+        # slot 1 (one token long in the shuffled case) reads 9 shared pages
+        k, v, bt = _share_prefix(k, v, bt, pages=9, page=page)
+        lengths = [0, 140] + lengths[2:]
+    lengths = jnp.asarray(lengths, jnp.int32)
+    ref = ops.decode_attention(q, k, v, lengths, impl="xla")
+    out = ops.paged_decode_attention(
+        q.astype(dt), k_pool.astype(dt), v_pool.astype(dt), bt, lengths,
+        impl="pallas",
+    )
+    tol = 2e-5 if dt == jnp.float32 else 1e-2
+    np.testing.assert_allclose(
+        np.asarray(out, np.float32), np.asarray(ref), rtol=tol, atol=tol,
+        err_msg=f"lengths={np.asarray(lengths)}",
+    )
+    assert not np.asarray(out[0]).any()  # the empty slot writes zeros
+
+
+@pytest.mark.parametrize("shape,want", [
+    ((16, 8, 128, 2, 128), 8),  # qwen3-1.7b: 0.5 MiB of K and V a buffer
+    ((16, 16, 128, 2, 128), 8),  # OLMo-1B: 1 MiB a buffer
+    ((16, 8, 128, 2, 5), 5),  # a table narrower than a block
+    ((8, 8, 128, 2, 160), 16),  # 8-token pages: 16 of them make 128 tokens
+    ((16, 64, 256, 4, 128), 2),  # wide f32 pages halve the block to fit
+])
+def test_pages_per_block_rule(shape, want):
+    """Pages a block come from the shapes alone: at least 128 tokens, halved
+    while the double-buffered K and V pass the VMEM budget, at most the
+    table's logical pages."""
+    from repro.kernels.paged_decode_attention import pages_per_block
+
+    assert pages_per_block(*shape) == want
+
+
 @pytest.mark.parametrize("impl", ["xla", "pallas"])
 def test_paged_verify_matches_dense_kernel(impl):
     """Property (seeded sweep): paged chunk-verify attention equals the

@@ -217,6 +217,26 @@ def test_paged_kernels_compile_at_olmo_widths(name, one_chip):
     assert "tpu_custom_call" in jax.jit(fn).lower(*args).compile().as_text()
 
 
+@pytest.mark.parametrize("heads,kv_heads,w", [
+    (QWEN3.num_heads, QWEN3.num_kv_heads, 129),
+    (QWEN3.num_heads, QWEN3.num_kv_heads, 161),
+    (OLMO.num_heads, OLMO.num_kv_heads, 129),
+], ids=["qwen3-max_seq2048", "qwen3-max_seq2560", "olmo-max_seq2048"])
+def test_paged_decode_compiles_at_cell_tables(heads, kv_heads, w, one_chip):
+    """The decode kernel at the benchmark cells' own engines: 16 slots,
+    16-token pages, a table of W-1 = 128 or 160 logical pages and a pool of
+    every slot's pages; its double-buffered K/V blocks must fit the scoped
+    VMEM and its dynamic block loop must lower."""
+    slots, hd = 16, 128
+    pool = slots * (w - 1) + 1
+    bf = lambda *shape: _sds(one_chip, shape)
+    i32 = lambda *shape: _sds(one_chip, shape, jnp.int32)
+    args = (bf(slots, heads, hd), bf(pool, PAGE, kv_heads, hd),
+            bf(pool, PAGE, kv_heads, hd), i32(slots, w), i32(slots))
+    hlo = jax.jit(paged_decode_attention).lower(*args).compile().as_text()
+    assert "tpu_custom_call" in hlo
+
+
 def _olmo_cell_engine() -> dict:
     """The engine block (``max_slots``, ``max_seq``) of the benchmark cell
     that serves OLMo-1B, from its traffic file."""
